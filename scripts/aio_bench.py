@@ -163,8 +163,10 @@ def main():
 
     import jax
 
+    from tritonclient_tpu import _compile_cache
     from tritonclient_tpu.server import InferenceServer
 
+    _compile_cache.configure()
     with InferenceServer(http=False) as server:
         unary = asyncio.run(_aio_unary(server.grpc_address))
         streams = asyncio.run(_aio_streams(server.grpc_address))

@@ -78,7 +78,7 @@ def main():
 
     import jax
 
-    from tritonclient_tpu import _memscope, _stepscope
+    from tritonclient_tpu import _compile_cache, _memscope, _stepscope
     from tritonclient_tpu.genai_perf import GenAIPerf
     from tritonclient_tpu.models.gpt import GptModel
     from tritonclient_tpu.models.gpt_engine import GptEngineModel
@@ -86,6 +86,7 @@ def main():
 
     import numpy as np
 
+    _compile_cache.configure()
     engine_model = GptEngineModel()
     loop_model = GptModel()
     engine_model.warmup()

@@ -10,14 +10,14 @@ Three tiers, mirroring the subsystem:
 * acceptance — the full crash drill: 2 replica SUBPROCESSES under
   sustained idempotent load, SIGKILL one mid-stream, assert eject /
   zero-visible-failure failover / stream resume / rejoin-with-replay,
-  recording ``CHAOS_r01.json`` with seed-deterministic fault counts.
+  writing the ``CHAOS_r01.json`` record (seed-deterministic fault
+  counts) under ``tmp_path``.
 
 Everything here must stay green under ``TPUSAN=1`` (all
 chaos/resilience locks are sanitizer-adopted named locks).
 """
 
 import json
-import os
 import random
 import threading
 import time
@@ -60,8 +60,6 @@ import sys
 
 sys.path.insert(0, "scripts")
 from check_metrics_exposition import check_exposition  # noqa: E402
-
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SERVICE_MS = 5
 
@@ -1118,14 +1116,15 @@ class TestResilienceExpositionChecker:
 
 
 class TestChaosAcceptance:
-    def test_sigkill_failover_resume_rejoin(self):
+    def test_sigkill_failover_resume_rejoin(self, tmp_path):
         """2 replica subprocesses under sustained idempotent load;
         SIGKILL one mid-stream. Assert: ejected within the probe
         window, zero client-visible failures for idempotent unary
         traffic (>= 99% availability gate), the sticky stream resumes
         on the survivor, and the restarted replica rejoins with the
-        router's journaled admin state replayed. Records CHAOS_r01.json
-        with seed-deterministic fault counts."""
+        router's journaled admin state replayed. Writes the CHAOS_r01
+        record (seed-deterministic fault counts) under tmp_path: a test
+        run must not rewrite a tracked file."""
         import tritonclient_tpu.utils.shared_memory as shm
         from tritonclient_tpu.http import (
             InferenceServerClient as HttpClient,
@@ -1324,7 +1323,7 @@ class TestChaosAcceptance:
             "stream_resumed": stream_replies[0] >= 4,
             "pass": bool(availability >= 0.99),
         })
-        with open(os.path.join(_REPO_ROOT, "CHAOS_r01.json"), "w") as f:
+        with open(tmp_path / "CHAOS_r01.json", "w") as f:
             json.dump(record, f, indent=2)
             f.write("\n")
         # Deterministic, plan-determined fault set: both nth rules fired

@@ -73,7 +73,7 @@ def test_flash_untileable_shapes_fall_back(monkeypatch):
 @pytest.mark.skipif(
     jax.default_backend() != "tpu",
     reason="compiled (Mosaic) kernel path needs a real TPU; CI runs the "
-    "interpreter path. Run scripts/tpu_smoke.py on hardware.",
+    "interpreter path. Run chip_smoke.py on hardware.",
 )
 def test_flash_compiles_on_tpu_bert_base_shape():
     # bert_base: H=12, d=64 — d below the 128-lane tile, relying on Mosaic

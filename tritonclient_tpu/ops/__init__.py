@@ -7,6 +7,9 @@ in tritonclient_tpu.parallel (ring_attention, ulysses_attention).
 """
 
 from tritonclient_tpu.ops.attention import dot_product_attention
-from tritonclient_tpu.ops.flash_attention import flash_attention
+from tritonclient_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_path,
+)
 
-__all__ = ["dot_product_attention", "flash_attention"]
+__all__ = ["dot_product_attention", "flash_attention", "flash_attention_path"]

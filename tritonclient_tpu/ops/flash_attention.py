@@ -366,6 +366,47 @@ def _reference_with_lse(q, k, v, causal, scale):
     return o.astype(q.dtype), jnp.transpose(lse, (0, 2, 1))
 
 
+def flash_attention_path(
+    q_shape: Tuple[int, ...],
+    k_shape: Tuple[int, ...],
+    *,
+    causal: bool = False,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: Optional[bool] = None,
+) -> str:
+    """Which implementation ``flash_attention`` runs for [B, L, H, D]
+    operands of these shapes: ``"mosaic"`` (the kernel compiled for the
+    TPU), ``"interpret"`` (the same kernel under the Pallas interpreter —
+    what ``interpret=None`` resolves to on every backend but tpu), or
+    ``"reference"`` (the materializing fallback for sequences that do not
+    tile). Serving models report this so a run can say which one it
+    measured.
+    """
+    lq, lk = q_shape[1], k_shape[1]
+    block_q = min(block_q, lq)
+    block_k = min(block_k, lk)
+    if (
+        lq % block_q
+        or lk % block_k
+        # Blocks must respect the f32 (8, 128) sublane/lane tiling: block_q
+        # is a sublane dim, block_k becomes the lane dim of the score tile
+        # (and of the lane-replicated stats tiles, hence the 128 multiple).
+        or block_q % 8
+        or block_k % 128
+        # Head dim is the lane dim of the q/k/v/acc tiles: Mosaic pads
+        # lanes to 128, which we rely on for d in {8,16,...,120}; sub-8
+        # or ragged head dims would need sublane-level padding too, so
+        # fall back there instead of gambling on lowering.
+        or q_shape[-1] % 8
+        or (causal and block_q != block_k)
+    ):
+        return "reference"
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return "interpret" if interpret else "mosaic"
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -392,28 +433,14 @@ def flash_attention(
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    lq, lk = q.shape[1], k.shape[1]
-    block_q = min(block_q, lq)
-    block_k = min(block_k, lk)
-    if (
-        lq % block_q
-        or lk % block_k
-        # Blocks must respect the f32 (8, 128) sublane/lane tiling: block_q
-        # is a sublane dim, block_k becomes the lane dim of the score tile
-        # (and of the lane-replicated stats tiles, hence the 128 multiple).
-        or block_q % 8
-        or block_k % 128
-        # Head dim is the lane dim of the q/k/v/acc tiles: Mosaic pads
-        # lanes to 128, which we rely on for d in {8,16,...,120}; sub-8
-        # or ragged head dims would need sublane-level padding too, so
-        # fall back there instead of gambling on lowering.
-        or q.shape[-1] % 8
-        or (causal and block_q != block_k)
-    ):
+    path = flash_attention_path(
+        q.shape, k.shape, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret,
+    )
+    if path == "reference":
         if return_lse:
             return _reference_with_lse(q, k, v, causal, scale)
         return dot_product_attention(q, k, v, causal=causal, scale=scale)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    o, lse = _flash(q, k, v, causal, scale, block_q, block_k, interpret)
+    o, lse = _flash(q, k, v, causal, scale, min(block_q, q.shape[1]),
+                    min(block_k, k.shape[1]), path == "interpret")
     return (o, lse) if return_lse else o

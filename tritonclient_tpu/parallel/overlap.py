@@ -39,25 +39,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tritonclient_tpu import _stepscope
 
 
-def _partial_shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axis: str):
-    """Partial-manual shard_map (only ``manual_axis`` manual, other mesh
-    axes stay under GSPMD) across the jax API generations: the top-level
-    ``jax.shard_map`` (``axis_names``/``check_vma``) when present, else
-    the ``jax.experimental`` form (``auto``/``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names={manual_axis}, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - {manual_axis}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=auto,
-    )
-
-
 def pick_chunks(d_out: int, tp: int, chunks: int) -> int:
     """Clamp a requested chunk count to what the geometry supports: each
     chunk must be a whole slice of the output dim. Returns 1 (no
@@ -105,11 +86,12 @@ def row_parallel_proj(x, w, b, *, mesh: Mesh, axis: str = "tp",
         out = parts[0] if n_chunks == 1 else jnp.concatenate(parts, axis=-1)
         return out + bl
 
-    return _partial_shard_map(
-        body, mesh,
+    # Partial-manual: only ``axis`` is manual, other mesh axes stay GSPMD.
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None), P(None)),
         out_specs=P(None, None),
-        manual_axis=axis,
+        axis_names={axis}, check_vma=False,
     )(x, w, b)
 
 
@@ -138,12 +120,12 @@ def calibrate_collective_us(mesh: Mesh, shape, dtype=jnp.float32,
     if mesh.shape.get(axis, 1) <= 1:
         return 0.0
     try:
-        fn = jax.jit(_partial_shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda t: lax.psum(t, axis),
-            mesh,
+            mesh=mesh,
             in_specs=P(None),
             out_specs=P(None),
-            manual_axis=axis,
+            axis_names={axis}, check_vma=False,
         ))
         probe = jnp.zeros(shape, dtype)
         jax.block_until_ready(fn(probe))  # compile outside the clock

@@ -173,6 +173,12 @@ def ring_attention(
     inside the chunk, logsumexp merge across chunks) instead of the
     materializing per-chunk einsum — the combination for long context, where
     neither the full sequence nor a chunk's score matrix fits HBM.
+
+    On the TPU ``impl='flash'`` does not lower under JAX 0.9.0 once a chunk
+    tiles onto the kernel: the shard_map here is partial-manual (dp/tp stay
+    automatic) and Mosaic raises "Mosaic kernels cannot be automatically
+    partitioned" (four v5e chips, PR 21; heads replicated or on tp alike).
+    It runs interpreted off-TPU; on chips use ``impl='reference'``.
     """
     if impl not in ("reference", "flash"):
         raise ValueError("impl must be 'reference' or 'flash'")

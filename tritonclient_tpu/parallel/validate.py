@@ -7,11 +7,35 @@ story end to end: tokens arrive sharded, the output parks back sharded,
 nothing congregates on one chip (SURVEY §5.7/§5.8).
 """
 
+import contextlib
 from typing import Optional
 
 import numpy as np
 
 
+@contextlib.contextmanager
+def full_matmul_precision():
+    """float32 matmuls at full precision, process-wide, while entered.
+
+    For comparing float32 models across shardings or code paths on the
+    TPU: at its default precision f32 matmuls run bfloat16 passes, so two
+    shardings of one model are two roundings (9.2e-3 apart on the sharded
+    bert_tiny below, four v5e chips) and a tight tolerance or a token
+    equality compares rounding, not function. Set through ``jax.config``
+    and not the thread-local ``jax.default_matmul_precision`` context,
+    because servers and engines trace on their own threads.
+    """
+    import jax
+
+    previous = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        yield
+    finally:
+        jax.config.update("jax_default_matmul_precision", previous)
+
+
+@full_matmul_precision()
 def serve_sharded_bert_roundtrip(mesh, seq_len: int = 64,
                                  rtol: float = 2e-4, atol: float = 2e-4,
                                  prefix: str = "msv") -> None:

@@ -2567,9 +2567,8 @@ class InferenceCore:
             # base transfer — k readback ops become 1, the win when the
             # serving host's CPU is the bottleneck. sliced parks an
             # independent device slice per member — k smaller transfers
-            # that the link runs IN PARALLEL, the win when transfer
-            # latency is the bottleneck (remote-PjRt links overlap
-            # transfers well; one big transfer is serial).
+            # that can run IN PARALLEL, the win when transfer latency
+            # and not host CPU is the bottleneck.
             shared_view = os.environ.get(
                 "TPU_SERVER_BATCH_ROWVIEW", "1") == "1"
             bases = {}
