@@ -41,6 +41,8 @@ Checks (exit 1 with one line per violation):
     quantile rows carry exactly {model, phase, stage, quantile} with
     ``stage``/``phase`` drawn from the canonical stepscope vocabularies
     (and the shared summary checks — quantile monotonicity, _sum/_count);
+    no stage row is required: a counters-mode server emits ``dispatch``
+    alone (it has no device clock), a ``sync`` one all three;
     ``nv_engine_collectives_total`` carries exactly {model, op}
   * the overlap families: ``nv_engine_collective_overlap_us_total``
     carries exactly {model, kind} with ``kind`` drawn from the canonical

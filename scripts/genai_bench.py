@@ -290,6 +290,8 @@ def main():
         reg_summary = regress.measure(8)
         phase_us = {}
         for rec in _stepscope.dump()["records"]:
+            if rec["phase"] in _stepscope.LOOP_STATES:
+                continue  # dispatches only: what the baseline's figures are
             phase_us.setdefault(rec["phase"], []).append(rec["total_us"])
         _stepscope.configure(_stepscope.MODE_OFF)
         result["stepscope_per_phase_us"] = {

@@ -1,14 +1,27 @@
 #!/usr/bin/env python
-"""Dominant-stage verdict for engine steps: dispatch-, device-, or
-collective-bound.
+"""Engine steps, loop states and request timelines from stepscope, and —
+where the dump has a device clock — the dominant-stage verdict: dispatch-,
+device-, or collective-bound.
 
 ``tail_report.py`` attributes *request* tails across serving stages;
-this report goes one level down, into the engine step records stepscope
-(``TPU_STEPSCOPE=1``) collects: host-dispatch time vs device time vs the
-clamped remainder, plus collectives charged per step. It consumes
+this report goes one level down, into what stepscope
+(``TPU_STEPSCOPE=1``) collects: per dispatch, host-dispatch time and, in
+``sync`` mode only, device time and the clamped remainder, plus collectives
+charged per step, positions computed and context held; per delivery item,
+how long it queued for the delivery thread, its readback and its hand-over;
+what the engine thread did between dispatches (``ticket_wait`` /
+``idle_wait`` / ``admit`` / ``join``); and one timeline per request
+(receipt to core to submit, wait for a slot, the prefill span split at its
+first chunk, last chunk and first token's readback, tokens, worst gap
+between two tokens). A counters-mode dump has no device clock — the engine thread's
+post-dispatch remainder is bookkeeping, and the delivery thread's
+``ready_ns`` is when *it* saw the result — so it gets the tables and no
+dispatch-/device-bound verdict. It consumes
 
 * a stepscope dump (``tritonclient_tpu._stepscope.dump()`` saved to a
-  file) — the primary input: the recent-step ring with full breakdowns;
+  file) — the primary input: the recent-step ring with full breakdowns,
+  the loop states, the delivery thread's ring and the finished requests'
+  ring;
 * a flight-recorder dump (``GET v2/debug/flight_recorder``) — retained
   records carry the slowest step's breakdown as ``step.slowest.*``
   attributes;
@@ -18,7 +31,8 @@ clamped remainder, plus collectives charged per step. It consumes
   the ``[tp-engine-stepscope]`` breakdown line.
 
 and reports, per model: per-phase step p50/p99, the mean per-step stage
-split, collectives per step, and the verdict —
+split, collectives per step, the deliveries' table, the loop states' share
+of the recorded span, the per-request table, and (``sync`` dumps) the verdict —
 
 * **dispatch-bound** — host time (dispatch + other) dominates: the
   device waits on python/trace/dispatch; batch more or trim host work;
@@ -52,6 +66,14 @@ STAGES = _stepscope.STEP_STAGES
 VERDICT_DISPATCH = "dispatch-bound"
 VERDICT_DEVICE = "device-bound"
 VERDICT_COLLECTIVE = "collective-bound"
+#: Records without a device stage (counters mode): nothing on the host's
+#: clock says when the device finished, so no bound is named.
+VERDICT_NO_DEVICE_CLOCK = "no-device-clock"
+_NO_DEVICE_CLOCK_WHY = (
+    "counters mode records no device time (TPU_STEPSCOPE=sync brackets "
+    "block_until_ready; per-executable device time is on the profile's "
+    "XLA Modules line)")
+REQUEST_ROWS = 32       # the per-request table shows the slowest waits
 
 _BENCH_TAG = "dryrun_multichip[tp-engine-stepscope]:"
 
@@ -98,10 +120,11 @@ def _records_from_flight(doc: dict) -> List[dict]:
             "step_index": int(attrs.get("step.slowest.index", 0)),
             "batch_size": int(attrs.get("step.slowest.batch_size", 0)),
             "dispatch_us": int(attrs.get("step.slowest.dispatch_us", 0)),
-            "device_us": int(attrs.get("step.slowest.device_us", 0)),
-            "other_us": int(attrs.get("step.slowest.other_us", 0)),
             "total_us": int(attrs.get("step.slowest.total_us", 0)),
             "collectives": int(attrs.get("step.slowest.collectives", 0)),
+            **{f"{stage}_us": int(attrs[f"step.slowest.{stage}_us"])
+               for stage in ("device", "other")
+               if f"step.slowest.{stage}_us" in attrs},
         })
     return out
 
@@ -114,19 +137,17 @@ def _records_from_spans(spans: List[dict]) -> List[dict]:
         attrs = s.get("attributes") or {}
         if "dispatch_us" not in attrs or "phase" not in attrs:
             continue
-        dispatch = int(attrs.get("dispatch_us", 0))
-        device = int(attrs.get("device_us", 0))
-        other = int(attrs.get("other_us", 0))
+        stages = {f"{stage}_us": int(attrs[f"{stage}_us"])
+                  for stage in STAGES if f"{stage}_us" in attrs}
         out.append({
             "model": attrs.get("model", ""),
             "phase": attrs.get("phase", "decode"),
             "step_index": int(attrs.get("step_index", 0)),
             "batch_size": int(attrs.get("batch_size", 0)),
-            "dispatch_us": dispatch,
-            "device_us": device,
-            "other_us": other,
+            "start_ns": int(s.get("start_ns", 0)),
+            **stages,
             "total_us": int(s.get("duration_ns", 0)) // 1000
-            or dispatch + device + other,
+            or sum(stages.values()),
             "collectives": int(attrs.get("collectives", 0)),
         })
     return out
@@ -134,8 +155,10 @@ def _records_from_spans(spans: List[dict]) -> List[dict]:
 
 def load_records(doc) -> List[dict]:
     """Normalize any supported input document to flat step-record dicts:
-    {model, phase, step_index, batch_size, dispatch_us, device_us,
-    other_us, total_us, collectives:int}."""
+    {model, phase, step_index, batch_size, dispatch_us, total_us,
+    collectives:int} plus device_us/other_us where the source had a
+    device clock (``sync`` mode). Loop states ride along under their own
+    phase names; ``analyze`` keeps them apart."""
     if isinstance(doc, dict) and doc.get("kind") == "stepscope":
         out = []
         for r in doc.get("records", []):
@@ -165,17 +188,36 @@ def load_compiles(doc) -> Dict[str, Dict[str, dict]]:
     return out
 
 
+def load_requests(doc) -> List[dict]:
+    """The finished requests' ring of a stepscope dump (empty for every
+    other input, and for dumps from before the ring existed)."""
+    if not (isinstance(doc, dict) and doc.get("kind") == "stepscope"):
+        return []
+    return list(doc.get("requests") or [])
+
+
+def load_deliveries(doc) -> List[dict]:
+    """The delivery thread's ring of a stepscope dump (empty for every
+    other input)."""
+    if not (isinstance(doc, dict) and doc.get("kind") == "stepscope"):
+        return []
+    return list(doc.get("deliveries") or [])
+
+
 def load_file(path: str) -> List[dict]:
     with open(path) as f:
         return load_records(json.load(f))
 
 
-def _verdict(dispatch_us: float, device_us: float, other_us: float,
-             coll_per_step: float) -> str:
+def _verdict(dispatch_us: float, device_us: Optional[float],
+             other_us: float, coll_per_step: float) -> str:
     """The decision rule: host time (dispatch + the clamped remainder)
     vs device time; device-dominant steps that issue collectives are
     collective-bound (the all-reduce wait is inside device time — there
-    is no separate collective clock)."""
+    is no separate collective clock). Without a device stage there is
+    nothing to weigh the host against."""
+    if device_us is None:
+        return VERDICT_NO_DEVICE_CLOCK
     if dispatch_us + other_us >= device_us:
         return VERDICT_DISPATCH
     if coll_per_step > 0:
@@ -183,16 +225,125 @@ def _verdict(dispatch_us: float, device_us: float, other_us: float,
     return VERDICT_DEVICE
 
 
+def _stage_means(recs: List[dict]) -> Dict[str, float]:
+    """Mean µs per stage over the records that carry it; a stage no
+    record carries (device/other in counters mode) is left out."""
+    means = {}
+    for stage in STAGES:
+        values = [int(r[f"{stage}_us"]) for r in recs
+                  if r.get(f"{stage}_us") is not None]
+        if values:
+            means[stage] = sum(values) / len(values)
+    return means
+
+
+def _loop_states(recs: List[dict]) -> Dict[str, dict]:
+    """Per loop state: stretches, their total, and its share of the span
+    the model's records cover (first start to last end)."""
+    spans = [(int(r["start_ns"]), int(r["start_ns"])
+              + 1000 * int(r.get("total_us") or r.get("dispatch_us", 0)))
+             for r in recs if r.get("start_ns")]
+    covered_us = ((max(e for _, e in spans) - min(s for s, _ in spans))
+                  / 1000 if spans else 0)
+    out = {}
+    for state in _stepscope.LOOP_STATES:
+        durations = [int(r.get("dispatch_us", 0)) for r in recs
+                     if r.get("phase") == state]
+        if durations:
+            out[state] = {
+                "n": len(durations),
+                "total_ms": round(sum(durations) / 1000, 3),
+                "share": round(sum(durations) / covered_us, 4)
+                if covered_us else 0.0,
+            }
+    return out
+
+
+def _span_ms(start: Optional[int], end: Optional[int]) -> Optional[float]:
+    if start is None or end is None:
+        return None
+    return round((end - start) / 1e6, 3)
+
+
+def _deliveries(deliveries: List[dict]) -> Dict[str, dict]:
+    """Per phase of the dispatch that made the item: how long items queued
+    for the delivery thread, the readback as that thread saw it, and the
+    hand-over to the requests (p50 / p95, ms). Never a device time."""
+    out = {}
+    for phase in sorted({d.get("phase", "") for d in deliveries}):
+        cell = {"n": 0}
+        for name, start, end in (("queue_wait", "queued_ns", "taken_ns"),
+                                 ("readback", "taken_ns", "ready_ns"),
+                                 ("handover", "ready_ns", "delivered_ns")):
+            spans = sorted(
+                ms for ms in (_span_ms(d.get(start), d.get(end))
+                              for d in deliveries if d.get("phase") == phase)
+                if ms is not None)
+            cell["n"] = max(cell["n"], len(spans))
+            cell[f"{name}_ms"] = {"p50": _percentile(spans, 0.50),
+                                  "p95": _percentile(spans, 0.95)}
+        out[phase] = cell
+    return out
+
+
+#: The ms columns of the per-request table, in the order of the timeline.
+REQUEST_SPANS = ("recv_ms", "core_ms", "wait_ms", "to_chunk_ms",
+                 "chunking_ms", "readback_ms", "handover_ms",
+                 "prefill_span_ms", "worst_gap_ms")
+
+
+def _request_rows(requests: List[dict]) -> List[dict]:
+    """One row per finished request, all spans in ms: receipt to the core
+    (``recv_ms``) and the core to the engine's submit (``core_ms``); the
+    wait for a slot and pages; the prefill span (admission to the first
+    token's hand-over) and its split at the first chunk's dispatch return,
+    the last chunk's, and the first token's readback; tokens; and the
+    worst gap between two consecutive tokens."""
+    rows = []
+    for r in requests:
+        out_ns = r.get("out_ns") or []
+        admitted = r.get("admitted_ns")
+        first_out = out_ns[0] if out_ns else None
+        gaps = [b - a for a, b in zip(out_ns, out_ns[1:])]
+        key = r.get("key") or [0, 0, 0]
+        rows.append({
+            "prompt_len": key[1], "max_new": key[2],
+            "outcome": r.get("outcome"),
+            "recv_ms": _span_ms(r.get("recv_ns"), r.get("core_ns")),
+            "core_ms": _span_ms(r.get("core_ns"), r.get("submit_ns")),
+            "wait_ms": _span_ms(r.get("submit_ns"), admitted),
+            "waited_for_pages": bool(r.get("waited_for_pages")),
+            "to_chunk_ms": _span_ms(admitted, r.get("first_chunk_ns")),
+            "chunking_ms": _span_ms(r.get("first_chunk_ns"),
+                                    r.get("last_chunk_ns")),
+            "readback_ms": _span_ms(r.get("last_chunk_ns"),
+                                    r.get("first_ready_ns")),
+            "handover_ms": _span_ms(r.get("first_ready_ns"), first_out),
+            "prefill_span_ms": _span_ms(admitted, first_out),
+            "chunks": r.get("chunks", 0),
+            "tokens": len(out_ns),
+            "worst_gap_ms": round(max(gaps) / 1e6, 3) if gaps else None,
+        })
+    return rows
+
+
 def analyze(records: List[dict],
-            compiles: Optional[Dict[str, Dict[str, dict]]] = None) -> dict:
+            compiles: Optional[Dict[str, Dict[str, dict]]] = None,
+            requests: Optional[List[dict]] = None,
+            deliveries: Optional[List[dict]] = None) -> dict:
     """Per-model verdict + per-phase quantiles and stage means; when the
     dump carries the compile plane, each model also gets its per-callable
-    cache-entry/retrace totals."""
+    cache-entry/retrace totals, and from a stepscope dump its loop states,
+    its deliveries' table and its requests' table."""
     by_model: Dict[str, List[dict]] = {}
     for r in records:
         by_model.setdefault(r.get("model", ""), []).append(r)
     models = {}
-    for model, recs in sorted(by_model.items()):
+    for model, every in sorted(by_model.items()):
+        recs = [r for r in every
+                if r.get("phase") not in _stepscope.LOOP_STATES]
+        if not recs:
+            continue
         phases = {}
         for phase in sorted({r.get("phase", "") for r in recs}):
             ph = [r for r in recs if r.get("phase", "") == phase]
@@ -202,10 +353,8 @@ def analyze(records: List[dict],
                 "n": n,
                 "p50_us": _percentile(totals, 0.50),
                 "p99_us": _percentile(totals, 0.99),
-                "mean_us": {
-                    stage: sum(int(r.get(f"{stage}_us", 0)) for r in ph) // n
-                    for stage in STAGES
-                },
+                "mean_us": {stage: int(v)
+                            for stage, v in _stage_means(ph).items()},
                 "collectives_per_step": round(
                     sum(_coll_count(r.get("collectives")) for r in ph) / n, 2
                 ),
@@ -223,12 +372,17 @@ def analyze(records: List[dict],
                 "kv_bytes_per_step": round(
                     sum(int(r.get("kv_bytes", 0)) for r in ph) / n
                 ),
+                # What the work was: positions computed and context held
+                # by the real lanes per dispatch; absent on older dumps.
+                "tokens_per_step": round(
+                    sum(int(r.get("tokens", 0)) for r in ph) / n, 1
+                ),
+                "ctx_tokens_per_step": round(
+                    sum(int(r.get("ctx_tokens", 0)) for r in ph) / n, 1
+                ),
             }
         n = len(recs)
-        means = {
-            stage: sum(int(r.get(f"{stage}_us", 0)) for r in recs) / n
-            for stage in STAGES
-        }
+        means = _stage_means(recs)
         coll = sum(_coll_count(r.get("collectives")) for r in recs) / n
         # Overlap plane (PR 13): exposed vs hidden collective time the
         # engine charged per record; absent on pre-overlap dumps.
@@ -246,9 +400,15 @@ def analyze(records: List[dict],
                 if exposed + hidden else 0.0,
             },
             "micro_steps": round(micro, 2),
-            "verdict": _verdict(means["dispatch"], means["device"],
-                                means["other"], coll),
+            "verdict": _verdict(means.get("dispatch", 0.0),
+                                means.get("device"),
+                                means.get("other", 0.0), coll),
             "phases": phases,
+            "loop_states": _loop_states(every),
+            "deliveries": _deliveries(
+                [d for d in deliveries or [] if d.get("model") == model]),
+            "requests": _request_rows(
+                [q for q in requests or [] if q.get("model") == model]),
             "compiles": dict(sorted(((compiles or {}).get(model)
                                      or {}).items())),
         }
@@ -262,12 +422,15 @@ def render(analysis: dict) -> str:
         total = max(sum(mu.values()), 1)
         shares = " ".join(
             f"{stage}={mu[stage]}us({100 * mu[stage] / total:.0f}%)"
-            for stage in STAGES
+            for stage in STAGES if stage in mu
         )
+        verdict = m["verdict"]
+        if verdict == VERDICT_NO_DEVICE_CLOCK:
+            verdict += f" ({_NO_DEVICE_CLOCK_WHY})"
         lines.append(
             f"{model}: {m['n']} steps, {shares}, "
             f"coll/step={m['collectives_per_step']} -> "
-            f"verdict: {m['verdict']}"
+            f"verdict: {verdict}"
         )
         ov = m.get("overlap") or {}
         if ov.get("exposed_us") or ov.get("hidden_us"):
@@ -290,17 +453,78 @@ def render(analysis: dict) -> str:
         lines.append(
             f"  {'phase':<10} {'n':>6} {'p50_us':>8} {'p99_us':>8} "
             f"{'dispatch':>9} {'device':>8} {'other':>7} {'coll':>6} "
-            f"{'batch':>6} {'kv_MB':>8}"
+            f"{'batch':>6} {'kv_MB':>8} {'tokens':>8} {'ctx_tok':>9}"
         )
         for phase, ph in m["phases"].items():
             pm = ph["mean_us"]
             kv_mb = ph.get("kv_bytes_per_step", 0) / 1e6
             lines.append(
                 f"  {phase:<10} {ph['n']:>6} {ph['p50_us']:>8} "
-                f"{ph['p99_us']:>8} {pm['dispatch']:>9} {pm['device']:>8} "
-                f"{pm['other']:>7} {ph['collectives_per_step']:>6} "
-                f"{ph['mean_batch']:>6} {kv_mb:>8.2f}"
+                f"{ph['p99_us']:>8} {pm.get('dispatch', '-'):>9} "
+                f"{pm.get('device', '-'):>8} {pm.get('other', '-'):>7} "
+                f"{ph['collectives_per_step']:>6} "
+                f"{ph['mean_batch']:>6} {kv_mb:>8.2f} "
+                f"{ph.get('tokens_per_step', 0):>8} "
+                f"{ph.get('ctx_tokens_per_step', 0):>9}"
             )
+        # The delivery thread's view of each dispatch's result (ms): a long
+        # queue wait says items stand behind one another, a long readback
+        # that the thread waited on the device. Not a device time.
+        for phase, cell in (m.get("deliveries") or {}).items():
+            spans = " ".join(
+                f"{name}={cell[f'{name}_ms']['p50']}/"
+                f"{cell[f'{name}_ms']['p95']}"
+                for name in ("queue_wait", "readback", "handover"))
+            lines.append(
+                f"  delivery {phase:<14} {cell['n']:>6} items, "
+                f"p50/p95 ms: {spans}"
+            )
+        # What the engine thread did between dispatches: a large
+        # ticket_wait share says the host runs ahead of the chip.
+        for state, cell in (m.get("loop_states") or {}).items():
+            lines.append(
+                f"  loop {state:<12} {cell['n']:>6} stretches "
+                f"{cell['total_ms']:>10.3f} ms "
+                f"({100 * cell['share']:.1f}% of the recorded span)"
+            )
+        rows = m.get("requests") or []
+        if rows:
+            lines.append(
+                f"  requests: {len(rows)} "
+                f"({sum(r['outcome'] == 'finished' for r in rows)} "
+                f"finished); slowest waits first"
+            )
+            # prefill_span = to_chunk + chunking + readback + handover;
+            # a wait marked * stood behind the page pool, not a slot.
+            head = " ".join(
+                f"{name[:-3].replace('prefill_span', 'prefill'):>9}"
+                for name in REQUEST_SPANS)
+            lines.append(
+                f"  {'prompt':>7} {'asked':>6} {'outcome':<10} {head} "
+                f"{'chunks':>6} {'tokens':>6}   (ms)"
+            )
+
+            def cells(row):
+                return " ".join(
+                    f"{'-' if row[name] is None else row[name]:>9}"
+                    for name in REQUEST_SPANS)
+
+            shown = sorted(rows, key=lambda r: -(r["wait_ms"] or 0))
+            for r in shown[:REQUEST_ROWS]:
+                lines.append(
+                    f"  {r['prompt_len']:>7} {r['max_new']:>6} "
+                    f"{str(r['outcome']):<10} {cells(r)} "
+                    f"{r['chunks']:>6} {r['tokens']:>6}"
+                    f"{' *' if r['waited_for_pages'] else ''}"
+                )
+            if len(rows) > REQUEST_ROWS:
+                lines.append(f"  ... {len(rows) - REQUEST_ROWS} more")
+            medians = {}
+            for name in REQUEST_SPANS:
+                values = sorted(r[name] for r in rows
+                                if r[name] is not None)
+                medians[name] = _percentile(values, 0.50) if values else None
+            lines.append(f"  {'median':>7} {'':>6} {'':<10} {cells(medians)}")
     return "\n".join(lines)
 
 
@@ -383,9 +607,10 @@ def render_bench(summary: dict) -> str:
                 f"  {label}: decode p50={row.get('p50_us')}us "
                 f"p99={row.get('p99_us')}us "
                 f"dispatch={row.get('dispatch_us')}us "
-                f"device={row.get('device_us')}us "
-                f"other={row.get('other_us')}us "
-                f"coll/step={row.get('collectives_per_step')}"
+                + "".join(f"{stage}={row[f'{stage}_us']}us "
+                          for stage in ("device", "other")
+                          if row.get(f"{stage}_us") is not None)
+                + f"coll/step={row.get('collectives_per_step')}"
                 f"{overlap} -> "
                 f"verdict: {verdict}"
             )
@@ -440,7 +665,8 @@ def _synthetic_dump(dispatch_us: int, device_us: int, other_us: int,
                          else 1_000_000),
         })
     return {
-        "kind": "stepscope", "mode": "counters", "records": records,
+        # device_us/other_us on every record: what a ``sync`` run dumps.
+        "kind": "stepscope", "mode": "sync", "records": records,
         # Compile plane: the well-bucketed shape — a handful of entries,
         # retraces = entries - 1 (each new bucket paid one compile).
         "compiles": {
@@ -575,6 +801,68 @@ def self_check() -> int:
         failures += 1
     else:
         print("self-check [compiles]: ok")
+    # A counters-mode dump: no device stage on any record, loop states in
+    # the ring, finished requests in their own. No bound may be named; the
+    # loop states stay out of the step tables; the request table renders.
+    dump = _synthetic_dump(60, 700, 20, 0)
+    dump["mode"] = "counters"
+    for r in dump["records"]:
+        del r["device_us"], r["other_us"]
+    dump["records"] += [
+        {"model": "gpt_engine", "phase": "ticket_wait", "step_index": 0,
+         "batch_size": 0, "slots": 8, "start_ns": 30_000_000,
+         "dispatch_us": 4000, "total_us": 4000, "micro_steps": 0,
+         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine"},
+        {"model": "gpt_engine", "phase": "admit", "step_index": 0,
+         "batch_size": 0, "slots": 8, "start_ns": 40_000_000,
+         "dispatch_us": 500, "total_us": 500, "micro_steps": 0,
+         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine"},
+    ]
+    dump["requests"] = [{
+        "model": "gpt_engine", "key": [7, 40, 3], "recv_ns": 900_000,
+        "core_ns": 950_000, "submit_ns": 1_000_000,
+        "admitted_ns": 3_000_000, "waited_for_pages": False,
+        "first_chunk_ns": 4_000_000, "last_chunk_ns": 5_000_000,
+        "chunks": 2, "first_ready_ns": 8_000_000,
+        "out_ns": [9_000_000, 10_000_000, 14_000_000],
+        "end_ns": 14_100_000, "outcome": "finished",
+    }]
+    for r in dump["records"]:
+        r["tokens"], r["ctx_tokens"] = 4, 400
+    dump["deliveries"] = [
+        {"model": "gpt_engine", "phase": "decode", "step_index": i,
+         "queued_ns": 1_000_000 * i, "taken_ns": 1_000_000 * i + 250_000,
+         "ready_ns": 1_000_000 * i + 750_000,
+         "delivered_ns": 1_000_000 * i + 800_000} for i in (1, 2, 3)]
+    analysis = analyze(load_records(dump), load_compiles(dump),
+                       load_requests(dump), load_deliveries(dump))
+    m = analysis["models"]["gpt_engine"]
+    rendered = render(analysis)
+    if (m["verdict"] != VERDICT_NO_DEVICE_CLOCK
+            or "device" in m["mean_us"] or m["n"] != 24
+            or "ticket_wait" in m["phases"]
+            or m["loop_states"]["ticket_wait"]["total_ms"] != 4.0
+            or m["requests"] != [{
+                "prompt_len": 40, "max_new": 3, "outcome": "finished",
+                "recv_ms": 0.05, "core_ms": 0.05, "wait_ms": 2.0,
+                "waited_for_pages": False, "to_chunk_ms": 1.0,
+                "chunking_ms": 1.0, "readback_ms": 3.0, "handover_ms": 1.0,
+                "prefill_span_ms": 6.0, "chunks": 2, "tokens": 3,
+                "worst_gap_ms": 4.0}]
+            or m["phases"]["decode"]["ctx_tokens_per_step"] != 400
+            or m["deliveries"] != {"decode": {
+                "n": 3, "queue_wait_ms": {"p50": 0.25, "p95": 0.25},
+                "readback_ms": {"p50": 0.5, "p95": 0.5},
+                "handover_ms": {"p50": 0.05, "p95": 0.05}}}
+            or "records no device time" not in rendered
+            or "loop ticket_wait" not in rendered
+            or "delivery decode" not in rendered
+            or "worst_gap" not in rendered or "median" not in rendered):
+        print("self-check [counters]: device clock invented, or loop "
+              "states / deliveries / requests lost", file=sys.stderr)
+        failures += 1
+    else:
+        print("self-check [counters]: ok")
     # Compare mode renders ratios for shared phases, with the overlap
     # column when either side charged exposed time.
     a = analyze(load_records(_synthetic_dump(60, 200, 20, 0)))
@@ -657,7 +945,8 @@ def main(argv=None) -> int:
         print(f"{args.dump_file}: no step records (is TPU_STEPSCOPE on?)",
               file=sys.stderr)
         return 1
-    analysis = analyze(records, load_compiles(doc))
+    analysis = analyze(records, load_compiles(doc), load_requests(doc),
+                       load_deliveries(doc))
     if args.compare:
         try:
             with open(args.compare) as f:

@@ -583,3 +583,132 @@ def test_named_locks_registered():
     cache.release_block(bid)
     assert cache.evictable_count == 1
     assert cache.evict_lru() is not None
+
+
+# --------------------------------------------------------------------------- #
+# names on the device trace, and the request timeline through the server      #
+# --------------------------------------------------------------------------- #
+
+
+def test_engine_executables_are_jitted_under_their_own_names(tiny):
+    """The HLO modules (and with them the profile's `XLA Modules` line) read
+    jit_decode_step / jit_decode_fused_<n> / jit_prefill_chunk, where bare
+    functools.partials gave jit(<unknown>) for all three."""
+    import jax.numpy as jnp
+
+    cfg, params = tiny
+    engine = GenerationEngine(cfg, params, max_slots=2, prefill_chunk=8)
+    try:
+        bank = (engine.params, engine._k, engine._v, engine._btabs,
+                engine._tokens, engine._pos, engine._seeds, engine._steps,
+                engine._temps, engine._topks)
+        z = jnp.zeros((1,), jnp.int32)
+        chunk = (engine.params, engine._k, engine._v,
+                 jnp.zeros((1, 8), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+                 z, jnp.ones((1,), jnp.int32), z,
+                 jnp.zeros((1,), jnp.float32), z)
+        lowered = {
+            "jit_decode_step": engine._step.lower(*bank),
+            "jit_decode_fused_4": engine._multi_step_fn(4).lower(*bank),
+            "jit_decode_fused_2": engine._multi_step_fn(2).lower(*bank),
+            "jit_prefill_chunk": engine._prefill_chunk_fn.lower(*chunk),
+            "jit__advance_slot_clocks": engine._advance.lower(
+                engine._pos, engine._steps),
+        }
+        for name, low in lowered.items():
+            assert f"module @{name} " in low.as_text()[:200], name
+    finally:
+        engine.shutdown()
+
+
+def test_the_named_wrappers_find_the_step_functions_when_traced(
+        tiny, monkeypatch):
+    """A step function replaced by module attribute (the benchmark's fault
+    tests do) is what a new engine traces, fused path included."""
+    from tritonclient_tpu.models import gpt_engine
+
+    cfg, params = tiny
+    produce = gpt_engine._decode_step_paged
+    calls = []
+
+    def spy(*args, **kwargs):
+        calls.append(1)
+        return produce(*args, **kwargs)
+
+    monkeypatch.setattr(gpt_engine, "_decode_step_paged", spy)
+    engine = GenerationEngine(cfg, params, max_slots=2, prefill_chunk=8)
+    try:
+        prompt = np.arange(1, 10, dtype=np.int32).reshape(1, 9)
+        assert _collect(engine.submit(prompt, 8)) == _reference(
+            params, prompt, 8, cfg)
+    finally:
+        engine.shutdown()
+    assert calls     # traced through the module attribute, not a captured one
+
+
+def test_request_timeline_carries_the_servers_receipt_stamps(tiny):
+    """Through the gRPC front end the core hands the request's TraceContext
+    timeline down beside the cancel event: the engine's record of the
+    request starts at the wire, recv <= core <= submit. With stepscope off
+    nothing is stamped and nothing is kept."""
+    from tritonclient_tpu import _stepscope
+    from tritonclient_tpu.models.gpt_engine import GptEngineModel
+    from tritonclient_tpu.server import InferenceServer
+    import tritonclient_tpu.grpc as grpcclient
+
+    cfg, _params = tiny
+    model = GptEngineModel(cfg=cfg, max_slots=2, prefill_chunk=8)
+    prev = _stepscope.mode()
+
+    def generate(server, n_tokens):
+        done: "queue.Queue" = queue.Queue()
+        client = grpcclient.InferenceServerClient(server.grpc_address)
+        client.start_stream(callback=lambda result, error: done.put(
+            (result, error)))
+        try:
+            # Another prompt per call: no prefix-cache hit shortens it.
+            prompt = np.arange(n_tokens, n_tokens + 19,
+                               dtype=np.int32).reshape(1, 19)
+            inputs = [grpcclient.InferInput("INPUT_IDS", [1, 19], "INT32"),
+                      grpcclient.InferInput("MAX_TOKENS", [1], "INT32")]
+            inputs[0].set_data_from_numpy(prompt)
+            inputs[1].set_data_from_numpy(np.array([n_tokens], np.int32))
+            sent_ns = time.monotonic_ns()
+            client.async_stream_infer(model.name, inputs,
+                                      enable_empty_final_response=True)
+            tokens = 0
+            while True:
+                result, error = done.get(timeout=120)
+                assert error is None, error
+                out = result.as_numpy("OUTPUT_IDS")
+                tokens += int(out is not None and out.size > 0)
+                final = result.get_response().parameters.get(
+                    "triton_final_response")
+                if final is not None and final.bool_param:
+                    return sent_ns, tokens
+        finally:
+            client.stop_stream()
+            client.close()
+
+    try:
+        with InferenceServer(models=[model], http=False) as server:
+            _stepscope.configure(_stepscope.MODE_OFF)
+            _stepscope.reset()
+            assert generate(server, 3)[1] == 3
+            doc = _stepscope.dump()
+            assert doc["records"] == [] and doc["requests"] == []
+            _stepscope.configure(_stepscope.MODE_COUNTERS)
+            sent_ns, tokens = generate(server, 5)
+            assert tokens == 5
+            deadline = time.time() + 30
+            while time.time() < deadline and not _stepscope.dump()["requests"]:
+                time.sleep(0.02)  # tpulint: disable=TPU001
+            (record,) = _stepscope.dump()["requests"]
+    finally:
+        _stepscope.configure(prev)
+        _stepscope.reset()
+    assert record["key"][1:] == [19, 5] and record["chunks"] == 3
+    assert sent_ns <= record["recv_ns"] <= record["core_ns"] \
+        <= record["submit_ns"] <= record["admitted_ns"]
+    assert len(record["out_ns"]) == 5
+    assert record["outcome"] == "finished"

@@ -1,7 +1,9 @@
-"""stepscope: the engine-step profiling plane — per-step dispatch /
-device / other attribution, collective counting, and its three sinks
-(/metrics summary families, flight-recorder slowest-step stamps, Perfetto
-thread tracks) plus the ``step_report.py`` verdict on top.
+"""stepscope: the engine-step profiling plane — per-dispatch records
+(dispatch time, what the work was, the delivery thread's stamps; a device
+stage in ``sync`` mode only), the engine-loop states, one timeline per
+request, collective counting, and its three sinks (/metrics summary
+families, flight-recorder slowest-step stamps, Perfetto thread tracks)
+plus ``step_report.py`` on top.
 
 Deterministic: engines run greedy decoding on the virtual CPU mesh with
 seeded params, and the synthetic-record tests use fixed timings.
@@ -114,15 +116,20 @@ def test_expected_tp_collectives():
 
 
 # --------------------------------------------------------------------------- #
-# engine at c4: records partition the compute span                            #
+# engine at c4: dispatch records, and no device stage in counters mode        #
 # --------------------------------------------------------------------------- #
 
 
-def test_engine_c4_records_partition_compute_span():
-    """Four concurrent generations through the engine: every step record's
-    stages partition its span (dispatch + device + other == total, all
-    clamped non-negative), decode and prefill both appear, and occupancy
-    never exceeds the slot count."""
+def _dispatches(records):
+    return [r for r in records if r["phase"] in _stepscope.STEP_PHASES]
+
+
+def test_engine_c4_counters_records_carry_no_device_stage():
+    """Four concurrent generations through the engine in counters mode: no
+    record pretends to a device time (the engine thread's post-dispatch
+    remainder is bookkeeping, not the device), dispatch fits inside the
+    step's span, decode and prefill both appear, and occupancy never
+    exceeds the slot count."""
     from tritonclient_tpu.models.gpt_engine import GenerationEngine
 
     _stepscope.configure(_stepscope.MODE_COUNTERS)
@@ -138,21 +145,18 @@ def test_engine_c4_records_partition_compute_span():
 
     doc = _stepscope.dump()
     assert doc["kind"] == "stepscope"
-    records = doc["records"]
+    assert all("device_us" not in r and "other_us" not in r
+               for r in doc["records"])
+    records = _dispatches(doc["records"])
     phases = {r["phase"] for r in records}
     assert _stepscope.PHASE_PREFILL_CHUNK in phases
     assert _stepscope.PHASE_DECODE in phases
     for r in records:
-        assert r["dispatch_us"] >= 0
-        assert r["device_us"] >= 0
-        assert r["other_us"] >= 0
-        # Counters mode: device is the clamped remainder, so the stages
-        # partition the step span exactly (up to the ns->us floor).
-        assert (
-            abs(r["dispatch_us"] + r["device_us"] + r["other_us"]
-                - r["total_us"]) <= 2
-        )
+        assert 0 <= r["dispatch_us"] <= r["total_us"]
         assert 0 <= r["batch_size"] <= r["slots"] == 4
+    stages = {stage for _, _, stage, *_ in
+              _stepscope.metrics_snapshot((0.5,))[0]}
+    assert stages == {_stepscope.STAGE_DISPATCH}
     decode = [r for r in records if r["phase"] == _stepscope.PHASE_DECODE]
     # Step indices are the engine loop's own sequence: strictly increasing.
     idx = [r["step_index"] for r in decode]
@@ -175,7 +179,7 @@ def test_sync_mode_measures_device_stage():
         _drain(engine, _PROMPTS_C4[:2], 4)
     finally:
         engine.shutdown()
-    records = _stepscope.dump()["records"]
+    records = _dispatches(_stepscope.dump()["records"])
     assert records
     for r in records:
         assert r["dispatch_us"] >= 0
@@ -183,6 +187,9 @@ def test_sync_mode_measures_device_stage():
         assert r["other_us"] >= 0
         assert r["dispatch_us"] + r["device_us"] + r["other_us"] \
             <= r["total_us"] + 2
+    stages = {stage for _, _, stage, *_ in
+              _stepscope.metrics_snapshot((0.5,))[0]}
+    assert stages == set(_stepscope.STEP_STAGES)
 
 
 def test_tp_engine_collectives_match_expected_per_step():
@@ -260,18 +267,24 @@ def test_note_collective_charges_active_step():
 # --------------------------------------------------------------------------- #
 
 
-def test_metrics_snapshot_and_exposition():
+@pytest.mark.parametrize("mode", [_stepscope.MODE_COUNTERS,
+                                  _stepscope.MODE_SYNC])
+def test_metrics_snapshot_and_exposition(mode):
     """The summary/counter families built from a live snapshot pass the
-    exposition checker, including the stepscope label-set rules."""
+    exposition checker, including the stepscope label-set rules. A
+    counters-mode server emits the dispatch stage alone; a ``sync`` one
+    (the step waited on a real array) all three."""
     from tritonclient_tpu.server import InferenceServer
 
-    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.configure(mode)
     _stepscope.reset()
     rec = _stepscope.step_begin("gpt", _stepscope.PHASE_DECODE, 0,
                                 batch_size=2, slots=4)
     _stepscope.step_dispatched(rec)
     _stepscope.note_collective("psum", count=4)
-    _stepscope.step_end(rec)
+    _stepscope.step_end(rec, outputs=jax.numpy.zeros((2,)))
+    assert ("device_us" in _stepscope.dump()["records"][0]) \
+        == (mode == _stepscope.MODE_SYNC)
 
     import urllib.request
 
@@ -282,6 +295,8 @@ def test_metrics_snapshot_and_exposition():
     assert _stepscope.STEP_METRIC in text
     assert _stepscope.COLLECTIVES_METRIC in text
     assert 'stage="dispatch"' in text
+    assert ('stage="device"' in text) == (mode == _stepscope.MODE_SYNC)
+    assert ('stage="other"' in text) == (mode == _stepscope.MODE_SYNC)
     assert 'op="psum"' in text
     checker = _load_script("check_metrics_exposition.py", "cm_stepscope")
     assert checker.check_exposition(text) == []
@@ -338,6 +353,7 @@ def test_flight_attributes_stamp_slowest_step():
             time.sleep(0.02)  # tpulint: disable=TPU001 - sync test, no loop
         _stepscope.step_end(rec)
     attrs = _stepscope.flight_attributes("gpt")
+    assert "step.slowest.device_us" not in attrs     # counters: no device clock
     assert attrs["step.slowest.index"] == 1
     assert attrs["step.slowest.phase"] == _stepscope.PHASE_DECODE
     assert attrs["step.slowest.batch_size"] == 3
@@ -377,12 +393,16 @@ def test_perfetto_events_load_as_orphan_tracks():
 # --------------------------------------------------------------------------- #
 
 
-def test_step_report_verdict_from_engine_dump():
-    """End to end: drive the engine at c4, dump, and the report renders a
-    dominant-stage verdict for the engine's scope."""
+@pytest.mark.parametrize("mode", [_stepscope.MODE_COUNTERS,
+                                  _stepscope.MODE_SYNC])
+def test_step_report_verdict_from_engine_dump(mode):
+    """End to end: drive the engine at c4, dump, and the report renders the
+    engine's scope: a dominant-stage verdict from a ``sync`` dump, none
+    (and the reason) from a counters one, which has no device clock; the
+    loop states and the request table beside the step table either way."""
     from tritonclient_tpu.models.gpt_engine import GenerationEngine
 
-    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.configure(mode)
     _stepscope.reset()
     cfg = gpt.gpt_tiny(max_len=32)
     params = gpt.init_params(jax.random.PRNGKey(0), cfg)
@@ -393,14 +413,48 @@ def test_step_report_verdict_from_engine_dump():
         engine.shutdown()
     doc = _stepscope.dump()
     step_report = _load_script("step_report.py", "step_report_e2e")
-    analysis = step_report.analyze(step_report.load_records(doc))
+    analysis = step_report.analyze(
+        step_report.load_records(doc),
+        requests=step_report.load_requests(doc),
+        deliveries=step_report.load_deliveries(doc))
     model = analysis["models"]["gpt_engine"]
-    assert model["verdict"] in (
-        step_report.VERDICT_DISPATCH, step_report.VERDICT_DEVICE,
-        step_report.VERDICT_COLLECTIVE,
-    )
     rendered = step_report.render(analysis)
+    if mode == _stepscope.MODE_SYNC:
+        assert model["verdict"] in (
+            step_report.VERDICT_DISPATCH, step_report.VERDICT_DEVICE,
+            step_report.VERDICT_COLLECTIVE,
+        )
+    else:
+        assert model["verdict"] == step_report.VERDICT_NO_DEVICE_CLOCK
+        assert "device" not in model["mean_us"]
+        assert "records no device time" in rendered
     assert "verdict:" in rendered and "decode" in rendered
+    # Loop states are not steps: out of the phase table, in their own rows.
+    assert not set(model["phases"]) & set(_stepscope.LOOP_STATES)
+    assert model["n"] == len(_dispatches(doc["records"]))
+    assert set(model["loop_states"]) <= set(_stepscope.LOOP_STATES)
+    assert model["loop_states"] and "  loop " in rendered
+    rows = model["requests"]
+    assert len(rows) == 4 and all(r["tokens"] == 6 for r in rows)
+    assert all(r["outcome"] == "finished" and r["wait_ms"] >= 0
+               and r["worst_gap_ms"] >= 0 for r in rows)
+    # The prefill span's four parts are its whole, request by request.
+    for r in rows:
+        parts = [r[k] for k in ("to_chunk_ms", "chunking_ms", "readback_ms",
+                                "handover_ms")]
+        assert min(parts) >= 0
+        assert abs(sum(parts) - r["prefill_span_ms"]) < 0.01
+        assert r["recv_ms"] is None and r["core_ms"] is None  # no server
+    assert "worst_gap" in rendered and "  median" in rendered
+    # What the work was, and the delivery thread's view of its result.
+    decode = model["phases"]["decode"]
+    assert decode["tokens_per_step"] > 0 and decode["ctx_tokens_per_step"] > 0
+    assert set(model["deliveries"]) == {"decode", "prefill_chunk"}
+    for cell in model["deliveries"].values():
+        assert cell["n"] > 0
+        assert all(cell[k]["p50"] >= 0 for k in
+                   ("queue_wait_ms", "readback_ms", "handover_ms"))
+    assert "  delivery decode" in rendered
 
 
 def test_step_report_self_check_passes(capsys):
@@ -453,3 +507,322 @@ def test_cancel_event_carries_steps_completed():
     steps = getattr(ev, "steps_completed", None)
     assert steps is not None and steps >= 5
     assert steps == got
+
+
+# --------------------------------------------------------------------------- #
+# one timeline per request, one per dispatch, and the loop states             #
+# --------------------------------------------------------------------------- #
+
+_CHUNK = 8
+
+
+def _timeline_run(prompt_lens, max_new, mode=_stepscope.MODE_COUNTERS):
+    """A traced run of a chunked-prefill engine; returns (results, dump)."""
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    _stepscope.configure(mode)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=128)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=4, prefill_chunk=_CHUNK)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, (1, n)).astype(np.int32)
+               for n in prompt_lens]
+    try:
+        results = _drain(engine, prompts, max_new)
+    finally:
+        engine.shutdown()
+    return prompts, results, _stepscope.dump()
+
+
+def test_every_finished_request_leaves_one_ordered_timeline():
+    """Six requests over four slots (two wait for a slot), prompts of two to
+    five chunks: exactly one record each, every stamp in the order the work
+    happened, one hand-over stamp per token, the chunk count of the prompt,
+    and the key a reader outside the server can compute."""
+    import zlib
+
+    lens = [19, 23, 31, 37, 12, 40]
+    prompts, results, doc = _timeline_run(lens, 9)
+    assert all(len(r) == 9 for r in results)
+    requests = doc["requests"]
+    assert len(requests) == len(lens)
+    by_key = {tuple(q["key"]): q for q in requests}
+    assert set(by_key) == {
+        (zlib.crc32(p.tobytes()), p.shape[1], 9) for p in prompts}
+    for prompt in prompts:
+        q = by_key[(zlib.crc32(prompt.tobytes()), prompt.shape[1], 9)]
+        assert q["outcome"] == _stepscope.OUTCOME_FINISHED
+        assert q["model"] == "gpt_engine"
+        assert q["recv_ns"] is None and q["core_ns"] is None  # no server
+        assert len(q["out_ns"]) == 9
+        assert q["chunks"] == -(-prompt.shape[1] // _CHUNK)
+        order = [q["submit_ns"], q["admitted_ns"], q["first_chunk_ns"],
+                 q["last_chunk_ns"], q["first_ready_ns"], *q["out_ns"],
+                 q["end_ns"]]
+        assert order == sorted(order), q
+        assert q["waited_for_pages"] is False
+    # Two of six had to wait for a slot: their admission came after some
+    # other request's last token.
+    firsts_end = sorted(q["end_ns"] for q in requests)[0]
+    assert sum(q["admitted_ns"] > firsts_end for q in requests) >= 2
+
+
+def test_a_request_that_waited_for_pages_says_so():
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=64)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    # Pages for exactly one full-budget request: the second parks.
+    engine = GenerationEngine(cfg, params, max_slots=2, n_blocks=5)
+    try:
+        results = _drain(engine, [_PROMPTS_C4[0], _PROMPTS_C4[1]], 50)
+    finally:
+        engine.shutdown()
+    assert [len(r) for r in results] == [50, 50]
+    waited = [q["waited_for_pages"] for q in _stepscope.dump()["requests"]]
+    assert sorted(waited) == [False, True]
+
+
+def test_a_cancelled_request_leaves_a_cancelled_timeline():
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=64)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=2)
+    ev = threading.Event()
+    try:
+        q = engine.submit(_PROMPTS_C4[0], 40, cancel_event=ev).out
+        got = 0
+        while got < 3:
+            assert q.get(timeout=120) is not None
+            got += 1
+        ev.set()
+        while q.get(timeout=120) is not None:
+            got += 1
+    finally:
+        engine.shutdown()
+    (record,) = _stepscope.dump()["requests"]
+    assert record["outcome"] == _stepscope.OUTCOME_CANCELLED
+    assert len(record["out_ns"]) == got < 40
+    assert record["end_ns"] >= record["out_ns"][-1]
+
+
+def _delivery_of(doc):
+    """(phase, step_index) -> the delivery record of that dispatch."""
+    by_step = {(d["phase"], d["step_index"]): d for d in doc["deliveries"]}
+    assert len(by_step) == len(doc["deliveries"])      # one per dispatch
+    assert all(d["model"] == "gpt_engine" for d in doc["deliveries"])
+    return by_step
+
+
+def test_dispatch_records_say_what_the_work_was_and_when_it_reached_the_host():
+    """Every decode record: tokens == batch x micro-steps over the whole
+    bank's table, and one delivery record with the delivery thread's four
+    stamps in order. Prefill chunks: the padded lane bucket and context
+    bucket, the positions computed; only a chunk that finished a prompt
+    has a delivery record."""
+    lens = [19, 23, 31, 37]
+    prompts, _, doc = _timeline_run(lens, 9)
+    records = _dispatches(doc["records"])
+    delivery_of = _delivery_of(doc)
+    decode = [r for r in records if r["phase"] == _stepscope.PHASE_DECODE]
+    assert decode
+    for r in decode:
+        d = delivery_of[(r["phase"], r["step_index"])]
+        assert d["queued_ns"] <= d["taken_ns"] <= d["ready_ns"] \
+            <= d["delivered_ns"]
+        assert r["start_ns"] <= d["queued_ns"]
+        assert r["tokens"] == r["batch_size"] * r["micro_steps"]
+        assert r["lanes"] == r["slots"] == 4
+        assert r["ctx_blocks"] == 128 // 16
+        # every active slot holds at least its prompt and the first token
+        assert r["ctx_tokens"] >= r["batch_size"] * (min(lens) + 1)
+    assert sum(r["tokens"] for r in decode) >= len(lens) * (9 - 1)
+    chunks = [r for r in records
+              if r["phase"] == _stepscope.PHASE_PREFILL_CHUNK]
+    assert sum(r["tokens"] for r in chunks) == sum(lens)
+    for r in chunks:
+        assert r["batch_size"] <= r["lanes"] <= 4
+        assert r["lanes"] & (r["lanes"] - 1) == 0          # a power of two
+        assert 0 < r["tokens"] <= r["batch_size"] * _CHUNK
+        assert r["tokens"] <= r["ctx_tokens"] <= r["lanes"] * \
+            r["ctx_blocks"] * 16
+    with_delivery = [delivery_of[(r["phase"], r["step_index"])]
+                     for r in chunks
+                     if (r["phase"], r["step_index"]) in delivery_of]
+    # 19 tokens in chunks of 8: the first two chunk dispatches finish no
+    # prompt, so they have no delivery record and no readback was added to
+    # observe them.
+    assert len(with_delivery) < len(chunks)
+    assert 1 <= len(with_delivery) <= len(lens)
+    assert len(doc["deliveries"]) == len(decode) + len(with_delivery)
+    for d in with_delivery:
+        assert d["queued_ns"] <= d["taken_ns"] <= d["ready_ns"] \
+            <= d["delivered_ns"]
+    # A request's first token became ready on the item that finished it.
+    ready = {d["ready_ns"] for d in with_delivery}
+    assert {q["first_ready_ns"] for q in doc["requests"]} <= ready
+    # Dispatch records enter the ring as they end, in the order dispatched.
+    starts = [r["start_ns"] for r in records]
+    assert starts == sorted(starts)
+
+
+def test_loop_states_enter_the_ring_and_nothing_else():
+    """ticket_wait / idle_wait / admit are records in the ring with the
+    harness's fields, overlap neither a dispatch's bracket nor each other,
+    and reach no sketch, no count and no /metrics row."""
+    _, _, doc = _timeline_run([19, 23, 31, 37, 12, 40], 9)
+    loops = [r for r in doc["records"]
+             if r["phase"] in _stepscope.LOOP_STATES]
+    assert {r["phase"] for r in loops} >= {_stepscope.LOOP_ADMIT,
+                                           _stepscope.LOOP_JOIN}
+    # one join per dispatch that finished a prompt, right behind it
+    joins = [r for r in loops if r["phase"] == _stepscope.LOOP_JOIN]
+    delivery_of = _delivery_of(doc)
+    finishing = [r for r in _dispatches(doc["records"])
+                 if r["phase"] == _stepscope.PHASE_PREFILL_CHUNK
+                 and (r["phase"], r["step_index"]) in delivery_of]
+    assert len(joins) == len(finishing)
+    for join, chunk in zip(sorted(joins, key=lambda r: r["start_ns"]),
+                           sorted(finishing, key=lambda r: r["start_ns"])):
+        queued_ns = delivery_of[(chunk["phase"],
+                                 chunk["step_index"])]["queued_ns"]
+        assert chunk["start_ns"] + 1000 * chunk["total_us"] \
+            <= join["start_ns"] + 1000
+        assert join["start_ns"] <= queued_ns \
+            <= join["start_ns"] + 1000 * (join["dispatch_us"] + 1)
+    for r in loops:
+        assert r["model"] == "gpt_engine" and r["slots"] == 4
+        assert r["batch_size"] == 0 and r["micro_steps"] == 0
+        assert r["dispatch_us"] >= 0 and r["start_ns"] > 0
+        assert "device_us" not in r
+    assert not set(_stepscope.STEP_PHASES) & set(_stepscope.LOOP_STATES)
+    # What the harness labels a gap by: [start, start + dispatch_us).
+    spans = sorted((r["start_ns"], r["start_ns"] + 1000 * r["dispatch_us"],
+                    r["phase"]) for r in doc["records"]
+                   if r["thread_name"] == "gpt-engine")
+    for (_, end, a), (start, _, b) in zip(spans, spans[1:]):
+        assert end <= start, (a, b, end - start)
+    step_rows, _ = _stepscope.metrics_snapshot((0.5,))
+    assert {phase for _, phase, *_ in step_rows} <= set(
+        _stepscope.STEP_PHASES)
+    assert not [k for k in doc["step_counts"]
+                if k.split("|")[1] in _stepscope.LOOP_STATES]
+    assert _stepscope.flight_attributes("gpt_engine")[
+        "step.slowest.phase"] in _stepscope.STEP_PHASES
+    # The Perfetto sink shows them on the engine thread's track.
+    names = {e["name"].split("[")[0] for e in _stepscope.perfetto_events(0)
+             if e.get("ph") == "X"}
+    assert "gpt_engine/admit" in names
+
+
+def test_idle_wait_is_recorded_when_the_engine_parks():
+    """An engine with nothing to do waits on its condition: one idle_wait
+    stretch from the last token to the next submit."""
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+    import time
+
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=32)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=2)
+    try:
+        for _ in range(2):      # every executable compiled before the pause
+            _drain(engine, _PROMPTS_C4[:1], 3)
+        time.sleep(0.3)  # tpulint: disable=TPU001 - sync test, no loop
+        _drain(engine, _PROMPTS_C4[:1], 3)
+    finally:
+        engine.shutdown()
+    idle = [r for r in _stepscope.dump()["records"]
+            if r["phase"] == _stepscope.LOOP_IDLE_WAIT]
+    assert idle and max(r["dispatch_us"] for r in idle) >= 150_000
+
+
+def test_stepscope_off_stamps_nothing():
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    cfg = gpt.gpt_tiny(max_len=32)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=2)
+    try:
+        req = engine.submit(_PROMPTS_C4[0], 4)
+        assert req.span is None
+        while req.out.get(timeout=120) is not None:
+            pass
+    finally:
+        engine.shutdown()
+    doc = _stepscope.dump()
+    assert doc["records"] == [] and doc["requests"] == []
+    assert doc["deliveries"] == []
+    assert doc["step_counts"] == {}
+    assert _stepscope.request_begin("m", _PROMPTS_C4[0], 4) is None
+    _stepscope.request_end(None, _stepscope.OUTCOME_FINISHED)
+    assert _stepscope.delivery_begin(None) is None
+    _stepscope.delivery_end(None)
+    _stepscope.step_abandon()
+    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, 1, 2)
+    assert _stepscope.dump()["records"] == []
+
+
+def test_a_dispatch_that_raises_leaves_no_step_open(monkeypatch):
+    """``step_abandon`` closes the open step's annotation and drops the
+    record; the engine's failure handler calls it, so a decode dispatch
+    that raises ends the request with an error and leaves no decode record
+    and no annotation entered on the engine thread."""
+    from tritonclient_tpu.models import gpt_engine
+
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    rec = _stepscope.step_begin("m", _stepscope.PHASE_DECODE, 0)
+    assert rec._annotation is not None and _stepscope._tls.active is rec
+    _stepscope.step_abandon()
+    assert rec._annotation is None and _stepscope._tls.active is None
+    assert _stepscope.dump()["records"] == []
+
+    closed = []
+    close = _stepscope._close_annotation
+    monkeypatch.setattr(_stepscope, "_close_annotation",
+                        lambda r: (closed.append(r.phase), close(r)))
+
+    def fail(*args, **kwargs):
+        raise RuntimeError("dispatch failed")
+
+    monkeypatch.setattr(gpt_engine, "_decode_step_paged", fail)
+    monkeypatch.setattr(gpt_engine, "_decode_multi_step_paged", fail)
+    cfg = gpt.gpt_tiny(max_len=32)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = gpt_engine.GenerationEngine(cfg, params, max_slots=2)
+    try:
+        out = engine.submit(_PROMPTS_C4[0], 4).out
+        items = [out.get(timeout=120)]          # the prefill's first token
+        while not isinstance(items[-1], BaseException):
+            assert items[-1] is not None
+            items.append(out.get(timeout=120))
+        assert "dispatch failed" in str(items[-1])
+    finally:
+        engine.shutdown()
+    doc = _stepscope.dump()
+    assert _stepscope.PHASE_DECODE not in {r["phase"] for r in doc["records"]}
+    # the decode step's annotation was closed, by the failure handler
+    assert closed[-1] == _stepscope.PHASE_DECODE
+    (request,) = doc["requests"]
+    assert request["outcome"] == _stepscope.OUTCOME_ERROR
+
+
+def test_request_ring_takes_its_length_from_the_step_ring(monkeypatch):
+    monkeypatch.setenv("TPU_STEPSCOPE_RING", "3")
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    for i in range(5):
+        rec = _stepscope.request_begin("m", np.array([[i]], np.int32), 1)
+        _stepscope.request_end(rec, _stepscope.OUTCOME_FINISHED)
+        _stepscope.request_end(rec, _stepscope.OUTCOME_ERROR)  # once only
+    requests = _stepscope.dump()["requests"]
+    assert len(requests) == 3
+    assert {q["outcome"] for q in requests} == {_stepscope.OUTCOME_FINISHED}

@@ -2,20 +2,31 @@
 
 The observability stack stops at ``compute``: a request span says how long
 the model ran, not where an engine *step* spent its time. This module is
-the missing layer — a low-overhead step clock the decode/prefill loops in
-``models/gpt_engine.py`` and the dynamic batcher's compute phase bracket
-around each device dispatch. Every step yields a record carrying:
+the missing layer — a low-overhead clock the decode/prefill loops in
+``models/gpt_engine.py`` and the dynamic batcher's compute phase stamp
+where the work happens. All times are ``time.monotonic_ns()``. It keeps
+three rings (``dump()["records"]``, ``dump()["deliveries"]`` and
+``dump()["requests"]``):
 
-- step index, phase (``prefill`` / ``decode`` / ``compute``), batch size
-  and slot occupancy;
+**Dispatch records** — one per device dispatch, opened on the dispatching
+thread:
+
+- step index, phase (``prefill`` / ``prefill_chunk`` / ``decode`` /
+  ``compute``), batch size and slot occupancy;
+- what the work was: ``lanes`` (the padded lane bucket; ``max_slots`` for
+  decode), ``ctx_blocks`` (the block-table width the executable gathers),
+  ``tokens`` (positions computed) and ``ctx_tokens`` (context really held
+  by the real lanes, from host-side state);
 - ``dispatch_us``: host time from step begin to dispatch return (trace +
-  XLA dispatch of the jitted call);
-- ``device_us``: device time. In ``sync`` mode this is a bracketed
-  ``jax.block_until_ready`` measurement (true device wait); in the default
-  counters mode it is the wall-clock remainder of the step — a lower
-  bound that never perturbs the host/device overlap being measured;
-- ``other_us``: the clamped remainder (host bookkeeping, delivery
-  hand-off);
+  XLA dispatch of the jitted call); the same bracket is a
+  ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
+  opened in Perfetto or TensorBoard shows the host span beside the
+  device's module;
+- ``device_us`` / ``other_us``: **``sync`` mode only** — a bracketed
+  ``jax.block_until_ready`` (true device wait) and the clamped remainder.
+  Counters mode has no device clock: the engine thread's post-dispatch
+  remainder is a few microseconds of bookkeeping, so the record carries
+  neither field and ``/metrics`` no ``device``/``other`` stage;
 - collective count/bytes, accumulated by ``note_collective`` at the
   ``parallel/`` call sites through a thread-local step context, or charged
   as an expected per-step count for GSPMD-implicit all-reduces
@@ -29,24 +40,47 @@ around each device dispatch. Every step yields a record carrying:
   calibrates once on the live mesh, and ``step_report.py --compare`` shows
   the exposed column before/after.
 
+**Delivery records** — one per delivery item, written by the engine's
+delivery thread when it is done with the item, and joined to the dispatch
+that made the item by ``(model, phase, step_index)``: ``queued_ns``,
+``taken_ns``, ``ready_ns`` (readback returned), ``delivered_ns`` (last
+token handed to its request). ``ready_ns`` is when *that thread saw* the
+result, in the order it serves its two queues — NOT the device's
+completion time; no device time may be derived from it. A dispatch with no
+delivery item (a prefill chunk that finishes no prompt) has no delivery
+record, and nothing is added to observe it.
+
+**Engine-loop states** (``LOOP_STATES``: ``ticket_wait``, ``idle_wait``,
+``admit``, ``join``) — what the engine thread did between dispatches, as
+records in the same ring with ``dispatch_us`` = their duration. They overlap
+neither a dispatch record nor each other, and reach the ring only: no
+sketch, no ``/metrics`` row.
+
+**Request records** — one per generation, written once by the thread that
+ends it: receipt and core stamps copied from the request's
+``TraceContext``, then submit, admission, first/last prefill chunk, first
+token ready, every token's hand-over, end and outcome (``RequestRecord``).
+
 The module also carries a tiny in-flight plane: ``inflight_update`` tracks
 how many decode dispatches each engine currently has in flight (the
 pipelined dispatch window), exported as the
 ``nv_engine_inflight_steps`` gauge.
 
-Records land in three existing sinks rather than a new one: ``/metrics``
-(``nv_engine_step_duration_us_quantiles`` + ``nv_engine_collectives_total``,
-via ``metrics_snapshot``), the flight recorder (``flight_attributes``
-stamps the slowest step's breakdown onto retained records), and the
-Perfetto exporters (``perfetto_events`` emits one thread-scoped track per
-engine thread — orphan tracks with no request parent, which the loaders
-accept). ``scripts/step_report.py`` turns a ``dump()`` into a
-dispatch-bound / device-bound / collective-bound verdict.
+Dispatch records land in three existing sinks rather than a new one:
+``/metrics`` (``nv_engine_step_duration_us_quantiles`` +
+``nv_engine_collectives_total``, via ``metrics_snapshot``), the flight
+recorder (``flight_attributes`` stamps the slowest step's breakdown onto
+retained records), and the Perfetto exporters (``perfetto_events`` emits
+one thread-scoped track per engine thread — orphan tracks with no request
+parent, which the loaders accept). ``scripts/step_report.py`` turns a
+``dump()`` into per-phase tables, the deliveries' queue wait and readback,
+the loop-state shares, a per-request table and — from a ``sync`` dump only
+— a dispatch-bound / device-bound / collective-bound verdict.
 
 Activation: ``TPU_STEPSCOPE=1`` (cheap counters), ``TPU_STEPSCOPE=sync``
 (adds ``block_until_ready`` bracketing). Off by default; the off path is
-one module-global read per step. All locks go through
-``sanitize.named_lock`` so the runtime sanitizer sees them.
+one module-global read per step and per submitted request. All locks go
+through ``sanitize.named_lock`` so the runtime sanitizer sees them.
 """
 
 from __future__ import annotations
@@ -54,6 +88,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import zlib
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -83,6 +118,18 @@ PHASE_DECODE = "decode"
 PHASE_COMPUTE = "compute"
 STEP_PHASES = (PHASE_PREFILL, PHASE_PREFILL_CHUNK, PHASE_DECODE,
                PHASE_COMPUTE)
+
+# What the engine thread does between dispatches (``loop_state``). Ring
+# only: these never reach a sketch or /metrics, so they are not STEP_PHASES.
+LOOP_TICKET_WAIT = "ticket_wait"   # inside try_ticket, the window was full
+LOOP_IDLE_WAIT = "idle_wait"       # parked on the condition, nothing to do
+LOOP_ADMIT = "admit"               # frees + cancels + admissions that did work
+LOOP_JOIN = "join"                 # finished prefills' slot state -> decode bank
+LOOP_STATES = (LOOP_TICKET_WAIT, LOOP_IDLE_WAIT, LOOP_ADMIT, LOOP_JOIN)
+
+OUTCOME_FINISHED = "finished"
+OUTCOME_CANCELLED = "cancelled"
+OUTCOME_ERROR = "error"
 
 STEP_METRIC = "nv_engine_step_duration_us_quantiles"
 COLLECTIVES_METRIC = "nv_engine_collectives_total"
@@ -120,30 +167,42 @@ _mode = _env_mode()
 
 
 class StepRecord:
-    """One engine step. Mutated only by the stepping thread until
+    """One engine dispatch. Mutated by the stepping thread until
     ``step_end`` hands it to the aggregator."""
 
     __slots__ = (
         "model", "phase", "step_index", "batch_size", "slots",
+        "lanes", "ctx_blocks", "tokens", "ctx_tokens",
         "t_begin", "t_dispatch", "t_end",
         "dispatch_us", "device_us", "other_us", "total_us",
         "micro_steps", "coll_exposed_us", "coll_hidden_us",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
+        "_annotation",
     )
 
     def __init__(self, model: str, phase: str, step_index: int,
-                 batch_size: int, slots: int):
+                 batch_size: int, slots: int, lanes: int = 0,
+                 ctx_blocks: int = 0):
         self.model = model
         self.phase = phase
         self.step_index = step_index
         self.batch_size = batch_size
         self.slots = slots
+        # What the work was: the padded lane bucket and the block-table
+        # width the executable gathers; positions computed and context
+        # really held (set by the engine on the thread-owned record).
+        self.lanes = lanes
+        self.ctx_blocks = ctx_blocks
+        self.tokens = 0
+        self.ctx_tokens = 0
         self.t_begin = time.monotonic_ns()
         self.t_dispatch = 0
         self.t_end = 0
         self.dispatch_us = 0
-        self.device_us = 0
-        self.other_us = 0
+        # sync mode only (a bracketed block_until_ready and the clamped
+        # remainder); counters mode has no device clock and leaves None.
+        self.device_us: Optional[int] = None
+        self.other_us: Optional[int] = None
         self.total_us = 0
         # Fused pipelined dispatch: how many decode micro-steps this one
         # dispatch covers (1 for the lockstep path).
@@ -160,21 +219,24 @@ class StepRecord:
         thread = threading.current_thread()
         self.thread_ident = thread.ident or 0
         self.thread_name = thread.name
+        self._annotation = None
 
     def collective_count(self) -> int:
         return sum(c for c, _ in self.collectives.values())
 
     def as_dict(self) -> dict:
-        return {
+        out = {
             "model": self.model,
             "phase": self.phase,
             "step_index": self.step_index,
             "batch_size": self.batch_size,
             "slots": self.slots,
+            "lanes": self.lanes,
+            "ctx_blocks": self.ctx_blocks,
+            "tokens": self.tokens,
+            "ctx_tokens": self.ctx_tokens,
             "start_ns": self.t_begin,
             "dispatch_us": self.dispatch_us,
-            "device_us": self.device_us,
-            "other_us": self.other_us,
             "total_us": self.total_us,
             "micro_steps": self.micro_steps,
             "coll_exposed_us": self.coll_exposed_us,
@@ -187,6 +249,49 @@ class StepRecord:
             "thread_ident": self.thread_ident,
             "thread_name": self.thread_name,
         }
+        if self.device_us is not None:
+            out["device_us"] = self.device_us
+            out["other_us"] = self.other_us
+        return out
+
+
+class RequestRecord:
+    """One generation's timeline across the three threads that serve a
+    token (server worker, engine loop, delivery). Each stamp is written by
+    the one thread that owns that moment; ``request_end`` hands the record
+    to the ring once."""
+
+    __slots__ = (
+        "model", "key", "recv_ns", "core_ns", "submit_ns", "admitted_ns",
+        "waited_for_pages", "first_chunk_ns", "last_chunk_ns", "chunks",
+        "first_ready_ns", "out_ns", "end_ns", "outcome",
+    )
+
+    def __init__(self, model: str, prompt, max_new: int, timestamps=None):
+        self.model = model
+        # What a reader outside the server joins on: the wire carries no
+        # request id it could know.
+        self.key = (zlib.crc32(prompt.tobytes()), int(prompt.shape[-1]),
+                    int(max_new))
+        timestamps = timestamps or {}
+        self.recv_ns: Optional[int] = timestamps.get("REQUEST_RECV")
+        self.core_ns: Optional[int] = timestamps.get("COMPUTE_INFER")
+        self.submit_ns = time.monotonic_ns()
+        self.admitted_ns: Optional[int] = None
+        self.waited_for_pages = False
+        self.first_chunk_ns: Optional[int] = None
+        self.last_chunk_ns: Optional[int] = None
+        self.chunks = 0
+        self.first_ready_ns: Optional[int] = None
+        self.out_ns: List[int] = []
+        self.end_ns: Optional[int] = None
+        self.outcome: Optional[str] = None
+
+    def as_dict(self) -> dict:
+        out = {name: getattr(self, name) for name in self.__slots__}
+        out["key"] = list(self.key)
+        out["out_ns"] = list(self.out_ns)
+        return out
 
 
 # Thread-local active step: ``note_collective`` at a parallel/ call site
@@ -230,12 +335,18 @@ class _Aggregator:
                                           str(_DEFAULT_RING)))
             except ValueError:
                 ring = _DEFAULT_RING
+            # Dispatch records (entered at step_end) and loop states.
             self.ring: deque = deque(maxlen=max(ring, 1))
+            # The delivery thread's records (``delivery_end``).
+            self.deliveries: deque = deque(maxlen=max(ring, 1))
+            # Finished request records (RequestRecord.as_dict()).
+            self.requests: deque = deque(maxlen=max(ring, 1))
 
     def absorb(self, rec: StepRecord):
-        stages = ((STAGE_DISPATCH, rec.dispatch_us),
-                  (STAGE_DEVICE, rec.device_us),
-                  (STAGE_OTHER, rec.other_us))
+        stages = [(STAGE_DISPATCH, rec.dispatch_us)]
+        if rec.device_us is not None:
+            stages += [(STAGE_DEVICE, rec.device_us),
+                       (STAGE_OTHER, rec.other_us)]
         with self._lock:
             for stage, us in stages:
                 key = (rec.model, rec.phase, stage)
@@ -305,14 +416,40 @@ def reset():
 
 
 def step_begin(model: str, phase: str, step_index: int,
-               batch_size: int = 0, slots: int = 0) -> Optional[StepRecord]:
+               batch_size: int = 0, slots: int = 0, lanes: int = 0,
+               ctx_blocks: int = 0) -> Optional[StepRecord]:
     """Open a step. Returns None when stepscope is off — callers pass the
-    handle straight through, so the off path is one global read."""
+    handle straight through, so the off path is one global read. The
+    bracket up to ``step_dispatched`` is also a profiler annotation (free
+    while no profile is being taken), so the host span sits beside the
+    device's module on the trace's own clock."""
     if _mode == MODE_OFF:
         return None
-    rec = StepRecord(model, phase, step_index, batch_size, slots)
+    import jax
+
+    rec = StepRecord(model, phase, step_index, batch_size, slots, lanes,
+                     ctx_blocks)
     _tls.active = rec
+    rec._annotation = jax.profiler.TraceAnnotation(
+        f"{model}/{phase}", step=step_index, lanes=lanes,
+        ctx_blocks=ctx_blocks)
+    rec._annotation.__enter__()
     return rec
+
+
+def _close_annotation(rec: StepRecord):
+    annotation, rec._annotation = rec._annotation, None
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+
+
+def step_abandon():
+    """The dispatch of the step open on this thread raised: close its
+    annotation and drop the record (callers' failure handlers)."""
+    rec = getattr(_tls, "active", None)
+    _tls.active = None
+    if rec is not None:
+        _close_annotation(rec)
 
 
 def step_dispatched(rec: Optional[StepRecord]):
@@ -320,6 +457,7 @@ def step_dispatched(rec: Optional[StepRecord]):
     everything between ``step_begin`` and here."""
     if rec is not None:
         rec.t_dispatch = time.monotonic_ns()
+        _close_annotation(rec)
 
 
 def step_end(rec: Optional[StepRecord], outputs=None):
@@ -327,13 +465,14 @@ def step_end(rec: Optional[StepRecord], outputs=None):
 
     In ``sync`` mode, ``outputs`` (any pytree of device arrays) is waited
     on with a timed ``jax.block_until_ready`` — the bracketed wait is the
-    device time. In counters mode outputs are ignored and device time is
-    the wall-clock remainder after dispatch (a lower bound: whatever the
-    host did not spend dispatching overlapped the device).
+    device time, and the clamped remainder is ``other``. In counters mode
+    outputs are ignored and the record has no device stage: nothing on
+    this thread's clock says when the device finished.
     """
     if rec is None:
         return
     _tls.active = None
+    _close_annotation(rec)
     if rec.t_dispatch == 0:
         rec.t_dispatch = time.monotonic_ns()
     device_ns = -1
@@ -351,19 +490,79 @@ def step_end(rec: Optional[StepRecord], outputs=None):
     rec.t_end = time.monotonic_ns()
     total_ns = max(rec.t_end - rec.t_begin, 0)
     dispatch_ns = min(max(rec.t_dispatch - rec.t_begin, 0), total_ns)
-    if device_ns >= 0:
-        device_ns = min(device_ns, total_ns - dispatch_ns)
-        other_ns = max(total_ns - dispatch_ns - device_ns, 0)
-    else:
-        # Counters mode: the post-dispatch remainder lower-bounds device
-        # time (any host work in it overlapped the device anyway).
-        device_ns = max(total_ns - dispatch_ns, 0)
-        other_ns = 0
     rec.total_us = total_ns // 1000
     rec.dispatch_us = dispatch_ns // 1000
-    rec.device_us = device_ns // 1000
-    rec.other_us = other_ns // 1000
+    if device_ns >= 0:
+        device_ns = min(device_ns, total_ns - dispatch_ns)
+        rec.device_us = device_ns // 1000
+        rec.other_us = max(total_ns - dispatch_ns - device_ns, 0) // 1000
     _aggregator.absorb(rec)
+
+
+def delivery_begin(rec: Optional[StepRecord]) -> Optional[dict]:
+    """Open the delivery record of the item that carries ``rec``'s result
+    (stamps ``queued_ns``); None when stepscope is off. The item hands it
+    to the delivery thread, which stamps ``taken_ns``, ``ready_ns`` and
+    ``delivered_ns`` and closes it with ``delivery_end``."""
+    if rec is None:
+        return None
+    return {"model": rec.model, "phase": rec.phase,
+            "step_index": rec.step_index,
+            "queued_ns": time.monotonic_ns(), "taken_ns": None,
+            "ready_ns": None, "delivered_ns": None}
+
+
+def delivery_end(delivery: Optional[dict]):
+    """The delivery thread is done with the item: its record enters the
+    ``deliveries`` ring."""
+    if delivery is not None:
+        with _aggregator._lock:
+            _aggregator.deliveries.append(delivery)
+
+
+def loop_state(model: str, state: str, start_ns: int, end_ns: int,
+               slots: int = 0):
+    """One stretch of an engine-loop state (``LOOP_STATES``), into the
+    ring only. The caller keeps stretches from overlapping a dispatch
+    record or each other; ``start_ns`` 0 (stepscope came on mid-stretch)
+    and empty stretches are dropped."""
+    if _mode == MODE_OFF or not start_ns or end_ns <= start_ns:
+        return
+    thread = threading.current_thread()
+    duration_us = (end_ns - start_ns) // 1000
+    record = {
+        "model": model, "phase": state, "step_index": 0, "batch_size": 0,
+        "slots": slots, "start_ns": start_ns, "dispatch_us": duration_us,
+        "total_us": duration_us, "micro_steps": 0, "collectives": {},
+        "thread_ident": thread.ident or 0, "thread_name": thread.name,
+    }
+    with _aggregator._lock:
+        _aggregator.ring.append(record)
+
+
+# -- request timeline ------------------------------------------------------- #
+
+
+def request_begin(model: str, prompt, max_new: int,
+                  timestamps=None) -> Optional[RequestRecord]:
+    """Open a request's record as it is submitted to the engine (stamps
+    ``submit_ns``). ``timestamps`` is the request's ``TraceContext``
+    timeline, where the core handed one down. Returns None when stepscope
+    is off: the engine checks once, here, and stamps nothing after."""
+    if _mode == MODE_OFF:
+        return None
+    return RequestRecord(model, prompt, max_new, timestamps)
+
+
+def request_end(rec: Optional[RequestRecord], outcome: str):
+    """The request ended (its terminator or error is about to be put):
+    stamp and hand the record to the ring, once."""
+    if rec is None or rec.end_ns is not None:
+        return
+    rec.end_ns = time.monotonic_ns()
+    rec.outcome = outcome
+    with _aggregator._lock:
+        _aggregator.requests.append(rec.as_dict())
 
 
 def note_collective(op: str, count: int = 1, nbytes: int = 0,
@@ -543,19 +742,21 @@ def flight_attributes(model: str) -> Dict[str, object]:
         worst = _aggregator.slowest.get(model)
         if worst is None:
             return {}
-        return {
+        attrs = {
             "step.slowest.phase": worst["phase"],
             "step.slowest.index": worst["step_index"],
             "step.slowest.batch_size": worst["batch_size"],
             "step.slowest.total_us": worst["total_us"],
             "step.slowest.dispatch_us": worst["dispatch_us"],
-            "step.slowest.device_us": worst["device_us"],
-            "step.slowest.other_us": worst["other_us"],
             "step.slowest.coll_exposed_us": worst.get("coll_exposed_us", 0),
             "step.slowest.collectives": sum(
                 c["count"] for c in worst["collectives"].values()
             ),
         }
+        if "device_us" in worst:    # sync mode only
+            attrs["step.slowest.device_us"] = worst["device_us"]
+            attrs["step.slowest.other_us"] = worst["other_us"]
+        return attrs
 
 
 def perfetto_events(epoch_ns: int) -> List[dict]:
@@ -594,8 +795,9 @@ def perfetto_events(epoch_ns: int) -> List[dict]:
                 "step_index": str(r["step_index"]),
                 "batch_size": str(r["batch_size"]),
                 "dispatch_us": str(r["dispatch_us"]),
-                "device_us": str(r["device_us"]),
-                "other_us": str(r["other_us"]),
+                # sync mode only; loop states and counters records have none
+                **{k: str(r[k]) for k in ("device_us", "other_us")
+                   if k in r},
                 "collectives": str(sum(
                     c["count"] for c in r["collectives"].values()
                 )),
@@ -606,10 +808,13 @@ def perfetto_events(epoch_ns: int) -> List[dict]:
 
 def dump() -> dict:
     """Self-describing document ``scripts/step_report.py`` loads: the
-    recent-step ring plus aggregate totals."""
+    recent-step ring (dispatch records and loop states), the delivery
+    thread's ring, the finished requests' ring, plus aggregate totals."""
     agg = _aggregator
     with agg._lock:
         records = list(agg.ring)
+        deliveries = list(agg.deliveries)
+        requests = list(agg.requests)
         step_counts = {
             f"{model}|{phase}": count
             for (model, phase), count in sorted(agg.step_counts.items())
@@ -639,6 +844,8 @@ def dump() -> dict:
         "kind": "stepscope",
         "mode": _mode,
         "records": records,
+        "deliveries": deliveries,
+        "requests": requests,
         "step_counts": step_counts,
         "collectives": collectives,
         "overlap": overlap,

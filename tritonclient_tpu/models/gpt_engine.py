@@ -50,9 +50,9 @@ Greedy decoding matches `gpt.generate_tokens` token-for-token (tested),
 so continuous batching changes scheduling, never results.
 """
 
-import functools
 import queue
 import threading
+import time
 from typing import Dict, Iterator, List, Optional
 
 import jax
@@ -287,14 +287,18 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
 class _Request:
     __slots__ = ("prompt", "max_new", "out", "remaining", "temperature",
                  "top_k", "seed", "cancelled", "cancel_event",
-                 "steps_completed", "mem_owner", "kv_pages_held")
+                 "steps_completed", "mem_owner", "kv_pages_held", "span")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
-                 cancel_event=None):
+                 cancel_event=None, span=None):
         self.prompt = prompt
         self.max_new = max_new
         self.remaining = max_new
+        # stepscope's timeline of this request (None while it is off: the
+        # check is made once, at submit). Each stamp is written by the one
+        # thread that owns that moment; ``end`` hands it to the ring.
+        self.span = span
         # Tokens delivered so far (delivery-thread-owned, like remaining).
         # Mirrored onto the cancel_event so shed/cancel finalization in the
         # core can stamp WHERE in the decode loop the request died — a
@@ -322,6 +326,13 @@ class _Request:
         return self.cancelled or (
             self.cancel_event is not None and self.cancel_event.is_set()
         )
+
+    def end(self, terminator, outcome: str):
+        """Put the request's last item (None or the error). The timeline
+        reaches stepscope's ring first, so a consumer that has seen the
+        terminator finds the record there."""
+        _stepscope.request_end(self.span, outcome)
+        self.out.put(terminator)
 
 
 class _PrefillState:
@@ -394,19 +405,23 @@ class _Distributor:
         """Return an acquired-but-unused ticket (no dispatch happened)."""
         self._sem.release()
 
-    def submit(self, nxt_dev, pairs, first_token: bool = False):
+    def submit(self, nxt_dev, pairs, first_token: bool = False,
+               scope=None):
         """``first_token`` (prefill) items ride the priority lane AND
         are exempt from the in-flight ticket window: admissions are
         already bounded by the slot count, and making a new request's
         prefill wait for a step-readback ticket (~a readback RTT) is
         exactly the TTFT-under-load term. Step items take/release
-        tickets as usual."""
+        tickets as usual. ``scope`` is the dispatch's stepscope record
+        (None when off): the item carries a delivery record of its own,
+        which the delivery thread stamps and closes."""
         self._start()
+        delivery = _stepscope.delivery_begin(scope)
         if first_token:
-            self.prio_q.put(("deliver", nxt_dev, pairs))
+            self.prio_q.put(("deliver", nxt_dev, pairs, delivery))
             self.q.put(("prio",))  # wake marker preserving queue blocking
         else:
-            self.q.put(("deliver", nxt_dev, pairs))
+            self.q.put(("deliver", nxt_dev, pairs, delivery))
 
     def submit_cancel(self, req):
         """Terminate a cancelled request IN DELIVERY ORDER: the None
@@ -458,28 +473,32 @@ class _Distributor:
                 req = item[1]
                 if req.remaining > 0:
                     req.remaining = 0
-                    req.out.put(None)
+                    req.end(None, _stepscope.OUTCOME_CANCELLED)
                 continue
+            delivery = item[3]
+            if delivery is not None:
+                delivery["taken_ns"] = time.monotonic_ns()
             try:
-                self._deliver(item[1], item[2])
+                self._deliver(item[1], item[2], delivery)
             except BaseException as e:  # noqa: BLE001 — surface, don't die silently
                 # A failed readback poisons the engine the same way a
                 # failed dispatch does: consumers of this dispatch get the
                 # error, the engine loop sees _broken at its next top.
                 for _, _, req in item[2]:
-                    req.out.put(e)
+                    req.end(e, _stepscope.OUTCOME_ERROR)
                 with self._engine._cv:
                     if self._engine._broken is None:
                         self._engine._broken = e
                     self._engine._cv.notify_all()
             finally:
+                _stepscope.delivery_end(delivery)
                 if ticketed:
                     self._sem.release()
                     _stepscope.inflight_update(
                         self._engine._scope_name, -1
                     )
 
-    def _deliver(self, nxt_dev, pairs):
+    def _deliver(self, nxt_dev, pairs, delivery=None):
         """Deliver one dispatch's tokens (one readback serves them all).
 
         `pairs` (index-in-array, slot, request) binds each delivery to the
@@ -493,14 +512,29 @@ class _Distributor:
         micro-step); rows deliver in step order, so per-request token
         order is exactly the lockstep pipeline's, and a request whose
         budget runs out mid-block simply drops the surplus rows.
+
+        stepscope (``delivery`` and each request's ``span``, None when
+        off): ``ready_ns`` is when THIS thread saw the readback return, in
+        the order it serves its two queues — not when the device finished.
         """
         nxt_np = np.asarray(nxt_dev)
+        ready_ns = 0
+        if delivery is not None:
+            ready_ns = delivery["ready_ns"] = time.monotonic_ns()
         rows = nxt_np if nxt_np.ndim == 2 else nxt_np[None]
         for t in range(rows.shape[0]):
             row = rows[t]
             for idx, slot, req in pairs:
                 if req.remaining <= 0:
                     continue  # surplus step of an already-finished request
+                span = req.span
+                if span is not None:
+                    now = time.monotonic_ns()
+                    if span.first_ready_ns is None:
+                        # A request's first delivery is its first-token
+                        # item (the priority lane).
+                        span.first_ready_ns = ready_ns or now
+                    span.out_ns.append(now)
                 req.out.put(row[idx : idx + 1].copy())
                 req.remaining -= 1
                 req.steps_completed += 1
@@ -515,10 +549,12 @@ class _Distributor:
                     except AttributeError:
                         pass
                 if req.remaining == 0:
-                    req.out.put(None)
+                    req.end(None, _stepscope.OUTCOME_FINISHED)
                     self.free_q.put((slot, req))
                     with self._engine._cv:
                         self._engine._cv.notify_all()
+        if delivery is not None:
+            delivery["delivered_ns"] = time.monotonic_ns()
 
 
 class GenerationEngine:
@@ -690,12 +726,27 @@ class GenerationEngine:
         )
         self._coll_us: Optional[float] = None  # lazy calibration
         self._prefill_seq = 0
-        self._step = jax.jit(
-            functools.partial(_decode_step_paged, cfg=cfg,
-                              block_size=block_size,
-                              proj_fn=self._proj_fn),
-            donate_argnums=(1, 2),
-        )
+        # The engine's executables are jitted under their own names, so the
+        # HLO modules and the profile's `XLA Modules` line read
+        # jit_decode_step / jit_decode_fused_<n> / jit_prefill_chunk. The
+        # wrappers look the step functions up in this module when traced.
+        proj_fn = self._proj_fn
+
+        def decode_step(params, k_pool, v_pool, btabs, tokens, pos, seeds,
+                        steps, temps, topks):
+            return _decode_step_paged(
+                params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
+                temps, topks, cfg=cfg, block_size=block_size,
+                proj_fn=proj_fn)
+
+        def prefill_chunk(params, k_pool, v_pool, chunks, btabs, starts,
+                          n_valids, seeds, temps, topks):
+            return _prefill_chunk_paged(
+                params, k_pool, v_pool, chunks, btabs, starts, n_valids,
+                seeds, temps, topks, cfg=cfg, block_size=block_size,
+                proj_fn=proj_fn)
+
+        self._step = jax.jit(decode_step, donate_argnums=(1, 2))
         # Unfused-branch slot clocks advance through a donating jit so
         # the dead pos/steps buffers are reused in place on TPU.
         self._advance = jax.jit(_advance_slot_clocks, donate_argnums=(0, 1))
@@ -708,12 +759,8 @@ class GenerationEngine:
         )
         self._multi_step: Dict[int, object] = {}
         self._dispatched = [0] * max_slots  # decode tokens dispatched/slot
-        self._prefill_chunk_fn = jax.jit(
-            functools.partial(_prefill_chunk_paged, cfg=cfg,
-                              block_size=block_size,
-                              proj_fn=self._proj_fn),
-            donate_argnums=(1, 2),
-        )
+        self._prefill_chunk_fn = jax.jit(prefill_chunk,
+                                         donate_argnums=(1, 2))
         # /metrics registry: weakly bound so a dropped engine vanishes
         # from the exposition instead of being pinned by it.
         import weakref
@@ -764,17 +811,18 @@ class GenerationEngine:
     def _drain_terminated(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Terminate every queued/active request (no thread will serve
         them): admission-queue waiters too, not just slot occupants."""
+        cancelled = _stepscope.OUTCOME_CANCELLED
         if self._pending is not None:
-            self._pending.out.put(None)
+            self._pending.end(None, cancelled)
             self._pending = None
         while True:
             try:
-                self._admit.get_nowait().out.put(None)
+                self._admit.get_nowait().end(None, cancelled)
             except queue.Empty:
                 break
         for slot, req in enumerate(self._slot_req):
             if req is not None:
-                req.out.put(None)
+                req.end(None, cancelled)
                 self._prefilling.pop(slot, None)
                 self._free_slot_blocks(slot, device_reset=False)
                 self._slot_req[slot] = None
@@ -783,13 +831,17 @@ class GenerationEngine:
 
     def submit(self, prompt: np.ndarray, max_new: int,
                temperature: float = 0.0, top_k: int = 0,
-               seed: int = 0, cancel_event=None) -> "_Request":
+               seed: int = 0, cancel_event=None,
+               timestamps=None) -> "_Request":
         """Queue a generation; returns the _Request whose ``.out`` queue
         yields np [1] per token, then None. Setting ``.cancelled`` (or
         arming ``cancel_event``) frees the slot — and returns its KV
         pages to the pool — at the engine's next loop top, i.e. within
         one decode step. Greedy by default; temperature/top_k/seed follow
-        the shared sampling key schedule (gpt.sampling_key)."""
+        the shared sampling key schedule (gpt.sampling_key).
+        ``timestamps`` is the request's TraceContext timeline where the
+        core handed one down: stepscope copies its receipt stamps onto
+        the request's record (nothing is read while stepscope is off)."""
         if prompt.shape[1] >= self.cfg.max_len:
             raise ValueError(
                 f"prompt length {prompt.shape[1]} must be < max_len "
@@ -799,9 +851,12 @@ class GenerationEngine:
                              self.cfg.max_len - prompt.shape[1]))
         # 31-bit canonical form (matches sampling_key) so the int32 slot
         # vectors hold any int64 wire seed without overflow.
-        req = _Request(prompt.astype(np.int32), max_new, temperature,
+        prompt = prompt.astype(np.int32)
+        req = _Request(prompt, max_new, temperature,
                        top_k, int(seed) & 0x7FFFFFFF,
-                       cancel_event=cancel_event)
+                       cancel_event=cancel_event,
+                       span=_stepscope.request_begin(
+                           self._scope_name, prompt, max_new, timestamps))
         with self._cv:
             if self._stopping:
                 raise RuntimeError("generation engine is shut down")
@@ -957,13 +1012,21 @@ class GenerationEngine:
         TPU_ENGINE_FUSE_STEPS, so the shape family stays tiny)."""
         fn = self._multi_step.get(n_steps)
         if fn is None:
+            cfg, block_size, proj_fn = (self.cfg, self.block_size,
+                                        self._proj_fn)
+
+            def decode_fused(params, k_pool, v_pool, btabs, tokens, pos,
+                             seeds, steps, temps, topks):
+                return _decode_multi_step_paged(
+                    params, k_pool, v_pool, btabs, tokens, pos, seeds,
+                    steps, temps, topks, cfg=cfg, block_size=block_size,
+                    n_steps=n_steps, proj_fn=proj_fn)
+
+            # One name per width: jit_decode_fused_<n> on the device trace.
+            decode_fused.__name__ = f"decode_fused_{n_steps}"
+            decode_fused.__qualname__ = decode_fused.__name__
             fn = self._multi_step[n_steps] = jax.jit(
-                functools.partial(_decode_multi_step_paged, cfg=self.cfg,
-                                  block_size=self.block_size,
-                                  n_steps=n_steps,
-                                  proj_fn=self._proj_fn),
-                donate_argnums=(1, 2),
-            )
+                decode_fused, donate_argnums=(1, 2))
         return fn
 
     def _choose_fuse(self, active: List[int]) -> int:  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
@@ -1025,9 +1088,12 @@ class GenerationEngine:
         thread, in pipeline order. ``cancel_event`` (armed by the
         protocol front-end on disconnect/stream cancel) is polled here —
         between decode steps — so an abandoned generation frees its slot
-        even when its response generator never runs again."""
+        even when its response generator never runs again. Returns
+        whether anything was released."""
+        released = False
         for slot, req in enumerate(self._slot_req):
             if req is not None and req.abandoned:
+                released = True
                 # Pages back BEFORE the slot reads empty: anything polling
                 # _slot_req for completion (tests, warm_admission callers)
                 # must find the pool already reconciled.
@@ -1037,8 +1103,10 @@ class GenerationEngine:
                 self._slot_req[slot] = None
                 self._dist.submit_cancel(req)
         if self._pending is not None and self._pending.abandoned:
-            self._pending.out.put(None)
+            self._pending.end(None, _stepscope.OUTCOME_CANCELLED)
             self._pending = None
+            released = True
+        return released
 
     def _process_frees(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Apply slot-completions reported by the delivery thread.
@@ -1047,13 +1115,16 @@ class GenerationEngine:
         queues (slot, req) here when a request's final token went out.
         Pages return to the pool HERE — block-granular, the moment the
         request finishes, not when the slot's longest cohabitant does.
+        Returns whether a slot was freed.
         """
+        freed = False
         while True:
             try:
                 slot, req = self._dist.free_q.get_nowait()
             except queue.Empty:
-                return
+                return freed
             if self._slot_req[slot] is req:
+                freed = True
                 # Pages back BEFORE the slot reads empty (same ordering
                 # as _release_cancelled: pollers of _slot_req must find
                 # the pool already reconciled). The temperature reset
@@ -1063,11 +1134,29 @@ class GenerationEngine:
                 self._temps = self._temps.at[slot].set(0.0)
                 self._slot_req[slot] = None
 
+    def _housekeep(self) -> bool:  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
+        """Frees, cancels and admissions, in that order: what the loop does
+        between dispatches besides waiting. When it did something the
+        stretch is stepscope's ``admit`` loop state; returns whether it
+        did."""
+        began = time.monotonic_ns() if _stepscope.enabled() else 0
+        freed = self._process_frees()
+        released = self._release_cancelled()
+        admitted = self._admit_requests()
+        worked = freed or released or admitted
+        if worked:
+            _stepscope.loop_state(self._scope_name, _stepscope.LOOP_ADMIT,
+                                  began, time.monotonic_ns(),
+                                  self.max_slots)
+        return worked
+
     def _admit_requests(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Claim free slots for queued requests: reserve pages (admission
         gates on FREE PAGES now, not just free slots) and queue the
         chunked prefill. No compute happens here — chunks dispatch from
-        _advance_prefills, interleaved with decode steps."""
+        _advance_prefills, interleaved with decode steps. Returns whether
+        a request was admitted (or turned away)."""
+        took = False
         for slot in range(self.max_slots):
             if self._slot_req[slot] is not None:
                 continue
@@ -1077,23 +1166,31 @@ class GenerationEngine:
                 try:
                     req = self._admit.get_nowait()
                 except queue.Empty:
-                    return
+                    return took
             if req.abandoned:
-                req.out.put(None)
+                req.end(None, _stepscope.OUTCOME_CANCELLED)
+                took = True
                 continue
             st = self._reserve(req)
             if isinstance(st, BaseException):
-                req.out.put(st)
+                req.end(st, _stepscope.OUTCOME_ERROR)
+                took = True
                 continue
             if st is None:
                 # Pool exhausted: hold the head of the line (FIFO — no
                 # starvation by smaller latecomers) and retry when a
                 # completion returns pages.
                 self._pending = req
-                return
+                if req.span is not None:
+                    req.span.waited_for_pages = True
+                return took
+            took = True
+            if req.span is not None:
+                req.span.admitted_ns = time.monotonic_ns()
             self._slot_req[slot] = req
             self._slot_blocks[slot] = st.blocks
             self._prefilling[slot] = st
+        return took
 
     def _advance_prefills(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Dispatch ONE prefill chunk for every still-prefilling slot —
@@ -1107,9 +1204,10 @@ class GenerationEngine:
         would put k×chunk-time in front of every admission in the burst
         (measured: the serial form put the c8 TTFT p99 at ~4× c1's on
         the CPU reference host; batched, the burst costs ~one chunk).
+        Returns whether a chunk was dispatched.
         """
         if not self._prefilling:
-            return
+            return False
         active = sorted(self._prefilling)
         c = self.prefill_chunk
         n_real = len(active)
@@ -1161,11 +1259,16 @@ class GenerationEngine:
         scope = _stepscope.step_begin(
             self._scope_name, _stepscope.PHASE_PREFILL_CHUNK,
             self._prefill_seq, batch_size=n_real, slots=self.max_slots,
+            lanes=kk, ctx_blocks=n_ctx,
         )
         if scope is not None:
             # The gathered view reads the bucketed block-table extent
             # for every lane, hit pages or not (shape-bucketed gather).
             scope.kv_bytes = kk * n_ctx * self._block_kv_bytes
+            # Positions computed, and the context the real lanes hold
+            # once this chunk is in (what the chunk's rows attend).
+            scope.tokens = sum(n for _, _, _, n in lanes)
+            scope.ctx_tokens = sum(s + n for _, _, s, n in lanes)
         self._prefill_seq += 1
         # One compile-cache entry per (lane, context) bucket: the key is
         # the traced-shape identity XLA uses, so the retrace counter and
@@ -1181,9 +1284,18 @@ class GenerationEngine:
         )
         _stepscope.step_dispatched(scope)
         _stepscope.charge_collectives(scope, self._expected_collectives)
+        # The dispatch return, on the requests' timelines too.
+        returned_ns = scope.t_dispatch if scope is not None else 0
         done = []  # (slot, state)
         for i, (slot, st, start, n_valid) in enumerate(lanes):
             st.next = start + n_valid
+            span = st.req.span
+            if span is not None:
+                returned_ns = returned_ns or time.monotonic_ns()
+                if not span.chunks:
+                    span.first_chunk_ns = returned_ns
+                span.last_chunk_ns = returned_ns
+                span.chunks += 1
             if st.next >= st.prompt_len:
                 st.first = firsts_dev[i : i + 1]
                 done.append((slot, st))
@@ -1194,7 +1306,10 @@ class GenerationEngine:
                 pass
         _stepscope.step_end(scope, outputs=firsts_dev)
         if not done:
-            return
+            return True
+        # stepscope's ``join`` loop state: from here to the hand-over of
+        # the first tokens, the burst of slot-state writes below.
+        joining_from = time.monotonic_ns() if scope is not None else 0
         # Slot-state updates are device-op ENQUEUES (several per slot):
         # a synchronized churn burst (batched steps finish batchmates
         # together, their clients resubmit together) completes many
@@ -1243,8 +1358,12 @@ class GenerationEngine:
         self._dist.submit(
             firsts,
             [(i, slot, st.req) for i, (slot, st) in enumerate(done)],
-            first_token=True,
+            first_token=True, scope=scope,
         )
+        _stepscope.loop_state(self._scope_name, _stepscope.LOOP_JOIN,
+                              joining_from, time.monotonic_ns(),
+                              self.max_slots)
+        return True
 
     def warm_admission(self):
         """Pre-execute the vectorized admission ops for every burst size
@@ -1360,6 +1479,7 @@ class GenerationEngine:
         try:
             self._run_loop()
         except BaseException as e:  # noqa: BLE001 — engine must not die silently
+            _stepscope.step_abandon()  # a dispatch that raised left it open
             # The jits donate the cache pool: after a failed dispatch the
             # engine cannot be restarted against possibly-deleted buffers.
             # Mark broken (submit() refuses), surface the error to every
@@ -1373,17 +1493,18 @@ class GenerationEngine:
                 self._dist.drain_and_stop(timeout=5.0)
             except Exception:
                 pass
+            failed = _stepscope.OUTCOME_ERROR
             if self._pending is not None:
-                self._pending.out.put(e)
+                self._pending.end(e, failed)
                 self._pending = None
             while True:
                 try:
-                    self._admit.get_nowait().out.put(e)
+                    self._admit.get_nowait().end(e, failed)
                 except queue.Empty:
                     break
             for slot, req in enumerate(self._slot_req):
                 if req is not None:
-                    req.out.put(e)
+                    req.end(e, failed)
                     self._slot_req[slot] = None
                     self._prefilling.pop(slot, None)
                     # Host bookkeeping only: the device is suspect.
@@ -1412,9 +1533,7 @@ class GenerationEngine:
             broken = self._broken  # tpulint: disable=TPU002,TPU009 - single-transition stop/broken flags polled lock-free by the loop
             if broken is not None:
                 raise broken
-            self._process_frees()
-            self._release_cancelled()
-            self._admit_requests()
+            self._housekeep()
             self._advance_prefills()
             active = [s for s, r in enumerate(self._slot_req)
                       if r is not None and s not in self._prefilling]
@@ -1424,7 +1543,12 @@ class GenerationEngine:
                 with self._cv:
                     if (self._admit.empty() and self._dist.free_q.empty()
                             and self._pending is None):
+                        began = (time.monotonic_ns()
+                                 if _stepscope.enabled() else 0)
                         got = self._cv.wait(timeout=5.0)
+                        _stepscope.loop_state(
+                            self._scope_name, _stepscope.LOOP_IDLE_WAIT,
+                            began, time.monotonic_ns(), self.max_slots)
                         if (not got and self._admit.empty()
                                 and self._dist.free_q.empty()
                                 and self._pending is None):
@@ -1438,16 +1562,31 @@ class GenerationEngine:
             # request's prefill chunks are ticket-exempt and must dispatch
             # while the step pipeline is full, or TTFT under load degrades
             # to a step-readback wait.
+            # stepscope's ``ticket_wait`` loop state: from the first try
+            # that misses until the ticket comes. Work done between tries
+            # (an admission, a chunk dispatch) closes the stretch before
+            # it and opens another after, so no two records overlap.
+            waiting_from = time.monotonic_ns() if _stepscope.enabled() else 0
+            missed = False
             got_ticket = self._dist.try_ticket(timeout=0.005)
             while not got_ticket:
                 # Same lock-free signal poll as the loop top.
                 if self._stopping or self._broken is not None:  # tpulint: disable=TPU002,TPU009 - single-transition stop/broken flags polled lock-free by the loop
                     break
-                self._process_frees()
-                self._release_cancelled()
-                self._admit_requests()
-                self._advance_prefills()
+                missed = True
+                missed_at = time.monotonic_ns() if waiting_from else 0
+                worked = self._housekeep()
+                dispatched = self._advance_prefills()
+                if (worked or dispatched) and waiting_from:
+                    _stepscope.loop_state(
+                        self._scope_name, _stepscope.LOOP_TICKET_WAIT,
+                        waiting_from, missed_at, self.max_slots)
+                    waiting_from = time.monotonic_ns()
                 got_ticket = self._dist.try_ticket(timeout=0.005)
+            if missed:
+                _stepscope.loop_state(
+                    self._scope_name, _stepscope.LOOP_TICKET_WAIT,
+                    waiting_from, time.monotonic_ns(), self.max_slots)
             if not got_ticket:
                 continue  # stopping/broken handled at loop top
             # Recompute: slots whose prefill completed during the ticket
@@ -1464,9 +1603,16 @@ class GenerationEngine:
             scope = _stepscope.step_begin(
                 self._scope_name, _stepscope.PHASE_DECODE, step_seq,
                 batch_size=len(active), slots=self.max_slots,
+                lanes=self.max_slots, ctx_blocks=self._max_blocks,
             )
             if scope is not None:
                 scope.micro_steps = fuse
+                scope.tokens = len(active) * fuse
+                # Context held by the active slots as the dispatch's first
+                # micro-step sees it: prompt + tokens dispatched so far.
+                scope.ctx_tokens = sum(
+                    self._slot_req[s].prompt.shape[1] + self._dispatched[s]
+                    for s in active)
                 # Whole-bank decode: every micro-step gathers the full
                 # [max_slots, max_blocks] table extent.
                 scope.kv_bytes = (
@@ -1524,7 +1670,8 @@ class GenerationEngine:
                 self._dispatched[s] += fuse
             self._dist.submit(
                 toks, [(s, s, self._slot_req[s]) for s in active
-                       if self._slot_req[s] is not None]
+                       if self._slot_req[s] is not None],
+                scope=scope,
             )
             _stepscope.inflight_update(self._scope_name, 1)
             # sync mode blocks on the step output here (true device time,
@@ -1547,7 +1694,10 @@ class GptEngineModel(Model):
     decoupled = True
     blocking = True
     # The core injects the request's cancel_event (PARAM_CANCEL_EVENT in
-    # the parameters copy) so the engine can poll it between decode steps.
+    # the parameters copy) so the engine can poll it between decode steps,
+    # and beside it the request's TraceContext timeline
+    # (PARAM_TRACE_TIMESTAMPS), which stepscope copies its receipt stamps
+    # from.
     accepts_cancel_event = True
 
     def __init__(self, cfg: Optional[GptConfig] = None, seed: int = 0,
@@ -1618,9 +1768,13 @@ class GptEngineModel(Model):
         if "MAX_TOKENS" in inputs:
             max_new = int(np.asarray(inputs["MAX_TOKENS"]).flatten()[0])
         temperature, top_k, gen_seed = sampling_inputs(inputs)
-        from tritonclient_tpu.protocol._literals import PARAM_CANCEL_EVENT
+        from tritonclient_tpu.protocol._literals import (
+            PARAM_CANCEL_EVENT,
+            PARAM_TRACE_TIMESTAMPS,
+        )
 
         cancel_event = (parameters or {}).get(PARAM_CANCEL_EVENT)
+        timestamps = (parameters or {}).get(PARAM_TRACE_TIMESTAMPS)
 
         def gen():
             # Admission happens on FIRST consumption (not at infer()):
@@ -1633,7 +1787,8 @@ class GptEngineModel(Model):
             req = self.engine.submit(prompt, max_new,
                                      temperature=temperature,
                                      top_k=top_k, seed=gen_seed,
-                                     cancel_event=cancel_event)
+                                     cancel_event=cancel_event,
+                                     timestamps=timestamps)
             try:
                 while True:
                     token = req.out.get(timeout=300)

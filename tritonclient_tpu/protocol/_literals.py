@@ -399,6 +399,12 @@ MEM_EVENTS = (
 #: ``accepts_cancel_event = True``.
 PARAM_CANCEL_EVENT = "_tpu_cancel_event"
 
+#: Server-internal parameter key carrying the request's ``TraceContext``
+#: timeline (its ``timestamps`` dict: REQUEST_RECV, COMPUTE_INFER, ...)
+#: beside the cancel event, under the same opt-in and never on the wire.
+#: stepscope copies the receipt stamps onto the engine's request record.
+PARAM_TRACE_TIMESTAMPS = "_tpu_trace_timestamps"
+
 #: Request parameters the clients reserve for dedicated kwargs; user-supplied
 #: ``parameters`` dicts may not name these (reference:
 #: tritonclient/http/_utils.py:114-117 and grpc/_utils.py equivalent).

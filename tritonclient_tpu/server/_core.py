@@ -34,6 +34,7 @@ from tritonclient_tpu.protocol._literals import (
     INVALID_REASON_MALFORMED,
     INVALID_REASONS,
     PARAM_CANCEL_EVENT,
+    PARAM_TRACE_TIMESTAMPS,
     PREFIX_EVENTS,
     SERVER_EXTENSIONS,
     SHED_REASON_ADMISSION,
@@ -2386,14 +2387,17 @@ class InferenceCore:
                 ])
 
         params = dict(request.parameters)
-        if request.cancel_event is not None and getattr(
-            model, "accepts_cancel_event", False
-        ):
-            # Engine-backed models poll this between decode steps so a
-            # departed client's generation frees its slot mid-stream.
-            # Injected into the COPY only, and only for models that opt
-            # in — request.parameters stays wire-shaped.
-            params[PARAM_CANCEL_EVENT] = request.cancel_event
+        if getattr(model, "accepts_cancel_event", False):
+            # Engine-backed models poll the event between decode steps so
+            # a departed client's generation frees its slot mid-stream;
+            # the request's timeline rides beside it so stepscope can put
+            # the receipt stamps on the engine's own record of the
+            # request. Injected into the COPY only, and only for models
+            # that opt in — request.parameters stays wire-shaped.
+            if request.cancel_event is not None:
+                params[PARAM_CANCEL_EVENT] = request.cancel_event
+            if trace is not None:
+                params[PARAM_TRACE_TIMESTAMPS] = trace.timestamps
         try:
             result = model.infer(inputs, params)
         except CoreError:
@@ -2621,6 +2625,7 @@ class InferenceCore:
                     trace.record("COMPUTE_INFER", t_input)
                     trace.record("COMPUTE_OUTPUT", t_infer)
         except CoreError:
+            _stepscope.step_abandon()
             duration = time.monotonic_ns() - t_start
             with self._lock:
                 stats.fail_count += len(live)
@@ -2629,6 +2634,7 @@ class InferenceCore:
                     stats.observe_duration(duration)
             raise
         except Exception as e:
+            _stepscope.step_abandon()
             duration = time.monotonic_ns() - t_start
             with self._lock:
                 stats.fail_count += len(live)
