@@ -46,6 +46,22 @@ def _wait_idle(engine, timeout=30.0):
     raise AssertionError(f"engine not idle: {engine._slot_req}")
 
 
+def _engine_step_arguments(engine):
+    """(the decode bank, a one-lane one-page chunk of 8): what the engine's
+    three jitted wrappers take, for lowering or tracing them by hand."""
+    import jax.numpy as jnp
+
+    bank = (engine.params, engine._k, engine._v, engine._btabs,
+            engine._tokens, engine._pos, engine._seeds, engine._steps,
+            engine._temps, engine._topks)
+    z = jnp.zeros((1,), jnp.int32)
+    chunk = (engine.params, engine._k, engine._v,
+             jnp.zeros((1, 8), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+             z, jnp.ones((1,), jnp.int32), z,
+             jnp.zeros((1,), jnp.float32), z)
+    return bank, chunk
+
+
 @pytest.fixture(scope="module")
 def tiny():
     cfg = gpt.gpt_tiny(max_len=64)
@@ -594,19 +610,10 @@ def test_engine_executables_are_jitted_under_their_own_names(tiny):
     """The HLO modules (and with them the profile's `XLA Modules` line) read
     jit_decode_step / jit_decode_fused_<n> / jit_prefill_chunk, where bare
     functools.partials gave jit(<unknown>) for all three."""
-    import jax.numpy as jnp
-
     cfg, params = tiny
     engine = GenerationEngine(cfg, params, max_slots=2, prefill_chunk=8)
     try:
-        bank = (engine.params, engine._k, engine._v, engine._btabs,
-                engine._tokens, engine._pos, engine._seeds, engine._steps,
-                engine._temps, engine._topks)
-        z = jnp.zeros((1,), jnp.int32)
-        chunk = (engine.params, engine._k, engine._v,
-                 jnp.zeros((1, 8), jnp.int32), jnp.zeros((1, 1), jnp.int32),
-                 z, jnp.ones((1,), jnp.int32), z,
-                 jnp.zeros((1,), jnp.float32), z)
+        bank, chunk = _engine_step_arguments(engine)
         lowered = {
             "jit_decode_step": engine._step.lower(*bank),
             "jit_decode_fused_4": engine._multi_step_fn(4).lower(*bank),
@@ -712,3 +719,157 @@ def test_request_timeline_carries_the_servers_receipt_stamps(tiny):
         <= record["submit_ns"] <= record["admitted_ns"]
     assert len(record["out_ns"]) == 5
     assert record["outcome"] == "finished"
+
+
+# --------------------------------------------------------------------------- #
+# the KV pool never travels through the layer scan                            #
+# --------------------------------------------------------------------------- #
+
+
+def _scan_eqns(jaxpr):
+    """Every ``scan`` equation of a jaxpr, those of nested jaxprs (the jit
+    wrapper, the fused scan's body, the sampler's cond) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _scan_eqns(sub)
+
+
+@pytest.mark.parametrize("program", ["decode", "fused_2", "prefill_chunk"])
+def test_layer_scan_carries_the_pools_and_scans_neither(tiny, program):
+    """The pools are carried values of the layer scan; no scan of the
+    program has a scanned input or a stacked output with the pool's shape
+    or one layer's. Scanned, every dispatch slices each layer's pages out
+    and writes a second pool back (ROADMAP A10)."""
+    cfg, params = tiny
+    engine = GenerationEngine(cfg, params, max_slots=2, prefill_chunk=8)
+    try:
+        bank, chunk = _engine_step_arguments(engine)
+        jaxpr = {
+            "decode": lambda: jax.make_jaxpr(engine._step)(*bank),
+            "fused_2": lambda: jax.make_jaxpr(engine._multi_step_fn(2))(*bank),
+            "prefill_chunk": lambda: jax.make_jaxpr(
+                engine._prefill_chunk_fn)(*chunk),
+        }[program]().jaxpr
+        pool = tuple(engine._k.shape)
+        wqkv = tuple(engine.params["layers"]["wqkv"].shape)
+    finally:
+        engine.shutdown()
+    assert pool == (cfg.n_layers, 1 + 2 * (cfg.max_len // 16), 16,
+                    cfg.n_heads * cfg.head_dim)
+    pool_shaped = (pool, pool[1:], (1,) + pool[1:])
+    layer_scans = []
+    for eqn in _scan_eqns(jaxpr):
+        n_consts, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        carried = [tuple(v.aval.shape)
+                   for v in eqn.invars[n_consts:n_consts + n_carry]]
+        scanned = [tuple(v.aval.shape)
+                   for v in eqn.invars[n_consts + n_carry:]]
+        stacked = [tuple(v.aval.shape) for v in eqn.outvars[n_carry:]]
+        assert not [s for s in scanned + stacked if s in pool_shaped], (
+            program, scanned, stacked)
+        if wqkv in scanned:
+            layer_scans.append(carried)
+    assert len(layer_scans) == 1, layer_scans
+    assert layer_scans[0].count(pool) == 2, layer_scans
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip. Described inside the fixture,
+    never at import: only one process may load the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compile_cache_off():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+_COMPILE_SECONDS = 120.0   # a two-layer program compiles in a few seconds
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("heads,head_dim", [(25, 64), (16, 128)])
+def test_v5e_compiler_moves_no_pool_and_allocates_none(
+        v5e_chip, compile_cache_off, heads, head_dim, program):
+    """Compiled for the v5e, a step holds no copy, dynamic-slice or
+    dynamic-update-slice with the pool's or one layer's dimensions, and
+    its temporaries stay under one layer's pool: the scatter is in place
+    on the donated buffer and the gather reads the table's pages only.
+    A 5-D pool fails the 25 x 64 case: the chip's default layout puts the
+    page axis minor there and every layer's pages are re-laid out."""
+    import functools
+    import re
+
+    import jax.numpy as jnp
+
+    from tritonclient_tpu.models import gpt_engine
+
+    slots, block_size, n_blocks, max_len, chunk = 2, 16, 48, 32, 4
+    cfg = gpt.GptConfig(vocab_size=256, d_model=heads * head_dim,
+                        n_layers=2, n_heads=heads,
+                        d_ff=4 * heads * head_dim, max_len=max_len,
+                        dtype=jnp.bfloat16)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+    k_pool, v_pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: gpt_engine._block_pool_arrays(cfg, n_blocks, block_size)))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "decode":
+        fn = gpt_engine._decode_step_paged
+        args = (vec(i32, slots, max_len // block_size), vec(i32, slots),
+                vec(i32, slots), vec(i32, slots), vec(i32, slots),
+                vec(f32, slots), vec(i32, slots))
+    else:
+        fn = gpt_engine._prefill_chunk_paged
+        args = (vec(i32, slots, chunk), vec(i32, slots, 2), vec(i32, slots),
+                vec(i32, slots), vec(i32, slots), vec(f32, slots),
+                vec(i32, slots))
+    began = time.monotonic()
+    compiled = jax.jit(
+        functools.partial(fn, cfg=cfg, block_size=block_size),
+        donate_argnums=(1, 2),
+    ).lower(params, k_pool, v_pool, *args).compile()
+    assert time.monotonic() - began < _COMPILE_SECONDS
+
+    pool = tuple(k_pool.shape)
+    pool_dims = {",".join(map(str, d))
+                 for d in (pool, pool[1:], (1,) + pool[1:])}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \(?\w+\[([\d,]*)\]\S* "
+                     r"(copy|copy-start|dynamic-slice|dynamic-update-slice)"
+                     r"\(", line)
+        if m and m.group(1) in pool_dims:
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    layer_pool_bytes = (n_blocks * block_size * heads * head_dim
+                        * np.dtype(k_pool.dtype).itemsize)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes

@@ -1,7 +1,7 @@
 """Host-side bookkeeping for the paged KV cache (block pool + prefix cache).
 
 The gpt engine's KV memory is a fixed pool of ``[n_layers, n_blocks,
-block_size, H, Dh]`` pages on device (models/gpt_engine.py); THIS module
+block_size, H * Dh]`` pages on device (models/gpt_engine.py); THIS module
 owns the host-side allocation state around it:
 
   * ``BlockPool`` — a free list plus per-block reference counts. Block 0

@@ -209,8 +209,9 @@ def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, mask,
     [N, H, Dh] projections; ``mask`` broadcasts against [N, H, L] scores.
     ``read_kv(kc, vc)`` (optional) maps the written cache to the [N, L, H,
     Dh] attention operands — the paged engine passes the block-table
-    gather here ([n_blocks, bs, H, Dh] pool -> per-row views) while the
-    contiguous paths read the cache directly. Decode is bandwidth-bound
+    gather here (kc/vc are then its whole [L, n_blocks, bs, H * Dh] pools,
+    which only its ``write_kv``/``read_kv`` index) while the contiguous
+    paths read the cache directly. Decode is bandwidth-bound
     on the cache read — the MXU-free regime where a flash kernel buys
     nothing — so a masked einsum is the kernel.
 

@@ -9,7 +9,7 @@ leave when finished, and a freed slot is immediately refilled from the
 admission queue.
 
 KV memory is PAGED (vLLM block tables / Ragged Paged Attention geometry):
-a fixed pool of ``[n_layers, n_blocks, block_size, H, Dh]`` pages plus a
+a fixed pool of ``[n_layers, n_blocks, block_size, H * Dh]`` pages plus a
 per-slot block table ``[S, max_len // block_size]``. A request reserves
 ``ceil((prompt + max_new) / block_size)`` pages at admission (deadlock-
 free: decode never allocates mid-flight) and returns them the moment it
@@ -79,8 +79,67 @@ from tritonclient_tpu.protocol._literals import (
 
 
 def _block_pool_arrays(cfg: GptConfig, n_blocks: int, block_size: int):
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_heads, cfg.head_dim)
+    """The K and V pools, each ``[n_layers, n_blocks, block_size, H * Dh]``.
+
+    The last axis is heads and head size FLAT. For ``[..., 25, 64]`` the
+    TPU's default layout puts the page axis minor (``{1,4,3,2,0}``), and a
+    step would re-lay every layer's pages out on the way in and on the
+    way out; ``[..., 1600]`` gets the row-major ``{3,2,1,0}`` the scatter
+    and the gather want, padded 4% instead of 25%.
+    """
+    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_heads * cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+
+def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
+                           rows_per_table: int, mask, cfg: GptConfig,
+                           proj_fn):
+    """The layer scan of every paged step: ``(h, k_pool, v_pool)`` is the
+    CARRY and the scanned inputs are the layer's parameters and its index.
+
+    The pools are never an ``xs`` or a ``ys`` of the scan. A scanned pool
+    has each layer's pages sliced out, written back into a second stacked
+    pool and that stack copied onto the donated buffer: about four passes
+    over the pool a dispatch, for a write of one position a slot. Carried,
+    what a step does to the pool compiles to one in-place scatter at
+    ``(layer, page, offset)`` and one gather of the tables' pages.
+
+    ``dest``/``off`` [N] are the page and offset of each of the N rows'
+    new K/V; ``btabs`` [T, n_ctx] are the tables to gather, each attended
+    by ``rows_per_table`` consecutive rows (N = T * rows_per_table: 1 for
+    decode, the chunk length for prefill).
+    """
+    n_tables, n_ctx = btabs.shape
+    heads = (n_ctx * k_pool.shape[2], cfg.n_heads, cfg.head_dim)
+
+    def layer(carry, xs):
+        h, k_pool, v_pool = carry
+        lp, li = xs
+
+        def write(pool, rows):
+            # One scatter at (layer, page, offset), rows [N, H * Dh].
+            return pool.at[li, dest, off].set(
+                rows.reshape(rows.shape[0], -1).astype(pool.dtype))
+
+        def read(pool):
+            # Only the tables' pages are read: [T, n_ctx, bs, H * Dh] ->
+            # [T, l_eff, H, Dh], each table's view for all of its rows.
+            table = pool[li, btabs].reshape((n_tables, 1) + heads)
+            return jnp.broadcast_to(
+                table, (n_tables, rows_per_table) + heads
+            ).reshape((n_tables * rows_per_table,) + heads)
+
+        h, (k_pool, v_pool) = _decode_layer(
+            h, lp, k_pool, v_pool, cfg,
+            lambda kc, vc, k, v: (write(kc, k), write(vc, v)), mask,
+            read_kv=lambda kc, vc: (read(kc), read(vc)), proj_fn=proj_fn)
+        return (h, k_pool, v_pool), None
+
+    (x, k_pool, v_pool), _ = lax.scan(
+        layer, (x, k_pool, v_pool),
+        (params["layers"], jnp.arange(cfg.n_layers)),
+    )
+    return x, k_pool, v_pool
 
 
 def _pow2_bucket(n: int, cap: int) -> int:
@@ -127,8 +186,11 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
     Sampling happens on device — logits never leave the chip. Every slot
     advances; idle slots carry an all-scratch table, so their garbage
     K/V lands on the scratch page instead of a page some OTHER request
-    now owns. The gather ``pool[btabs]`` reconstructs the dense
-    [S, max_len, H, Dh] view, making the attention math bit-identical to
+    now owns. The pools are ``[n_layers, n_blocks, bs, H * Dh]`` and ride
+    the layer scan as its carry (``_scan_layers_over_pool``): a layer
+    scatters its S new rows at ``(layer, page, offset)`` and gathers
+    ``pool[layer, btabs]``, which reshapes to the dense
+    [S, max_len, H, Dh] view, so the attention math is bit-identical to
     the old contiguous bank.
     """
     s_count = tokens.shape[0]
@@ -142,27 +204,8 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
     off = pos % block_size
     dest = btabs[slot_ids, blk]                              # [S] page ids
     mask = (jnp.arange(l_eff)[None, :] <= pos[:, None])[:, None, :]
-
-    def write_kv(kc, vc, k, v):
-        # Per-slot pages: a batched scatter at (page, offset).
-        kc = kc.at[dest, off].set(k.astype(kc.dtype))
-        vc = vc.at[dest, off].set(v.astype(vc.dtype))
-        return kc, vc
-
-    def read_kv(kc, vc):
-        # [n_blocks, bs, H, Dh] -> [S, max_blocks, bs, H, Dh] -> dense.
-        ka = kc[btabs].reshape(s_count, l_eff, cfg.n_heads, cfg.head_dim)
-        va = vc[btabs].reshape(s_count, l_eff, cfg.n_heads, cfg.head_dim)
-        return ka, va
-
-    def layer(h, xs):
-        lp, kc, vc = xs                   # kc/vc [n_blocks, bs, H, Dh]
-        return _decode_layer(h, lp, kc, vc, cfg, write_kv, mask,
-                             read_kv=read_kv, proj_fn=proj_fn)
-
-    x, (k_pool, v_pool) = lax.scan(
-        layer, x, (params["layers"], k_pool, v_pool)
-    )
+    x, k_pool, v_pool = _scan_layers_over_pool(
+        params, x, k_pool, v_pool, btabs, dest, off, 1, mask, cfg, proj_fn)
     logits = _head(params, x, cfg)
     # Greedy-only banks (the default) skip the sampler's full-vocab sort.
     nxt = lax.cond(
@@ -229,7 +272,11 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     their own table, so cross-lane isolation is structural, not masked.
     ``n_ctx`` (the traced table width) is the caller-bucketed context
     extent — the mask admits no key past a lane's last valid position,
-    so truncating the table to the prompt seen so far is lossless.
+    so truncating the table to the prompt seen so far is lossless. The
+    pools are ``[n_layers, n_blocks, bs, H * Dh]`` and carried through
+    the layer scan like decode's (``_scan_layers_over_pool``): a layer
+    scatters its K * C rows and gathers its K tables, each table's view
+    broadcast over the lane's C rows.
     Returns (first tokens [K] int32 — sampled with each request's
     settings at step 0, meaningful only on a lane's FINAL chunk — and
     the pools).
@@ -250,26 +297,8 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     mask = (jnp.arange(l_eff)[None, None, :]
             <= positions[:, :, None]).reshape(kk * c, 1, l_eff)
 
-    def write_kv(kc, vc, k, v):
-        kc = kc.at[dest, off].set(k.astype(kc.dtype))
-        vc = vc.at[dest, off].set(v.astype(vc.dtype))
-        return kc, vc
-
-    def read_kv(kc, vc):
-        hd = (l_eff, cfg.n_heads, cfg.head_dim)
-        full = (kk, c) + hd
-        ka = jnp.broadcast_to(kc[btabs].reshape((kk,) + hd)[:, None], full)
-        va = jnp.broadcast_to(vc[btabs].reshape((kk,) + hd)[:, None], full)
-        return ka.reshape((kk * c,) + hd), va.reshape((kk * c,) + hd)
-
-    def layer(h, xs):
-        lp, kc, vc = xs
-        return _decode_layer(h, lp, kc, vc, cfg, write_kv, mask,
-                             read_kv=read_kv, proj_fn=proj_fn)
-
-    x, (k_pool, v_pool) = lax.scan(
-        layer, x, (params["layers"], k_pool, v_pool)
-    )
+    x, k_pool, v_pool = _scan_layers_over_pool(
+        params, x, k_pool, v_pool, btabs, dest, off, c, mask, cfg, proj_fn)
     last = jnp.take_along_axis(
         x.reshape(kk, c, cfg.d_model),
         (n_valids - 1).astype(jnp.int32)[:, None, None], axis=1,
@@ -566,7 +595,7 @@ class GenerationEngine:
                  prefill_chunk: int = 32):
         """``mesh``: run the engine tensor-parallel — params laid out by
         the Megatron rules (models/gpt.PARTITION_RULES) and the paged
-        KV pool sharded on the heads axis over 'tp', so continuous
+        KV pool sharded on its flat heads axis over 'tp', so continuous
         batching scales past one chip's HBM/FLOPs. Greedy decoding stays
         token-identical to the single-device path (GSPMD inserts the
         all-reduces through prefill chunks, the batched decode step, and
@@ -611,11 +640,12 @@ class GenerationEngine:
             )
 
             params = shard_tree(mesh, params, PARTITION_RULES)
-            # Pool layout [n_layers, n_blocks, bs, H, Dh]: heads on tp.
+            # Pool layout [n_layers, n_blocks, bs, H * Dh]: the flat axis
+            # on tp, which keeps heads whole per shard (n_heads % tp == 0).
             # named_sharding drops absent/size-1 axes, so a tp-less mesh
             # degrades to replication like shard_tree does for params.
             self._cache_sharding = named_sharding(
-                mesh, None, None, None, "tp", None
+                mesh, None, None, None, "tp"
             )
             self._vec_sharding = named_sharding(mesh)
         else:
@@ -629,7 +659,7 @@ class GenerationEngine:
         self.max_slots = max_slots
         if self._cache_sharding is not None:
             # Allocate the pool directly sharded: staging the full
-            # unsharded [L, n_blocks, bs, H, Dh] zeros on one device
+            # unsharded [L, n_blocks, bs, H * Dh] zeros on one device
             # first would OOM exactly the configs the mesh exists for.
             self._k, self._v = jax.jit(
                 lambda: _block_pool_arrays(cfg, n_blocks, block_size),
