@@ -7,7 +7,10 @@ device-, or collective-bound.
 this report goes one level down, into what stepscope
 (``TPU_STEPSCOPE=1``) collects: per dispatch, host-dispatch time and, in
 ``sync`` mode only, device time and the clamped remainder, plus collectives
-charged per step, positions computed and context held; per delivery item,
+charged per step, positions computed and context held and, for a family
+with a routed expert layer, what its router did (tokens routed, the share
+of the experts held that got one, the most loaded expert against the mean:
+the ``routing <phase>`` rows); per delivery item,
 how long it queued for the delivery thread, its readback and its hand-over;
 what the engine thread did between dispatches (``ticket_wait`` /
 ``idle_wait`` / ``admit`` / ``join``); and one timeline per request
@@ -327,6 +330,31 @@ def _request_rows(requests: List[dict]) -> List[dict]:
     return rows
 
 
+def _routing(recs: List[dict]) -> Optional[dict]:
+    """What the router of a routed family did in a phase's dispatches
+    (stepscope ``ROUTING_FIELDS``, read back by the delivery thread): the
+    tokens routed a dispatch, the share of the experts held that got a
+    token, and the most loaded expert against the mean. None where no
+    record carries the counters (another family, or an older dump)."""
+    routed = [r for r in recs
+              if r.get("experts_held") and r.get("routed_tokens")]
+    if not routed:
+        return None
+    n = len(routed)
+    return {
+        "n": n,
+        "routed_tokens_per_step": round(
+            sum(r["routed_tokens"] for r in routed) / n, 1),
+        "experts_hit_share": round(
+            sum(r["experts_hit"] for r in routed)
+            / sum(r["experts_held"] for r in routed), 4),
+        # sum over sum: a dispatch counts by the tokens it routed
+        "load_max_over_mean": round(
+            sum(r["expert_load_max"] for r in routed)
+            / sum(r["expert_load_mean"] for r in routed), 2),
+    }
+
+
 def analyze(records: List[dict],
             compiles: Optional[Dict[str, Dict[str, dict]]] = None,
             requests: Optional[List[dict]] = None,
@@ -381,6 +409,9 @@ def analyze(records: List[dict],
                     sum(int(r.get("ctx_tokens", 0)) for r in ph) / n, 1
                 ),
             }
+            routing = _routing(ph)
+            if routing is not None:
+                phases[phase]["routing"] = routing
         n = len(recs)
         means = _stage_means(recs)
         coll = sum(_coll_count(r.get("collectives")) for r in recs) / n
@@ -467,6 +498,19 @@ def render(analysis: dict) -> str:
                 f"{ph.get('tokens_per_step', 0):>8} "
                 f"{ph.get('ctx_tokens_per_step', 0):>9}"
             )
+        # A routed family's router, per phase: how many of the experts a
+        # dispatch holds its tokens reached (what it had to read), and how
+        # unevenly (the grouped product's longest group).
+        for phase, ph in m["phases"].items():
+            routing = ph.get("routing")
+            if routing:
+                lines.append(
+                    f"  routing {phase:<14} {routing['n']:>6} dispatches, "
+                    f"{routing['routed_tokens_per_step']} tokens routed "
+                    f"each, {100 * routing['experts_hit_share']:.1f}% of "
+                    f"the experts held hit, load max/mean "
+                    f"{routing['load_max_over_mean']}"
+                )
         # The delivery thread's view of each dispatch's result (ms): a long
         # queue wait says items stand behind one another, a long readback
         # that the thread waited on the device. Not a device time.
@@ -829,6 +873,9 @@ def self_check() -> int:
     }]
     for r in dump["records"]:
         r["tokens"], r["ctx_tokens"] = 4, 400
+        if r["phase"] == "decode":      # a routed family's counters
+            r.update(routed_tokens=4, experts_hit=24, experts_held=64,
+                     expert_load_max=2, expert_load_mean=0.5)
     dump["deliveries"] = [
         {"model": "gpt_engine", "phase": "decode", "step_index": i,
          "queued_ns": 1_000_000 * i, "taken_ns": 1_000_000 * i + 250_000,
@@ -850,6 +897,11 @@ def self_check() -> int:
                 "prefill_span_ms": 6.0, "chunks": 2, "tokens": 3,
                 "worst_gap_ms": 4.0}]
             or m["phases"]["decode"]["ctx_tokens_per_step"] != 400
+            or m["phases"]["decode"].get("routing") != {
+                "n": m["phases"]["decode"]["n"],
+                "routed_tokens_per_step": 4.0, "experts_hit_share": 0.375,
+                "load_max_over_mean": 4.0}
+            or "routing decode" not in rendered
             or m["deliveries"] != {"decode": {
                 "n": 3, "queue_wait_ms": {"p50": 0.25, "p95": 0.25},
                 "readback_ms": {"p50": 0.5, "p95": 0.5},
@@ -859,7 +911,8 @@ def self_check() -> int:
             or "delivery decode" not in rendered
             or "worst_gap" not in rendered or "median" not in rendered):
         print("self-check [counters]: device clock invented, or loop "
-              "states / deliveries / requests lost", file=sys.stderr)
+              "states / deliveries / requests / routing lost",
+              file=sys.stderr)
         failures += 1
     else:
         print("self-check [counters]: ok")

@@ -873,3 +873,74 @@ def test_v5e_compiler_moves_no_pool_and_allocates_none(
     layer_pool_bytes = (n_blocks * block_size * heads * head_dim
                         * np.dtype(k_pool.dtype).itemsize)
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_v5e_compiler_moves_no_latent_pool_and_slices_no_expert_bank(
+        v5e_chip, compile_cache_off, program):
+    """The MLA / routed-expert family's steps (models/mla_moe.py), compiled
+    for the v5e at the published latent width (512 + 64, a page row padded
+    to 640 lanes: at 576 the chip lays the pool page-minor and every step
+    copies it in and out): no copy, dynamic-slice or dynamic-update-slice
+    with the pool's dimensions, and none with one layer's expert bank's
+    (the grouped product takes the stacked banks and the layer's index; a
+    scanned bank is sliced out, 2.4 GB a layer at the published sizes).
+    Kept in this file for its fixture: one process describes the chip."""
+    import re
+
+    import jax.numpy as jnp
+
+    from tritonclient_tpu.models import mla_moe
+
+    # Every published width of JoyAI-LLM-Flash (the config's defaults), one
+    # dense and two expert layers, a small vocabulary: at toy widths the
+    # compiler unrolls the layers and stages whole banks in fast memory,
+    # which says nothing about 256 experts of 2048 x 768.
+    slots, block_size, n_blocks, chunk = 2, 16, 48, 32
+    cfg = mla_moe.MlaMoeConfig(vocab_size=1024, n_layers=3, max_len=64)
+    model = mla_moe.MlaMoePaged(cfg)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    (pool,) = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.pool_arrays(n_blocks, block_size)))
+    assert pool.shape == (3, n_blocks, block_size, 640)
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "decode":
+        fn = model.decode_step(block_size)
+        args = (vec(i32, slots, cfg.max_len // block_size), vec(i32, slots),
+                vec(i32, slots), vec(i32, slots), vec(i32, slots),
+                vec(f32, slots), vec(i32, slots))
+    else:
+        fn = model.prefill_chunk(block_size)
+        args = (vec(i32, slots, chunk), vec(i32, slots, 2), vec(i32, slots),
+                vec(i32, slots), vec(i32, slots), vec(f32, slots),
+                vec(i32, slots))
+    began = time.monotonic()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    assert time.monotonic() - began < _COMPILE_SECONDS
+
+    shape = tuple(pool.shape)
+    bank = (cfg.n_experts, cfg.d_model, cfg.d_expert)
+    watched = {",".join(map(str, d)) for d in (
+        shape, shape[1:], (1,) + shape[1:], bank, (bank[0], bank[2], bank[1]),
+        (1,) + bank)}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \(?\w+\[([\d,]*)\]\S* "
+                     r"(copy|copy-start|dynamic-slice|dynamic-update-slice)"
+                     r"\(", line)
+        if m and m.group(1) in watched:
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    # ... nor under another name: the step's temporaries stay under one
+    # expert layer's bank (0.8 GB here).
+    bank_bytes = 2 * cfg.n_experts * cfg.d_model * cfg.d_expert
+    assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes

@@ -22,6 +22,14 @@ thread:
   ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
   opened in Perfetto or TensorBoard shows the host span beside the
   device's module;
+- for a family with a routed expert layer, what the router did
+  (``ROUTING_FIELDS``): ``routed_tokens``, ``experts_hit`` (distinct
+  experts that got a token, summed over the expert layers and the
+  micro-steps) of ``experts_held`` (layers x micro-steps x experts),
+  ``expert_load_max`` (the most tokens on one expert of one layer) against
+  ``expert_load_mean``. They come from the histogram the step returns,
+  which the delivery thread reads back behind the tokens (``step_routing``):
+  a record that is read before that has no such fields yet;
 - ``device_us`` / ``other_us``: **``sync`` mode only** — a bracketed
   ``jax.block_until_ready`` (true device wait) and the clamped remainder.
   Counters mode has no device clock: the engine thread's post-dispatch
@@ -177,7 +185,7 @@ class StepRecord:
         "dispatch_us", "device_us", "other_us", "total_us",
         "micro_steps", "coll_exposed_us", "coll_hidden_us",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
-        "_annotation",
+        "_annotation", "_entry",
     )
 
     def __init__(self, model: str, phase: str, step_index: int,
@@ -220,6 +228,7 @@ class StepRecord:
         self.thread_ident = thread.ident or 0
         self.thread_name = thread.name
         self._annotation = None
+        self._entry: Optional[dict] = None    # its dict in the ring
 
     def collective_count(self) -> int:
         return sum(c for c, _ in self.collectives.values())
@@ -372,7 +381,8 @@ class _Aggregator:
             worst = self.slowest.get(rec.model)
             if worst is None or rec.total_us > worst["total_us"]:
                 self.slowest[rec.model] = rec.as_dict()
-            self.ring.append(rec.as_dict())
+            rec._entry = rec.as_dict()
+            self.ring.append(rec._entry)
 
 
 _aggregator = _Aggregator()
@@ -497,6 +507,23 @@ def step_end(rec: Optional[StepRecord], outputs=None):
         rec.device_us = device_ns // 1000
         rec.other_us = max(total_ns - dispatch_ns - device_ns, 0) // 1000
     _aggregator.absorb(rec)
+
+
+ROUTING_FIELDS = ("routed_tokens", "experts_hit", "experts_held",
+                  "expert_load_max", "expert_load_mean")
+
+
+def step_routing(rec: Optional[StepRecord], counters: Optional[dict]):
+    """What the router of a routed family did in ``rec``'s dispatch
+    (``ROUTING_FIELDS``), from the per-layer histogram the step returned:
+    the engine's delivery thread reads that back behind the dispatch's
+    tokens and adds the counters to the record already in the ring, so the
+    engine loop never waits for them. Call after ``step_end``."""
+    if rec is None or not counters:
+        return
+    with _aggregator._lock:
+        if rec._entry is not None:
+            rec._entry.update(counters)
 
 
 def delivery_begin(rec: Optional[StepRecord]) -> Optional[dict]:

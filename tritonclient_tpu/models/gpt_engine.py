@@ -313,6 +313,137 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     return firsts, k_pool, v_pool
 
 
+class PagedModel:
+    """What the scheduler knows of a model family, and all it knows.
+
+    Admission, reservation, block tables, chunked prefill over context
+    buckets, decode with its fused widths and delivery are the same for
+    every family; the engine holds one of these and calls it. A family's
+    steps take ``(params, *pools, ...)`` and give back ``(tokens, *pools,
+    *extras)`` (fused: ``(token block, tokens, pos, steps, *pools,
+    *extras)``), every pool donated: the engine carries the pools from one
+    dispatch to the next and hands the extras, if there are any and
+    stepscope is on, to its delivery thread with the dispatch's record.
+    """
+
+    cfg = None      # has ``max_len``: the positions served (the table's width)
+
+    def pool_arrays(self, n_blocks: int, block_size: int) -> tuple:
+        """The page pools, each ``[n_layers, n_blocks, block_size, ...]``."""
+        raise NotImplementedError
+
+    def block_bytes(self, block_size: int) -> int:
+        """Bytes one block-table entry stands for, over every layer."""
+        raise NotImplementedError
+
+    def shard(self, mesh, params):
+        """``(params laid out on the mesh, the pools' sharding)``."""
+        raise NotImplementedError
+
+    def decode_step(self, block_size: int, proj_fn=None):
+        """The function to jit as the whole-bank decode step; its name is
+        the executable's on the device trace."""
+        raise NotImplementedError
+
+    def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
+        raise NotImplementedError
+
+    def prefill_chunk(self, block_size: int, proj_fn=None):
+        raise NotImplementedError
+
+    def routing(self, extras) -> Optional[dict]:
+        """Counters for the dispatch record, from a step's extras (called
+        on the delivery thread: it may read them back)."""
+        return None
+
+
+class GptPaged(PagedModel):
+    """The GPT family: two pools of ``H * Dh`` (keys, values), learned
+    positions, no extras. Its three wrappers look the step functions up in
+    this module when traced, so the HLO modules and the profile's `XLA
+    Modules` line read jit_decode_step / jit_decode_fused_<n> /
+    jit_prefill_chunk."""
+
+    def __init__(self, cfg: GptConfig):
+        self.cfg = cfg
+
+    def pool_arrays(self, n_blocks: int, block_size: int):
+        return _block_pool_arrays(self.cfg, n_blocks, block_size)
+
+    def block_bytes(self, block_size: int) -> int:
+        cfg = self.cfg
+        try:
+            itemsize = np.dtype(cfg.dtype).itemsize
+        except TypeError:
+            itemsize = 2  # bf16-family default
+        return (cfg.n_layers * 2 * block_size * cfg.n_heads * cfg.head_dim
+                * itemsize)
+
+    def shard(self, mesh, params):
+        from tritonclient_tpu.models.gpt import PARTITION_RULES
+        from tritonclient_tpu.parallel.sharding import (
+            named_sharding,
+            shard_tree,
+        )
+
+        # Pool layout [n_layers, n_blocks, bs, H * Dh]: the flat axis on
+        # tp, which keeps heads whole per shard (n_heads % tp == 0).
+        # named_sharding drops absent/size-1 axes, so a tp-less mesh
+        # degrades to replication like shard_tree does for params.
+        return (shard_tree(mesh, params, PARTITION_RULES),
+                named_sharding(mesh, None, None, None, "tp"))
+
+    def decode_step(self, block_size: int, proj_fn=None):
+        cfg = self.cfg
+
+        def decode_step(params, k_pool, v_pool, btabs, tokens, pos, seeds,
+                        steps, temps, topks):
+            return _decode_step_paged(
+                params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
+                temps, topks, cfg=cfg, block_size=block_size,
+                proj_fn=proj_fn)
+
+        return decode_step
+
+    def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
+        cfg = self.cfg
+
+        def decode_fused(params, k_pool, v_pool, btabs, tokens, pos,
+                         seeds, steps, temps, topks):
+            return _decode_multi_step_paged(
+                params, k_pool, v_pool, btabs, tokens, pos, seeds,
+                steps, temps, topks, cfg=cfg, block_size=block_size,
+                n_steps=n_steps, proj_fn=proj_fn)
+
+        # One name per width: jit_decode_fused_<n> on the device trace.
+        decode_fused.__name__ = f"decode_fused_{n_steps}"
+        decode_fused.__qualname__ = decode_fused.__name__
+        return decode_fused
+
+    def prefill_chunk(self, block_size: int, proj_fn=None):
+        cfg = self.cfg
+
+        def prefill_chunk(params, k_pool, v_pool, chunks, btabs, starts,
+                          n_valids, seeds, temps, topks):
+            return _prefill_chunk_paged(
+                params, k_pool, v_pool, chunks, btabs, starts, n_valids,
+                seeds, temps, topks, cfg=cfg, block_size=block_size,
+                proj_fn=proj_fn)
+
+        return prefill_chunk
+
+
+def wire_tensors():
+    """The engine models' wire contract: ``(inputs, outputs)``."""
+    return ([
+        TensorSpec("INPUT_IDS", "INT32", [-1, -1]),
+        TensorSpec("MAX_TOKENS", "INT32", [1], optional=True),
+        TensorSpec("TEMPERATURE", "FP32", [1], optional=True),
+        TensorSpec("TOP_K", "INT32", [1], optional=True),
+        TensorSpec("SEED", "INT64", [1], optional=True),
+    ], [TensorSpec("OUTPUT_IDS", "INT32", [-1])])
+
+
 class _Request:
     __slots__ = ("prompt", "max_new", "out", "remaining", "temperature",
                  "top_k", "seed", "cancelled", "cancel_event",
@@ -452,6 +583,16 @@ class _Distributor:
         else:
             self.q.put(("deliver", nxt_dev, pairs, delivery))
 
+    def submit_routing(self, scope, extras):
+        """What a step returned beside its tokens and pools (a routed
+        family's histogram), for the dispatch's record: queued behind the
+        dispatch's own item and read back on this thread, so the engine
+        loop waits for nothing. Only when stepscope is on and the family
+        returns something; call it after ``step_end``."""
+        if scope is not None and extras:
+            self._start()
+            self.q.put(("routing", scope, extras))
+
     def submit_cancel(self, req):
         """Terminate a cancelled request IN DELIVERY ORDER: the None
         terminator lands after every token already in the pipe, and
@@ -503,6 +644,15 @@ class _Distributor:
                 if req.remaining > 0:
                     req.remaining = 0
                     req.end(None, _stepscope.OUTCOME_CANCELLED)
+                continue
+            if item[0] == "routing":
+                # Control item too. A readback that fails here fails the
+                # dispatch's own delivery as well, which reports it.
+                try:
+                    _stepscope.step_routing(
+                        item[1], self._engine._model.routing(item[2]))
+                except Exception:  # noqa: BLE001
+                    pass
                 continue
             delivery = item[3]
             if delivery is not None:
@@ -589,11 +739,15 @@ class _Distributor:
 class GenerationEngine:
     """The continuous-batching scheduler around the paged block pool."""
 
-    def __init__(self, cfg: GptConfig, params: Dict, max_slots: int = 8,
+    def __init__(self, cfg, params: Dict, max_slots: int = 8,
                  mesh=None, scope_name: str = "gpt_engine",
                  block_size: int = 16, n_blocks: Optional[int] = None,
                  prefill_chunk: int = 32):
-        """``mesh``: run the engine tensor-parallel — params laid out by
+        """``cfg``: a ``GptConfig`` (the GPT family) or any ``PagedModel``;
+        from here on the engine asks the family for its pools, its steps
+        and its bytes a page, and for nothing else.
+
+        ``mesh``: run the engine tensor-parallel — params laid out by
         the Megatron rules (models/gpt.PARTITION_RULES) and the paged
         KV pool sharded on its flat heads axis over 'tp', so continuous
         batching scales past one chip's HBM/FLOPs. Greedy decoding stays
@@ -609,7 +763,9 @@ class GenerationEngine:
         behavior to the old slot bank unless the caller sizes the pool
         smaller. ``prefill_chunk`` is the single compiled prefill shape.
         """
-        self.cfg = cfg
+        model = cfg if isinstance(cfg, PagedModel) else GptPaged(cfg)
+        self._model = model
+        self.cfg = cfg = model.cfg
         self.mesh = mesh
         if cfg.max_len % block_size:
             raise ValueError(
@@ -620,33 +776,15 @@ class GenerationEngine:
         self.block_size = block_size
         self._max_blocks = cfg.max_len // block_size   # per-slot table width
         # Bytes one block-table entry makes a step touch, across every
-        # layer's K and V page (the stepscope kv_bytes accounting unit).
-        try:
-            itemsize = np.dtype(cfg.dtype).itemsize
-        except TypeError:
-            itemsize = 2  # bf16-family default
-        self._block_kv_bytes = (
-            cfg.n_layers * 2 * block_size * cfg.n_heads * cfg.head_dim
-            * itemsize
-        )
+        # layer's pages (the stepscope kv_bytes accounting unit).
+        self._block_kv_bytes = model.block_bytes(block_size)
         if n_blocks is None:
             n_blocks = 1 + max_slots * self._max_blocks
         self.prefill_chunk = max(1, min(int(prefill_chunk), cfg.max_len))
         if mesh is not None:
-            from tritonclient_tpu.models.gpt import PARTITION_RULES
-            from tritonclient_tpu.parallel.sharding import (
-                named_sharding,
-                shard_tree,
-            )
+            from tritonclient_tpu.parallel.sharding import named_sharding
 
-            params = shard_tree(mesh, params, PARTITION_RULES)
-            # Pool layout [n_layers, n_blocks, bs, H * Dh]: the flat axis
-            # on tp, which keeps heads whole per shard (n_heads % tp == 0).
-            # named_sharding drops absent/size-1 axes, so a tp-less mesh
-            # degrades to replication like shard_tree does for params.
-            self._cache_sharding = named_sharding(
-                mesh, None, None, None, "tp"
-            )
+            params, self._cache_sharding = model.shard(mesh, params)
             self._vec_sharding = named_sharding(mesh)
         else:
             self._cache_sharding = None
@@ -657,16 +795,18 @@ class GenerationEngine:
         # devices; replication charges every device its full size).
         _memscope.register_params(scope_name, params)
         self.max_slots = max_slots
+        # The family's page pools, carried from one dispatch to the next
+        # (every step donates them and gives them back).
         if self._cache_sharding is not None:
-            # Allocate the pool directly sharded: staging the full
+            # Allocate the pools directly sharded: staging the full
             # unsharded [L, n_blocks, bs, H * Dh] zeros on one device
             # first would OOM exactly the configs the mesh exists for.
-            self._k, self._v = jax.jit(
-                lambda: _block_pool_arrays(cfg, n_blocks, block_size),
-                out_shardings=(self._cache_sharding, self._cache_sharding),
-            )()
+            self._pools = tuple(jax.jit(
+                lambda: model.pool_arrays(n_blocks, block_size),
+                out_shardings=self._cache_sharding,
+            )())
         else:
-            self._k, self._v = _block_pool_arrays(cfg, n_blocks, block_size)
+            self._pools = tuple(model.pool_arrays(n_blocks, block_size))
         # Host-side allocation state. The first alloc deterministically
         # returns page 0 — pinned forever as the SCRATCH page that idle
         # and still-prefilling slots write into.
@@ -756,27 +896,13 @@ class GenerationEngine:
         )
         self._coll_us: Optional[float] = None  # lazy calibration
         self._prefill_seq = 0
-        # The engine's executables are jitted under their own names, so the
-        # HLO modules and the profile's `XLA Modules` line read
-        # jit_decode_step / jit_decode_fused_<n> / jit_prefill_chunk. The
-        # wrappers look the step functions up in this module when traced.
-        proj_fn = self._proj_fn
-
-        def decode_step(params, k_pool, v_pool, btabs, tokens, pos, seeds,
-                        steps, temps, topks):
-            return _decode_step_paged(
-                params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
-                temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn)
-
-        def prefill_chunk(params, k_pool, v_pool, chunks, btabs, starts,
-                          n_valids, seeds, temps, topks):
-            return _prefill_chunk_paged(
-                params, k_pool, v_pool, chunks, btabs, starts, n_valids,
-                seeds, temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn)
-
-        self._step = jax.jit(decode_step, donate_argnums=(1, 2))
+        # The engine's executables are jitted under the names the family
+        # gives its step functions (the GPT family: jit_decode_step /
+        # jit_decode_fused_<n> / jit_prefill_chunk on the profile's `XLA
+        # Modules` line). Every pool is donated.
+        self._donate = tuple(range(1, 1 + len(self._pools)))
+        self._step = jax.jit(model.decode_step(block_size, self._proj_fn),
+                             donate_argnums=self._donate)
         # Unfused-branch slot clocks advance through a donating jit so
         # the dead pos/steps buffers are reused in place on TPU.
         self._advance = jax.jit(_advance_slot_clocks, donate_argnums=(0, 1))
@@ -789,8 +915,9 @@ class GenerationEngine:
         )
         self._multi_step: Dict[int, object] = {}
         self._dispatched = [0] * max_slots  # decode tokens dispatched/slot
-        self._prefill_chunk_fn = jax.jit(prefill_chunk,
-                                         donate_argnums=(1, 2))
+        self._prefill_chunk_fn = jax.jit(
+            model.prefill_chunk(block_size, self._proj_fn),
+            donate_argnums=self._donate)
         # /metrics registry: weakly bound so a dropped engine vanishes
         # from the exposition instead of being pinned by it.
         import weakref
@@ -815,6 +942,39 @@ class GenerationEngine:
         import atexit
 
         atexit.register(lambda: (lambda e: e and e.shutdown())(ref()))
+
+    def _keep_pools(self, result, lead: int):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        """Take the pools back from a step's result ``(lead arrays, *pools,
+        *extras)``; returns ``(the lead arrays, the extras)``."""
+        n = lead + len(self._pools)
+        self._pools = tuple(result[lead:n])
+        return result[:lead], result[n:]
+
+    # The GPT family's two pools under the names its benchmark adapter,
+    # chip_smoke.py and the tests take and put them by.
+    @property
+    def _k(self):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        return self._pools[0]
+
+    @_k.setter
+    def _k(self, pool):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        self._pools = (pool,) + self._pools[1:]
+
+    @property
+    def _v(self):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        return self._pools[1]
+
+    @_v.setter
+    def _v(self, pool):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        self._pools = self._pools[:1] + (pool,) + self._pools[2:]
+
+    def release_pools(self):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
+        """Free the page pools' device memory (after ``shutdown``: whoever
+        takes the engine down to give the device to something else)."""
+        for pool in self._pools:
+            if pool is not None and not pool.is_deleted():
+                pool.delete()
+        self._pools = (None,) * len(self._pools)
 
     def shutdown(self, timeout: float = 10.0):
         """Stop the engine loop (in-flight step finishes; queued and
@@ -1042,21 +1202,10 @@ class GenerationEngine:
         TPU_ENGINE_FUSE_STEPS, so the shape family stays tiny)."""
         fn = self._multi_step.get(n_steps)
         if fn is None:
-            cfg, block_size, proj_fn = (self.cfg, self.block_size,
-                                        self._proj_fn)
-
-            def decode_fused(params, k_pool, v_pool, btabs, tokens, pos,
-                             seeds, steps, temps, topks):
-                return _decode_multi_step_paged(
-                    params, k_pool, v_pool, btabs, tokens, pos, seeds,
-                    steps, temps, topks, cfg=cfg, block_size=block_size,
-                    n_steps=n_steps, proj_fn=proj_fn)
-
-            # One name per width: jit_decode_fused_<n> on the device trace.
-            decode_fused.__name__ = f"decode_fused_{n_steps}"
-            decode_fused.__qualname__ = decode_fused.__name__
             fn = self._multi_step[n_steps] = jax.jit(
-                decode_fused, donate_argnums=(1, 2))
+                self._model.decode_fused(self.block_size, n_steps,
+                                         self._proj_fn),
+                donate_argnums=self._donate)
         return fn
 
     def _choose_fuse(self, active: List[int]) -> int:  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
@@ -1306,12 +1455,12 @@ class GenerationEngine:
         _stepscope.note_compile(
             self._scope_name, "prefill_chunk", f"{kk}x{c}x{n_ctx}"
         )
-        firsts_dev, self._k, self._v = self._prefill_chunk_fn(
-            self.params, self._k, self._v, jnp.asarray(chunks),
+        (firsts_dev,), extras = self._keep_pools(self._prefill_chunk_fn(
+            self.params, *self._pools, jnp.asarray(chunks),
             jnp.asarray(btab_rows), jnp.asarray(starts),
             jnp.asarray(n_valids), jnp.asarray(seeds),
             jnp.asarray(temps), jnp.asarray(topks),
-        )
+        ), 1)
         _stepscope.step_dispatched(scope)
         _stepscope.charge_collectives(scope, self._expected_collectives)
         # The dispatch return, on the requests' timelines too.
@@ -1335,6 +1484,7 @@ class GenerationEngine:
             except AttributeError:
                 pass
         _stepscope.step_end(scope, outputs=firsts_dev)
+        self._dist.submit_routing(scope, extras)
         if not done:
             return True
         # stepscope's ``join`` loop state: from here to the hand-over of
@@ -1493,17 +1643,17 @@ class GenerationEngine:
             while True:
                 for n_ctx in buckets:
                     z = jnp.zeros((kk,), jnp.int32)
-                    _, self._k, self._v = self._prefill_chunk_fn(
-                        self.params, self._k, self._v,
+                    self._keep_pools(self._prefill_chunk_fn(
+                        self.params, *self._pools,
                         jnp.zeros((kk, c), jnp.int32),
                         jnp.zeros((kk, n_ctx), jnp.int32),
                         z, jnp.ones((kk,), jnp.int32), z,
                         jnp.zeros((kk,), jnp.float32), z,
-                    )
+                    ), 1)
                 if kk >= self.max_slots:
                     break
                 kk = min(kk * 2, self.max_slots)
-            jax.block_until_ready(self._k)
+            jax.block_until_ready(self._pools[0])
 
     def _run(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         try:
@@ -1658,11 +1808,11 @@ class GenerationEngine:
                 f"bank:{self.max_slots}x{self._max_blocks}:fuse:{fuse}",
             )
             if fuse == 1:
-                toks, self._k, self._v = self._step(
-                    self.params, self._k, self._v, self._btabs,
+                (toks,), extras = self._keep_pools(self._step(
+                    self.params, *self._pools, self._btabs,
                     self._tokens, self._pos, self._seeds, self._steps,
                     self._temps, self._topks,
-                )
+                ), 1)
                 self._tokens = toks
                 self._pos, self._steps = self._advance(
                     self._pos, self._steps
@@ -1670,12 +1820,13 @@ class GenerationEngine:
             else:
                 # Fused window: one dispatch, [fuse, S] tokens, carry
                 # advanced on device (no per-step host enqueues).
-                (toks, self._tokens, self._pos, self._steps,
-                 self._k, self._v) = self._multi_step_fn(fuse)(
-                    self.params, self._k, self._v, self._btabs,
-                    self._tokens, self._pos, self._seeds, self._steps,
-                    self._temps, self._topks,
-                )
+                (toks, self._tokens, self._pos,
+                 self._steps), extras = self._keep_pools(
+                    self._multi_step_fn(fuse)(
+                        self.params, *self._pools, self._btabs,
+                        self._tokens, self._pos, self._seeds, self._steps,
+                        self._temps, self._topks,
+                    ), 4)
             _stepscope.step_dispatched(scope)
             if scope is not None:
                 ops = self._expected_collectives if fuse == 1 else {
@@ -1708,6 +1859,7 @@ class GenerationEngine:
             # at the cost of the host/device overlap); counters mode only
             # stamps the clock.
             _stepscope.step_end(scope, outputs=toks)
+            self._dist.submit_routing(scope, extras)
 
 
 class GptEngineModel(Model):
@@ -1735,14 +1887,7 @@ class GptEngineModel(Model):
                  n_blocks: Optional[int] = None, prefill_chunk: int = 32):
         super().__init__()
         self.cfg = cfg or gpt_small()
-        self.inputs = [
-            TensorSpec("INPUT_IDS", "INT32", [-1, -1]),
-            TensorSpec("MAX_TOKENS", "INT32", [1], optional=True),
-            TensorSpec("TEMPERATURE", "FP32", [1], optional=True),
-            TensorSpec("TOP_K", "INT32", [1], optional=True),
-            TensorSpec("SEED", "INT64", [1], optional=True),
-        ]
-        self.outputs = [TensorSpec("OUTPUT_IDS", "INT32", [-1])]
+        self.inputs, self.outputs = wire_tensors()
         key = jax.random.PRNGKey(seed)
         if mesh is not None:
             # Initialize DIRECTLY sharded — no single-device staging copy
