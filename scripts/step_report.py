@@ -330,6 +330,23 @@ def _request_rows(requests: List[dict]) -> List[dict]:
     return rows
 
 
+def _pages_read_share(recs: List[dict]) -> Optional[float]:
+    """Σ ``ctx_pages`` / Σ table pages (``lanes`` x ``ctx_blocks`` a
+    micro-step) of a phase's dispatches: the share of the tables' width
+    that lay under the lanes' lengths. None where no record carries
+    ``ctx_pages`` (a phase with no block table, an older dump)."""
+    pages = sum(int(r.get("ctx_pages", 0)) for r in recs)
+    table = sum(int(r.get("lanes", 0)) * int(r.get("ctx_blocks", 0))
+                * max(int(r.get("micro_steps", 1) or 1), 1) for r in recs)
+    if not pages or not table:
+        return None
+    return round(pages / table, 3)
+
+
+def _dash(value) -> str:
+    return "-" if value is None else str(value)
+
+
 def _routing(recs: List[dict]) -> Optional[dict]:
     """What the router of a routed family did in a phase's dispatches
     (stepscope ``ROUTING_FIELDS``, read back by the delivery thread): the
@@ -408,6 +425,11 @@ def analyze(records: List[dict],
                 "ctx_tokens_per_step": round(
                     sum(int(r.get("ctx_tokens", 0)) for r in ph) / n, 1
                 ),
+                # Table entries under the lanes' lengths (what a paged
+                # kernel visits) over the tables' whole width (what a
+                # gather reads: lanes x ctx_blocks a micro-step); absent
+                # on dumps from before the record had ``ctx_pages``.
+                "pages_read_share": _pages_read_share(ph),
             }
             routing = _routing(ph)
             if routing is not None:
@@ -484,7 +506,8 @@ def render(analysis: dict) -> str:
         lines.append(
             f"  {'phase':<10} {'n':>6} {'p50_us':>8} {'p99_us':>8} "
             f"{'dispatch':>9} {'device':>8} {'other':>7} {'coll':>6} "
-            f"{'batch':>6} {'kv_MB':>8} {'tokens':>8} {'ctx_tok':>9}"
+            f"{'batch':>6} {'kv_MB':>8} {'tokens':>8} {'ctx_tok':>9} "
+            f"{'pages/table':>11}"
         )
         for phase, ph in m["phases"].items():
             pm = ph["mean_us"]
@@ -496,7 +519,8 @@ def render(analysis: dict) -> str:
                 f"{ph['collectives_per_step']:>6} "
                 f"{ph['mean_batch']:>6} {kv_mb:>8.2f} "
                 f"{ph.get('tokens_per_step', 0):>8} "
-                f"{ph.get('ctx_tokens_per_step', 0):>9}"
+                f"{ph.get('ctx_tokens_per_step', 0):>9} "
+                f"{_dash(ph.get('pages_read_share')):>11}"
             )
         # A routed family's router, per phase: how many of the experts a
         # dispatch holds its tokens reached (what it had to read), and how
@@ -873,6 +897,8 @@ def self_check() -> int:
     }]
     for r in dump["records"]:
         r["tokens"], r["ctx_tokens"] = 4, 400
+        if r["phase"] == "decode":      # 8 lanes x 64 entries, 128 live
+            r.update(lanes=8, ctx_blocks=64, ctx_pages=128)
         if r["phase"] == "decode":      # a routed family's counters
             r.update(routed_tokens=4, experts_hit=24, experts_held=64,
                      expert_load_max=2, expert_load_mean=0.5)
@@ -897,6 +923,8 @@ def self_check() -> int:
                 "prefill_span_ms": 6.0, "chunks": 2, "tokens": 3,
                 "worst_gap_ms": 4.0}]
             or m["phases"]["decode"]["ctx_tokens_per_step"] != 400
+            or m["phases"]["decode"]["pages_read_share"] != 0.25
+            or "pages/table" not in rendered
             or m["phases"]["decode"].get("routing") != {
                 "n": m["phases"]["decode"]["n"],
                 "routed_tokens_per_step": 4.0, "experts_hit_share": 0.375,

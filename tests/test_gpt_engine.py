@@ -811,15 +811,23 @@ _COMPILE_SECONDS = 120.0   # a two-layer program compiles in a few seconds
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
 @pytest.mark.parametrize("heads,head_dim", [(25, 64), (16, 128)])
 def test_v5e_compiler_moves_no_pool_and_allocates_none(
-        v5e_chip, compile_cache_off, heads, head_dim, program):
+        v5e_chip, compile_cache_off, monkeypatch, heads, head_dim, program):
     """Compiled for the v5e, a step holds no copy, dynamic-slice or
     dynamic-update-slice with the pool's or one layer's dimensions, and
     its temporaries stay under one layer's pool: the scatter is in place
-    on the donated buffer and the gather reads the table's pages only.
-    A 5-D pool fails the 25 x 64 case: the chip's default layout puts the
-    page axis minor there and every layer's pages are re-laid out."""
+    on the donated buffer and the attention is the Mosaic kernel over the
+    whole pools. A 5-D pool fails the 25 x 64 case: the chip's default
+    layout puts the page axis minor there and every layer's pages are
+    re-laid out. At both head shapes the program holds the kernel's custom
+    call, no float32 array as large as the table's view (slots x table
+    positions x H x Dh elements) and no gather of the table's pages:
+    nothing of the table's width is read outside the kernel."""
     import functools
     import re
+
+    # The kernel compiles for the chip the program is compiled for; this
+    # process's own backend is the CPU, which would pick the interpreter.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     import jax.numpy as jnp
 
@@ -862,14 +870,34 @@ def test_v5e_compiler_moves_no_pool_and_allocates_none(
     pool = tuple(k_pool.shape)
     pool_dims = {",".join(map(str, d))
                  for d in (pool, pool[1:], (1,) + pool[1:])}
-    moved = []
-    for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?\S+ = \(?\w+\[([\d,]*)\]\S* "
-                     r"(copy|copy-start|dynamic-slice|dynamic-update-slice)"
-                     r"\(", line)
-        if m and m.group(1) in pool_dims:
+    table_elements = args[0].shape[0] * (
+        args[1 if program == "prefill_chunk" else 0].shape[1]
+        * block_size) * heads * head_dim
+    moved, wide, gathers = [], [], []
+    text = compiled.as_text()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \(?(\w+)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", line)
+        if not m:
+            continue
+        dtype, dims, op = m.groups()
+        if (op in ("copy", "copy-start", "dynamic-slice",
+                   "dynamic-update-slice") and dims in pool_dims):
             moved.append(line.strip()[:160])
+        if not dims or np.prod(
+                [int(d) for d in dims.split(",")]) < table_elements:
+            continue
+        if dtype == "f32":
+            wide.append(line.strip()[:160])
+        if op == "gather" or "gather" in line.split("calls=")[-1]:
+            gathers.append(line.strip()[:160])
     assert not moved, moved
+    # The widest float32 arrays left are the feed-forward's and the logits'.
+    assert not [w for w in wide if "custom-call" not in w
+                and str(4 * heads * head_dim) not in w
+                and str(cfg.vocab_size) not in w], wide
+    assert not gathers, gathers
+    assert "tpu_custom_call" in text
     layer_pool_bytes = (n_blocks * block_size * heads * head_dim
                         * np.dtype(k_pool.dtype).itemsize)
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes

@@ -672,6 +672,47 @@ def test_dispatch_records_say_what_the_work_was_and_when_it_reached_the_host():
     assert starts == sorted(starts)
 
 
+@pytest.mark.parametrize("lens", [[19], [19, 23, 31, 37]],
+                         ids=["one_request", "four_requests"])
+def test_dispatch_records_count_the_pages_under_the_lanes_lengths(lens):
+    """``ctx_pages`` is what the paged-attention kernel visits: for a decode
+    dispatch the sum over its live slots (and its micro-steps) of
+    ceil((pos + 1) / block_size), pos + 1 being the context the slot holds;
+    for a chunk dispatch the pages under each real lane's last position.
+    With one request in flight the record's ``ctx_tokens`` is that one
+    slot's, so the count is checked exactly; with four, between its bounds.
+    It never passes the table's width, and ``kv_bytes`` is reckoned from it
+    (the GPT family reads the pages held, not the table)."""
+    from tritonclient_tpu.models.gpt_engine import GptPaged
+
+    bs = 16
+    _, _, doc = _timeline_run(lens, 40)
+    records = _dispatches(doc["records"])
+    decode = [r for r in records if r["phase"] == _stepscope.PHASE_DECODE]
+    chunks = [r for r in records
+              if r["phase"] == _stepscope.PHASE_PREFILL_CHUNK]
+    assert decode and chunks
+    block_bytes = GptPaged(gpt.gpt_tiny(max_len=128)).block_bytes(bs)
+    for r in decode + chunks:
+        steps = r["micro_steps"]
+        assert 0 < r["ctx_pages"] <= steps * r["lanes"] * r["ctx_blocks"]
+        assert r["kv_bytes"] == r["ctx_pages"] * block_bytes
+    for r in decode:
+        steps, live, held = r["micro_steps"], r["batch_size"], r["ctx_tokens"]
+        if live == 1:
+            assert r["ctx_pages"] == sum(
+                -(-(held + i) // bs) for i in range(steps))
+        # every slot's ceil is at least its share and under one page more
+        grown = steps * held + live * steps * (steps - 1) // 2
+        assert -(-grown // bs) <= r["ctx_pages"] < grown / bs + steps * live
+    if len(lens) == 1:
+        assert any(r["micro_steps"] > 1 for r in decode)   # fused counted too
+        assert [r["ctx_pages"] for r in chunks] == [1, 1, 2]   # 8, 16, 19
+    for r in chunks:
+        assert -(-r["ctx_tokens"] // bs) <= r["ctx_pages"] \
+            < r["ctx_tokens"] / bs + r["batch_size"]
+
+
 def test_loop_states_enter_the_ring_and_nothing_else():
     """ticket_wait / idle_wait / admit are records in the ring with the
     harness's fields, overlap neither a dispatch's bracket nor each other,

@@ -14,9 +14,12 @@ thread:
 - step index, phase (``prefill`` / ``prefill_chunk`` / ``decode`` /
   ``compute``), batch size and slot occupancy;
 - what the work was: ``lanes`` (the padded lane bucket; ``max_slots`` for
-  decode), ``ctx_blocks`` (the block-table width the executable gathers),
-  ``tokens`` (positions computed) and ``ctx_tokens`` (context really held
-  by the real lanes, from host-side state);
+  decode), ``ctx_blocks`` (the width of the block table the executable is
+  given: what a table-wide gather reads, every lane and micro-step),
+  ``ctx_pages`` (the table entries under the real lanes' lengths, summed
+  over the micro-steps: what a paged-attention kernel visits, and the GPT
+  family's does), ``tokens`` (positions computed) and ``ctx_tokens``
+  (context really held by the real lanes), all from host-side state;
 - ``dispatch_us``: host time from step begin to dispatch return (trace +
   XLA dispatch of the jitted call); the same bracket is a
   ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
@@ -180,7 +183,7 @@ class StepRecord:
 
     __slots__ = (
         "model", "phase", "step_index", "batch_size", "slots",
-        "lanes", "ctx_blocks", "tokens", "ctx_tokens",
+        "lanes", "ctx_blocks", "ctx_pages", "tokens", "ctx_tokens",
         "t_begin", "t_dispatch", "t_end",
         "dispatch_us", "device_us", "other_us", "total_us",
         "micro_steps", "coll_exposed_us", "coll_hidden_us",
@@ -196,11 +199,13 @@ class StepRecord:
         self.step_index = step_index
         self.batch_size = batch_size
         self.slots = slots
-        # What the work was: the padded lane bucket and the block-table
-        # width the executable gathers; positions computed and context
-        # really held (set by the engine on the thread-owned record).
+        # What the work was: the padded lane bucket and the width of the
+        # block table the executable is given; the table entries under the
+        # real lanes' lengths, positions computed and context really held
+        # (set by the engine on the thread-owned record).
         self.lanes = lanes
         self.ctx_blocks = ctx_blocks
+        self.ctx_pages = 0
         self.tokens = 0
         self.ctx_tokens = 0
         self.t_begin = time.monotonic_ns()
@@ -220,9 +225,10 @@ class StepRecord:
         self.coll_hidden_us = 0
         # op -> [count, bytes]
         self.collectives: Dict[str, List[int]] = {}
-        # Paged-KV bytes this step touched (blocks gathered x block
-        # bytes from the block-table extent); the engine sets it on the
-        # thread-owned record before step_end.
+        # Paged-KV bytes this step's attention read: ``ctx_pages`` x block
+        # bytes where the family's kernel reads the pages held, the
+        # block-table extent x block bytes where it gathers the table; the
+        # engine sets it on the thread-owned record before step_end.
         self.kv_bytes = 0
         thread = threading.current_thread()
         self.thread_ident = thread.ident or 0
@@ -242,6 +248,7 @@ class StepRecord:
             "slots": self.slots,
             "lanes": self.lanes,
             "ctx_blocks": self.ctx_blocks,
+            "ctx_pages": self.ctx_pages,
             "tokens": self.tokens,
             "ctx_tokens": self.ctx_tokens,
             "start_ns": self.t_begin,

@@ -176,8 +176,9 @@ def prefill(params: Dict, tokens: jax.Array, cfg: GptConfig,
 
     tokens [B, L] → (logits_last [B, vocab], (k_cache, v_cache)).
     ``attention_fn(q, k, v)`` must be causal; pass a flash_attention
-    closure for long prompts (decode stays the masked-cache einsum —
-    single-query attention is cache-bandwidth-bound, not MXU-bound).
+    closure for long prompts. (``decode_step`` attends this contiguous
+    cache with a masked einsum; the paged engine's decode and prefill
+    read their pages through ``ops/paged_attention.py``.)
     """
     atn = attention_fn or functools.partial(
         dot_product_attention, causal=True
@@ -197,23 +198,39 @@ def prefill(params: Dict, tokens: jax.Array, cfg: GptConfig,
     return logits, (k_cache, v_cache)
 
 
-def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, mask,
-                  read_kv=None, proj_fn=None):
+def _masked_cache_attention(q, kc, vc, mask):
+    """q [N, H, Dh] against a dense cache kc/vc [N, L, H, Dh] → [N, H, Dh]
+    float32; ``mask`` broadcasts against the [N, H, L] scores. The
+    contiguous cache's attention, and the float32 reference the paged
+    kernel (``ops/paged_attention.py``) is tested against: one query row
+    a cache is bound by the cache read, which a masked einsum does in one
+    pass over a cache that is dense."""
+    s = jnp.einsum(
+        "nhd,nlhd->nhl",
+        q.astype(jnp.float32) / np.sqrt(q.shape[-1]),
+        kc.astype(jnp.float32),
+    )
+    s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nhl,nlhd->nhd", p, vc.astype(jnp.float32))
+
+
+def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, attend,
+                  proj_fn=None):
     """Single-token decoder layer, shared by the per-request decode path
     (`decode_step`) and the continuous-batching slot bank
-    (models/gpt_engine.py) — one source of truth for the LN/QKV/masked-
-    cache-attention/MLP math, parameterized only by how the new token's
-    K/V enter the cache and how valid positions are masked.
+    (models/gpt_engine.py) — one source of truth for the LN/QKV/
+    attention/MLP math, parameterized only by how the new token's K/V
+    enter the cache and how the cache is attended.
 
-    h [N, d]; kc/vc [N, L, H, Dh]; ``write_kv(kc, vc, k, v)`` inserts the
-    [N, H, Dh] projections; ``mask`` broadcasts against [N, H, L] scores.
-    ``read_kv(kc, vc)`` (optional) maps the written cache to the [N, L, H,
-    Dh] attention operands — the paged engine passes the block-table
-    gather here (kc/vc are then its whole [L, n_blocks, bs, H * Dh] pools,
-    which only its ``write_kv``/``read_kv`` index) while the contiguous
-    paths read the cache directly. Decode is bandwidth-bound
-    on the cache read — the MXU-free regime where a flash kernel buys
-    nothing — so a masked einsum is the kernel.
+    h [N, d]; ``write_kv(kc, vc, k, v)`` inserts the [N, H, Dh]
+    projections into kc/vc; ``attend(q, kc, vc)`` gives the [N, H, Dh]
+    attention of the queries over the written cache. The contiguous path
+    holds kc/vc [N, L, H, Dh] and attends by ``_masked_cache_attention``;
+    the paged engine passes its whole [L, n_blocks, bs, H * Dh] pools,
+    which only its ``write_kv`` (a scatter at layer, page, offset) and its
+    ``attend`` (the paged-attention kernel, which reads the pages a table
+    holds) index.
 
     ``proj_fn(x, w, b)`` (optional) computes the two row-parallel
     projections (attention output ``wo``, FFN down ``w_out``); the tp
@@ -228,17 +245,8 @@ def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, mask,
     qkv = a @ lp["wqkv"] + lp["bqkv"]
     q, k, v = jnp.split(qkv, 3, axis=-1)
     hd = (n, cfg.n_heads, cfg.head_dim)
-    q = q.reshape(hd)
     kc, vc = write_kv(kc, vc, k.reshape(hd), v.reshape(hd))
-    ka, va = (kc, vc) if read_kv is None else read_kv(kc, vc)
-    s = jnp.einsum(
-        "nhd,nlhd->nhl",
-        q.astype(jnp.float32) / np.sqrt(cfg.head_dim),
-        ka.astype(jnp.float32),
-    )
-    s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("nhl,nlhd->nhd", p, va.astype(jnp.float32))
+    out = attend(q.reshape(hd), kc, vc)
     out = out.reshape(n, cfg.d_model).astype(h.dtype)
     h = h + proj_fn(out, lp["wo"], lp["bo"])
     m = _layer_norm(h, lp["ln2_scale"], lp["ln2_bias"], cfg.layer_norm_eps)
@@ -268,11 +276,13 @@ def decode_step(params: Dict, k_cache, v_cache, token: jax.Array,
         )
         return kc, vc
 
-    mask = (jnp.arange(cfg.max_len) <= pos)[None, None, :]
+    attend = functools.partial(
+        _masked_cache_attention,
+        mask=(jnp.arange(cfg.max_len) <= pos)[None, None, :])
 
     def layer(h, xs):
         lp, kc, vc = xs
-        return _decode_layer(h, lp, kc, vc, cfg, write_kv, mask)
+        return _decode_layer(h, lp, kc, vc, cfg, write_kv, attend)
 
     x, (k_cache, v_cache) = lax.scan(
         layer, x, (params["layers"], k_cache, v_cache)

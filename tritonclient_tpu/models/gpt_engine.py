@@ -21,12 +21,13 @@ TPU-first mechanics:
   * static shapes everywhere: the pool, the block tables, and the slot
     vectors never change shape, so the decode step compiles exactly
     once; block-table indices are TRACED operands — paging costs a
-    gather, never a recompile;
+    table look-up, never a recompile;
   * per-slot cache writes are batched scatters into pages
-    (``.at[dest_block, offset]``); the attention read gathers
-    ``pool[block_table]`` back to the dense ``[S, max_len, H, Dh]``
-    geometry, so the masked-einsum decode math is IDENTICAL to the old
-    contiguous bank (token-for-token, tested);
+    (``.at[dest_block, offset]``); the GPT family's attention reads the
+    pages under each slot's position where they lie, through one Pallas
+    kernel over the whole pool (``ops/paged_attention.py``), in the
+    float32 mathematics of the old contiguous bank's masked einsum
+    (token-for-token against it, tested);
   * block 0 is the reserved SCRATCH page: idle and still-prefilling
     slots keep an all-zeros block-table row, routing their garbage
     decode writes there — in a paged layout a stray write into a
@@ -72,6 +73,7 @@ from tritonclient_tpu.models.gpt import (
     sampling_inputs,
     sampling_key,
 )
+from tritonclient_tpu.ops.paged_attention import paged_attention, plan_pages
 from tritonclient_tpu.protocol._literals import (
     PREFIX_EVENT_HIT,
     PREFIX_EVENT_MISS,
@@ -92,8 +94,8 @@ def _block_pool_arrays(cfg: GptConfig, n_blocks: int, block_size: int):
 
 
 def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
-                           rows_per_table: int, mask, cfg: GptConfig,
-                           proj_fn):
+                           rows_per_table: int, lengths, cfg: GptConfig,
+                           proj_fn, mesh=None):
     """The layer scan of every paged step: ``(h, k_pool, v_pool)`` is the
     CARRY and the scanned inputs are the layer's parameters and its index.
 
@@ -102,15 +104,22 @@ def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
     pool and that stack copied onto the donated buffer: about four passes
     over the pool a dispatch, for a write of one position a slot. Carried,
     what a step does to the pool compiles to one in-place scatter at
-    ``(layer, page, offset)`` and one gather of the tables' pages.
+    ``(layer, page, offset)``, and the attention kernel
+    (``ops.paged_attention``) takes the whole pools with the layer's
+    index and reads the pages the tables hold, where they lie.
 
     ``dest``/``off`` [N] are the page and offset of each of the N rows'
-    new K/V; ``btabs`` [T, n_ctx] are the tables to gather, each attended
-    by ``rows_per_table`` consecutive rows (N = T * rows_per_table: 1 for
-    decode, the chunk length for prefill).
+    new K/V; ``btabs`` [T, n_ctx] are the block tables, each attended by
+    ``rows_per_table`` consecutive rows (N = T * rows_per_table: 1 for
+    decode, the chunk length for prefill); row n attends positions
+    ``[0, lengths[n])`` of its table, and no page past a table's longest
+    row is read. Under a ``mesh`` the pools' flat axis is on ``tp`` and
+    each shard attends its own heads.
     """
-    n_tables, n_ctx = btabs.shape
-    heads = (n_ctx * k_pool.shape[2], cfg.n_heads, cfg.head_dim)
+    # What the tables and lengths say of the kernel's grid is the same in
+    # every layer: made here once, not in the scan's body.
+    plan = plan_pages(btabs, lengths, rows_per_table=rows_per_table,
+                      block_size=k_pool.shape[2])
 
     def layer(carry, xs):
         h, k_pool, v_pool = carry
@@ -121,18 +130,13 @@ def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
             return pool.at[li, dest, off].set(
                 rows.reshape(rows.shape[0], -1).astype(pool.dtype))
 
-        def read(pool):
-            # Only the tables' pages are read: [T, n_ctx, bs, H * Dh] ->
-            # [T, l_eff, H, Dh], each table's view for all of its rows.
-            table = pool[li, btabs].reshape((n_tables, 1) + heads)
-            return jnp.broadcast_to(
-                table, (n_tables, rows_per_table) + heads
-            ).reshape((n_tables * rows_per_table,) + heads)
-
         h, (k_pool, v_pool) = _decode_layer(
             h, lp, k_pool, v_pool, cfg,
-            lambda kc, vc, k, v: (write(kc, k), write(vc, v)), mask,
-            read_kv=lambda kc, vc: (read(kc), read(vc)), proj_fn=proj_fn)
+            lambda kc, vc, k, v: (write(kc, k), write(vc, v)),
+            lambda q, kc, vc: paged_attention(
+                q, kc, vc, li, btabs, plan,
+                rows_per_table=rows_per_table, mesh=mesh),
+            proj_fn=proj_fn)
         return (h, k_pool, v_pool), None
 
     (x, k_pool, v_pool), _ = lax.scan(
@@ -177,7 +181,7 @@ def _sample_slots(logits, seeds, steps, temps, topks):
 
 def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
                        seeds, steps, temps, topks, cfg: GptConfig,
-                       block_size: int, proj_fn=None):
+                       block_size: int, proj_fn=None, mesh=None):
     """One step for the whole slot bank against the paged pool.
 
     ``btabs`` [S, max_blocks] int32 maps each slot's logical block index
@@ -188,14 +192,14 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
     K/V lands on the scratch page instead of a page some OTHER request
     now owns. The pools are ``[n_layers, n_blocks, bs, H * Dh]`` and ride
     the layer scan as its carry (``_scan_layers_over_pool``): a layer
-    scatters its S new rows at ``(layer, page, offset)`` and gathers
-    ``pool[layer, btabs]``, which reshapes to the dense
-    [S, max_len, H, Dh] view, so the attention math is bit-identical to
-    the old contiguous bank.
+    scatters its S new rows at ``(layer, page, offset)`` and the
+    paged-attention kernel reads, for each slot, the pages under its
+    position and no more of its table: float32 scores, softmax and
+    accumulation over the K/V as stored, the mathematics of the contiguous
+    bank's masked einsum (token for token against it, tested).
     """
     s_count = tokens.shape[0]
     max_blocks = btabs.shape[1]
-    l_eff = max_blocks * block_size
     x = params["embed"]["tok"][tokens] + params["embed"]["pos"][pos]  # [S, d]
     slot_ids = jnp.arange(s_count)
     # Surplus pipeline steps can push pos past the reserved region; the
@@ -203,9 +207,9 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
     blk = jnp.minimum(pos // block_size, max_blocks - 1)
     off = pos % block_size
     dest = btabs[slot_ids, blk]                              # [S] page ids
-    mask = (jnp.arange(l_eff)[None, :] <= pos[:, None])[:, None, :]
     x, k_pool, v_pool = _scan_layers_over_pool(
-        params, x, k_pool, v_pool, btabs, dest, off, 1, mask, cfg, proj_fn)
+        params, x, k_pool, v_pool, btabs, dest, off, 1, pos + 1, cfg,
+        proj_fn, mesh)
     logits = _head(params, x, cfg)
     # Greedy-only banks (the default) skip the sampler's full-vocab sort.
     nxt = lax.cond(
@@ -219,7 +223,7 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
 def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
                              pos, seeds, steps, temps, topks,
                              cfg: GptConfig, block_size: int, n_steps: int,
-                             proj_fn=None):
+                             proj_fn=None, mesh=None):
     """``n_steps`` decode micro-steps in ONE dispatch: a ``lax.scan`` over
     the exact single-step body, returning the ``[n_steps, S]`` token
     block plus the advanced carry.
@@ -242,7 +246,7 @@ def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
         tokens, pos, steps, k_pool, v_pool = carry
         nxt, k_pool, v_pool = _decode_step_paged(
             params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
-            temps, topks, cfg, block_size, proj_fn=proj_fn,
+            temps, topks, cfg, block_size, proj_fn=proj_fn, mesh=mesh,
         )
         return (nxt, pos + 1, steps + 1, k_pool, v_pool), nxt
 
@@ -254,7 +258,8 @@ def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
 
 def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
                          starts, n_valids, seeds, temps, topks,
-                         cfg: GptConfig, block_size: int, proj_fn=None):
+                         cfg: GptConfig, block_size: int, proj_fn=None,
+                         mesh=None):
     """One fixed-size prompt chunk for K prefilling slots in a SINGLE
     dispatch, K/V written into the pages of ``btabs`` [K, n_ctx] int32.
 
@@ -265,25 +270,26 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     batchmates together, their clients resubmit together, and K serial
     chunk dispatches at one loop top would put k×chunk-time in front of
     every admission in the burst. Rows attend the pages' already-written
-    positions AND each other causally via the position mask — all rows
-    are written first, then the gather reads them back, so intra-chunk
-    causality falls out of ``position <= my position``. Pad rows (and
-    pad lanes) route their writes to the scratch page; lanes gather only
-    their own table, so cross-lane isolation is structural, not masked.
-    ``n_ctx`` (the traced table width) is the caller-bucketed context
-    extent — the mask admits no key past a lane's last valid position,
-    so truncating the table to the prompt seen so far is lossless. The
-    pools are ``[n_layers, n_blocks, bs, H * Dh]`` and carried through
-    the layer scan like decode's (``_scan_layers_over_pool``): a layer
-    scatters its K * C rows and gathers its K tables, each table's view
-    broadcast over the lane's C rows.
+    positions AND each other causally by their lengths — all rows are
+    written first, then the kernel reads them back, so intra-chunk
+    causality falls out of ``position < my position + 1``. Pad rows (and
+    pad lanes) route their writes to the scratch page and attend position
+    0 alone, so no page a lane has not written yet is read; lanes read
+    only their own table, so cross-lane isolation is structural, not
+    masked. ``n_ctx`` (the traced table width) is the caller-bucketed
+    context extent — no row attends a key past its lane's last valid
+    position, so truncating the table to the prompt seen so far is
+    lossless. The pools are ``[n_layers, n_blocks, bs, H * Dh]`` and
+    carried through the layer scan like decode's
+    (``_scan_layers_over_pool``): a layer scatters its K * C rows and the
+    paged-attention kernel attends each lane's C rows to the pages under
+    the lane's last valid position.
     Returns (first tokens [K] int32 — sampled with each request's
     settings at step 0, meaningful only on a lane's FINAL chunk — and
     the pools).
     """
     kk, c = chunks.shape
     n_ctx = btabs.shape[1]
-    l_eff = n_ctx * block_size
     rows = jnp.arange(c, dtype=jnp.int32)
     positions = starts[:, None] + rows[None, :]                # [K, C]
     safe_pos = jnp.minimum(positions, cfg.max_len - 1)
@@ -294,11 +300,11 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     dest = jnp.where(valid, jnp.take_along_axis(btabs, blk, axis=1),
                      0).reshape(kk * c)           # pad rows -> scratch
     off = (safe_pos % block_size).reshape(kk * c)
-    mask = (jnp.arange(l_eff)[None, None, :]
-            <= positions[:, :, None]).reshape(kk * c, 1, l_eff)
+    lengths = jnp.where(valid, positions + 1, 1).reshape(kk * c)
 
     x, k_pool, v_pool = _scan_layers_over_pool(
-        params, x, k_pool, v_pool, btabs, dest, off, c, mask, cfg, proj_fn)
+        params, x, k_pool, v_pool, btabs, dest, off, c, lengths, cfg,
+        proj_fn, mesh)
     last = jnp.take_along_axis(
         x.reshape(kk, c, cfg.d_model),
         (n_valids - 1).astype(jnp.int32)[:, None, None], axis=1,
@@ -327,6 +333,10 @@ class PagedModel:
     """
 
     cfg = None      # has ``max_len``: the positions served (the table's width)
+    # Whether the family's attention reads the pages under a lane's length
+    # (a paged kernel) or gathers its table's whole width: what a
+    # dispatch's ``kv_bytes`` are reckoned from.
+    reads_pages_held = False
 
     def pool_arrays(self, n_blocks: int, block_size: int) -> tuple:
         """The page pools, each ``[n_layers, n_blocks, block_size, ...]``."""
@@ -364,8 +374,11 @@ class GptPaged(PagedModel):
     Modules` line read jit_decode_step / jit_decode_fused_<n> /
     jit_prefill_chunk."""
 
+    reads_pages_held = True     # ops/paged_attention.py
+
     def __init__(self, cfg: GptConfig):
         self.cfg = cfg
+        self._mesh = None     # set by ``shard``: the steps' kernel shards on it
 
     def pool_arrays(self, n_blocks: int, block_size: int):
         return _block_pool_arrays(self.cfg, n_blocks, block_size)
@@ -390,30 +403,31 @@ class GptPaged(PagedModel):
         # tp, which keeps heads whole per shard (n_heads % tp == 0).
         # named_sharding drops absent/size-1 axes, so a tp-less mesh
         # degrades to replication like shard_tree does for params.
+        self._mesh = mesh
         return (shard_tree(mesh, params, PARTITION_RULES),
                 named_sharding(mesh, None, None, None, "tp"))
 
     def decode_step(self, block_size: int, proj_fn=None):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self._mesh
 
         def decode_step(params, k_pool, v_pool, btabs, tokens, pos, seeds,
                         steps, temps, topks):
             return _decode_step_paged(
                 params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
                 temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn)
+                proj_fn=proj_fn, mesh=mesh)
 
         return decode_step
 
     def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self._mesh
 
         def decode_fused(params, k_pool, v_pool, btabs, tokens, pos,
                          seeds, steps, temps, topks):
             return _decode_multi_step_paged(
                 params, k_pool, v_pool, btabs, tokens, pos, seeds,
                 steps, temps, topks, cfg=cfg, block_size=block_size,
-                n_steps=n_steps, proj_fn=proj_fn)
+                n_steps=n_steps, proj_fn=proj_fn, mesh=mesh)
 
         # One name per width: jit_decode_fused_<n> on the device trace.
         decode_fused.__name__ = f"decode_fused_{n_steps}"
@@ -421,14 +435,14 @@ class GptPaged(PagedModel):
         return decode_fused
 
     def prefill_chunk(self, block_size: int, proj_fn=None):
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self._mesh
 
         def prefill_chunk(params, k_pool, v_pool, chunks, btabs, starts,
                           n_valids, seeds, temps, topks):
             return _prefill_chunk_paged(
                 params, k_pool, v_pool, chunks, btabs, starts, n_valids,
                 seeds, temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn)
+                proj_fn=proj_fn, mesh=mesh)
 
         return prefill_chunk
 
@@ -943,6 +957,14 @@ class GenerationEngine:
 
         atexit.register(lambda: (lambda e: e and e.shutdown())(ref()))
 
+    def _attention_bytes(self, ctx_pages: int, table_pages: int) -> int:
+        """KV bytes a dispatch's attention reads: the pages under its
+        lanes' lengths where the family's kernel reads the pages held (hit
+        pages too), every lane's and micro-step's whole table extent where
+        it gathers the table."""
+        return self._block_kv_bytes * (
+            ctx_pages if self._model.reads_pages_held else table_pages)
+
     def _keep_pools(self, result, lead: int):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
         """Take the pools back from a step's result ``(lead arrays, *pools,
         *extras)``; returns ``(the lead arrays, the extras)``."""
@@ -1441,13 +1463,15 @@ class GenerationEngine:
             lanes=kk, ctx_blocks=n_ctx,
         )
         if scope is not None:
-            # The gathered view reads the bucketed block-table extent
-            # for every lane, hit pages or not (shape-bucketed gather).
-            scope.kv_bytes = kk * n_ctx * self._block_kv_bytes
             # Positions computed, and the context the real lanes hold
-            # once this chunk is in (what the chunk's rows attend).
+            # once this chunk is in (what the chunk's rows attend), in
+            # tokens and in table entries.
             scope.tokens = sum(n for _, _, _, n in lanes)
             scope.ctx_tokens = sum(s + n for _, _, s, n in lanes)
+            scope.ctx_pages = sum(
+                -(-(s + n) // self.block_size) for _, _, s, n in lanes)
+            scope.kv_bytes = self._attention_bytes(scope.ctx_pages,
+                                                   kk * n_ctx)
         self._prefill_seq += 1
         # One compile-cache entry per (lane, context) bucket: the key is
         # the traced-shape identity XLA uses, so the retrace counter and
@@ -1790,15 +1814,17 @@ class GenerationEngine:
                 scope.tokens = len(active) * fuse
                 # Context held by the active slots as the dispatch's first
                 # micro-step sees it: prompt + tokens dispatched so far.
-                scope.ctx_tokens = sum(
-                    self._slot_req[s].prompt.shape[1] + self._dispatched[s]
-                    for s in active)
-                # Whole-bank decode: every micro-step gathers the full
-                # [max_slots, max_blocks] table extent.
-                scope.kv_bytes = (
-                    fuse * self.max_slots * self._max_blocks
-                    * self._block_kv_bytes
-                )
+                held = [self._slot_req[s].prompt.shape[1]
+                        + self._dispatched[s] for s in active]
+                scope.ctx_tokens = sum(held)
+                # Table entries under the active slots' lengths, every
+                # micro-step's: what a paged kernel visits.
+                scope.ctx_pages = sum(
+                    min(-(-(n + i) // self.block_size), self._max_blocks)
+                    for n in held for i in range(fuse))
+                scope.kv_bytes = self._attention_bytes(
+                    scope.ctx_pages,
+                    fuse * self.max_slots * self._max_blocks)
             step_seq += fuse
             # Whole-bank decode traces one shape per fuse width: the
             # unfused branch is a single cache entry, the fused branch

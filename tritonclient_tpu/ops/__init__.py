@@ -2,8 +2,10 @@
 
 `dot_product_attention` is the plain jnp implementation;
 `flash_attention` is the Pallas-fused TPU kernel (tile-streamed online
-softmax, interpreter-backed off-TPU). The sequence-parallel variants live
-in tritonclient_tpu.parallel (ring_attention, ulysses_attention).
+softmax, interpreter-backed off-TPU); `paged_attention` is the Pallas
+kernel the paged engine's layers read their KV pages through (the pages a
+request holds, where they lie in the pool). The sequence-parallel variants
+live in tritonclient_tpu.parallel (ring_attention, ulysses_attention).
 """
 
 from tritonclient_tpu.ops.attention import dot_product_attention
@@ -11,5 +13,7 @@ from tritonclient_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_path,
 )
+from tritonclient_tpu.ops.paged_attention import paged_attention, plan_pages
 
-__all__ = ["dot_product_attention", "flash_attention", "flash_attention_path"]
+__all__ = ["dot_product_attention", "flash_attention", "flash_attention_path",
+           "paged_attention", "plan_pages"]
