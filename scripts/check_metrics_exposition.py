@@ -44,11 +44,8 @@ Checks (exit 1 with one line per violation):
     no stage row is required: a counters-mode server emits ``dispatch``
     alone (it has no device clock), a ``sync`` one all three;
     ``nv_engine_collectives_total`` carries exactly {model, op}
-  * the overlap families: ``nv_engine_collective_overlap_us_total``
-    carries exactly {model, kind} with ``kind`` drawn from the canonical
-    overlap vocabulary and both kind rows present per model (so the
-    overlap ratio is computable from one scrape);
-    ``nv_engine_inflight_steps`` carries exactly {model}, non-negative
+  * the pipelined-dispatch depth gauge ``nv_engine_inflight_steps``
+    carries exactly {model}, non-negative
   * the paged-KV families: ``nv_engine_kv_blocks_used`` /
     ``nv_engine_kv_blocks_total`` carry exactly {model}, are
     non-negative, and used <= total per model;
@@ -122,11 +119,6 @@ except ImportError:  # standalone copy of the script: keep it usable
     PREFIX_EVENTS = ("hit", "miss", "evict")
 
 try:
-    from tritonclient_tpu.protocol._literals import OVERLAP_KINDS
-except ImportError:  # standalone copy of the script: keep it usable
-    OVERLAP_KINDS = ("exposed", "hidden")
-
-try:
     from tritonclient_tpu.protocol._literals import (
         COHORT_LABEL_RE,
         SLO_WINDOWS,
@@ -173,9 +165,7 @@ _COLLECTIVES_FAMILY = "nv_engine_collectives_total"
 _KV_USED_FAMILY = "nv_engine_kv_blocks_used"
 _KV_TOTAL_FAMILY = "nv_engine_kv_blocks_total"
 _PREFIX_FAMILY = "nv_engine_prefix_cache_events_total"
-# Overlap plane (PR 13): exposed-vs-hidden collective time counter with
-# the canonical kind vocabulary, plus the pipelined-dispatch depth gauge.
-_OVERLAP_FAMILY = "nv_engine_collective_overlap_us_total"
+# The pipelined-dispatch depth gauge.
 _INFLIGHT_FAMILY = "nv_engine_inflight_steps"
 # Fleetscope families (PR 16): scrape-health gauges/counters on the
 # router plus the SLO plane (burn rates, budget, cohort attribution)
@@ -466,37 +456,6 @@ def check_exposition(text: str) -> List[str]:
                     if missing:
                         errors.append(
                             f'{family}{{model="{model}"}}: missing event '
-                            f"rows {missing}"
-                        )
-            if family == _OVERLAP_FAMILY:
-                # Overlap contract: fixed {model, kind} label set,
-                # canonical kinds only, and BOTH kinds present per model
-                # (the overlap ratio hidden / (hidden + exposed) must be
-                # computable from one scrape without absent-as-zero
-                # guessing).
-                model_kinds: Dict[str, set] = {}
-                for labels, value, name, lineno in samples.get(family, []):
-                    if set(labels) != {"model", "kind"}:
-                        errors.append(
-                            f"line {lineno}: {family} label set "
-                            f"{sorted(labels)} != ['kind', 'model']"
-                        )
-                        continue
-                    if labels["kind"] not in OVERLAP_KINDS:
-                        errors.append(
-                            f"line {lineno}: {family} kind "
-                            f"{labels['kind']!r} not in "
-                            f"{list(OVERLAP_KINDS)}"
-                        )
-                        continue
-                    model_kinds.setdefault(
-                        labels["model"], set()
-                    ).add(labels["kind"])
-                for model, kinds in model_kinds.items():
-                    missing = [k for k in OVERLAP_KINDS if k not in kinds]
-                    if missing:
-                        errors.append(
-                            f'{family}{{model="{model}"}}: missing kind '
                             f"rows {missing}"
                         )
             if family == _SCRAPE_FAILURES_FAMILY:

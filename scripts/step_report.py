@@ -42,8 +42,8 @@ of the recorded span, the per-request table, and (``sync`` dumps) the verdict â€
 * **device-bound** â€” device time dominates and steps issue no
   collectives: compute is the wall; scale or shrink the model;
 * **collective-bound** â€” device time dominates and steps carry
-  collectives: the tp all-reduces are inside that device time, so
-  overlap (Triton-distributed-style) is the lever.
+  collectives: the tp all-reduces are inside that device time; how much
+  of it no compute hides is read from a device trace, not from here.
 
 Usage::
 
@@ -403,12 +403,6 @@ def analyze(records: List[dict],
                 "collectives_per_step": round(
                     sum(_coll_count(r.get("collectives")) for r in ph) / n, 2
                 ),
-                "coll_exposed_us": round(
-                    sum(int(r.get("coll_exposed_us", 0)) for r in ph) / n, 1
-                ),
-                "coll_hidden_us": round(
-                    sum(int(r.get("coll_hidden_us", 0)) for r in ph) / n, 1
-                ),
                 "mean_batch": round(
                     sum(int(r.get("batch_size", 0)) for r in ph) / n, 2
                 ),
@@ -437,21 +431,11 @@ def analyze(records: List[dict],
         n = len(recs)
         means = _stage_means(recs)
         coll = sum(_coll_count(r.get("collectives")) for r in recs) / n
-        # Overlap plane (PR 13): exposed vs hidden collective time the
-        # engine charged per record; absent on pre-overlap dumps.
-        exposed = sum(int(r.get("coll_exposed_us", 0)) for r in recs) / n
-        hidden = sum(int(r.get("coll_hidden_us", 0)) for r in recs) / n
         micro = sum(int(r.get("micro_steps", 1) or 1) for r in recs) / n
         models[model] = {
             "n": n,
             "mean_us": {k: round(v, 1) for k, v in means.items()},
             "collectives_per_step": round(coll, 2),
-            "overlap": {
-                "exposed_us": round(exposed, 1),
-                "hidden_us": round(hidden, 1),
-                "hidden_frac": round(hidden / (exposed + hidden), 3)
-                if exposed + hidden else 0.0,
-            },
             "micro_steps": round(micro, 2),
             "verdict": _verdict(means.get("dispatch", 0.0),
                                 means.get("device"),
@@ -485,15 +469,6 @@ def render(analysis: dict) -> str:
             f"coll/step={m['collectives_per_step']} -> "
             f"verdict: {verdict}"
         )
-        ov = m.get("overlap") or {}
-        if ov.get("exposed_us") or ov.get("hidden_us"):
-            lines.append(
-                f"  overlap: exposed={ov['exposed_us']}us "
-                f"hidden={ov['hidden_us']}us "
-                f"({100 * ov['hidden_frac']:.0f}% of collective time "
-                f"hidden under compute), "
-                f"micro-steps/dispatch={m.get('micro_steps', 1)}"
-            )
         # Compile plane: distinct cache entries and retraces per jitted
         # callable. Retraces growing with step count (rather than
         # plateauing at the bucket-family size) is the TPU017 signal.
@@ -629,12 +604,6 @@ def compare(a: dict, b: dict, label_a: str = "A",
                 f"{pha['collectives_per_step']} -> "
                 f"{phb['collectives_per_step']}"
             )
-            # Overlap column: exposed collective us per step before/after
-            # (what remains on the critical path once hiding is applied).
-            ea = pha.get("coll_exposed_us", 0)
-            eb = phb.get("coll_exposed_us", 0)
-            if ea or eb:
-                line += f", exposed {ea} -> {eb} us"
             lines.append(line)
     return "\n".join(lines)
 
@@ -665,12 +634,6 @@ def render_bench(summary: dict) -> str:
         row = summary.get(f"{key}_decode") or {}
         verdict = summary.get(f"{key}_verdict", "?")
         if row:
-            overlap = ""
-            exposed = row.get("coll_exposed_us") or 0
-            hidden = row.get("coll_hidden_us") or 0
-            if exposed or hidden:
-                overlap = (f" exposed={exposed}us hidden={hidden}us"
-                           f" micro-steps={row.get('micro_steps', 1)}")
             lines.append(
                 f"  {label}: decode p50={row.get('p50_us')}us "
                 f"p99={row.get('p99_us')}us "
@@ -678,8 +641,7 @@ def render_bench(summary: dict) -> str:
                 + "".join(f"{stage}={row[f'{stage}_us']}us "
                           for stage in ("device", "other")
                           if row.get(f"{stage}_us") is not None)
-                + f"coll/step={row.get('collectives_per_step')}"
-                f"{overlap} -> "
+                + f"coll/step={row.get('collectives_per_step')} -> "
                 f"verdict: {verdict}"
             )
         else:
@@ -692,8 +654,7 @@ def render_bench(summary: dict) -> str:
 
 def _synthetic_dump(dispatch_us: int, device_us: int, other_us: int,
                     coll_per_step: int, model: str = "gpt_engine",
-                    n: int = 24, exposed_us: int = 0, hidden_us: int = 0,
-                    micro_steps: int = 1) -> dict:
+                    n: int = 24, micro_steps: int = 1) -> dict:
     """Deterministic stepscope-kind dump (no RNG: a fixed per-step jitter
     pattern keeps quantiles meaningful and reproducible)."""
     records = []
@@ -720,11 +681,8 @@ def _synthetic_dump(dispatch_us: int, device_us: int, other_us: int,
                 {"psum": {"count": coll_per_step, "bytes": 0}}
                 if coll_per_step else {}
             ),
-            # Overlap/pipelining fields ride decode records only, the way
-            # the engine charges them (prefills are never fused).
+            # Prefills are never fused.
             "micro_steps": micro_steps if phase == "decode" else 1,
-            "coll_exposed_us": exposed_us if phase == "decode" else 0,
-            "coll_hidden_us": hidden_us if phase == "decode" else 0,
             "thread_ident": 42,
             "thread_name": "gpt-engine",
             # KV traffic scales with fused depth on decode, is a single
@@ -826,22 +784,10 @@ def self_check() -> int:
         failures += 1
     else:
         print("self-check [flight]: ok")
-    # Overlap fields: exposed/hidden charges and fused micro-steps must
-    # survive the loader and surface in analysis + render.
-    dump = _synthetic_dump(60, 700, 20, 16, exposed_us=120, hidden_us=240,
-                           micro_steps=4)
+    dump = _synthetic_dump(60, 700, 20, 16, micro_steps=4)
     analysis = analyze(load_records(dump))
     m = analysis["models"]["gpt_engine"]
     decode = m["phases"]["decode"]
-    if (decode["coll_exposed_us"] != 120
-            or decode["coll_hidden_us"] != 240
-            or not 0.6 < m["overlap"]["hidden_frac"] < 0.7
-            or "hidden under compute" not in render(analysis)):
-        print("self-check [overlap]: exposed/hidden fields lost",
-              file=sys.stderr)
-        failures += 1
-    else:
-        print("self-check [overlap]: ok")
     # KV traffic column: per-phase bytes-touched means must survive the
     # loader and surface in the rendered table (decode fused 4x deep
     # charges 16 MB/step vs 1 MB/step on prefill chunks).
@@ -944,16 +890,13 @@ def self_check() -> int:
         failures += 1
     else:
         print("self-check [counters]: ok")
-    # Compare mode renders ratios for shared phases, with the overlap
-    # column when either side charged exposed time.
+    # Compare mode renders ratios for shared phases.
     a = analyze(load_records(_synthetic_dump(60, 200, 20, 0)))
     b = analyze(load_records(_synthetic_dump(60, 700, 20, 16,
-                                             exposed_us=90,
-                                             hidden_us=180,
                                              micro_steps=4)))
     text = compare(a, b, "tp=1", "tp=2")
     if ("decode: p50" not in text or VERDICT_COLLECTIVE not in text
-            or "exposed 0.0 -> 90.0 us" not in text):
+            or "coll/step 0.0 -> 16.0" not in text):
         print("self-check [compare]: comparison incomplete",
               file=sys.stderr)
         failures += 1
@@ -966,14 +909,13 @@ def self_check() -> int:
         '"tp1_verdict": "dispatch-bound", "tp_decode": {"p50_us": 90, '
         '"p99_us": 120, "dispatch_us": 20, "device_us": 60, '
         '"other_us": 10, "collectives_per_step": 4.0, '
-        '"coll_exposed_us": 30.0, "coll_hidden_us": 60.0, '
         '"micro_steps": 4}, "tp1_decode": '
         '{"p50_us": 30, "p99_us": 40, "dispatch_us": 20, '
         '"device_us": 8, "other_us": 2, "collectives_per_step": 0.0}}\n'
     )}
     summary = bench_tail_summary(tail_doc)
     if (not summary or "collective-bound" not in render_bench(summary)
-            or "exposed=30.0us hidden=60.0us" not in render_bench(summary)):
+            or "coll/step=4.0" not in render_bench(summary)):
         print("self-check [bench-tail]: extraction failed",
               file=sys.stderr)
         failures += 1

@@ -3,6 +3,7 @@ reference, prefix caching, block accounting under churn, and admission
 gating on pool pages (plus the /metrics families the pool exposes)."""
 
 import queue
+import re
 import threading
 import time
 
@@ -423,50 +424,23 @@ class TestKvExpositionViolations:
         assert any("< 0" in e for e in errors)
 
 
-class TestOverlapExpositionViolations:
-    """The overlap/in-flight exposition contract (PR 13), checked the same
+class TestInflightExpositionViolations:
+    """The in-flight gauge's exposition contract (PR 13), checked the same
     way as the paged-KV families: synthetic documents through the real
     checker, one mutation per violation class."""
 
     HEAD = (
-        "# HELP nv_engine_collective_overlap_us_total x\n"
-        "# TYPE nv_engine_collective_overlap_us_total counter\n"
         "# HELP nv_engine_inflight_steps x\n"
         "# TYPE nv_engine_inflight_steps gauge\n"
     )
 
     def _good_rows(self):
-        rows = [
-            f'nv_engine_collective_overlap_us_total{{model="gpt_engine"'
-            f',kind="{k}"}} 0'
-            for k in ("exposed", "hidden")
-        ]
-        rows.append('nv_engine_inflight_steps{model="gpt_engine"} 2')
-        return rows
+        return ['nv_engine_inflight_steps{model="gpt_engine"} 2']
 
     def test_good_document_passes(self):
         assert check_exposition(
             self.HEAD + "\n".join(self._good_rows()) + "\n"
         ) == []
-
-    def test_noncanonical_kind(self):
-        rows = self._good_rows()
-        rows[0] = ('nv_engine_collective_overlap_us_total'
-                   '{model="gpt_engine",kind="mystery"} 0')
-        errors = check_exposition(self.HEAD + "\n".join(rows) + "\n")
-        assert any("mystery" in e for e in errors)
-
-    def test_missing_kind_row(self):
-        rows = [r for r in self._good_rows() if 'kind="hidden"' not in r]
-        errors = check_exposition(self.HEAD + "\n".join(rows) + "\n")
-        assert any("missing kind rows" in e for e in errors)
-
-    def test_overlap_label_set(self):
-        rows = self._good_rows()
-        rows.append('nv_engine_collective_overlap_us_total'
-                    '{model="m",kind="exposed",op="psum"} 0')
-        errors = check_exposition(self.HEAD + "\n".join(rows) + "\n")
-        assert any("label set" in e for e in errors)
 
     def test_inflight_label_set(self):
         rows = self._good_rows()
@@ -480,21 +454,22 @@ class TestOverlapExpositionViolations:
         errors = check_exposition(self.HEAD + "\n".join(rows) + "\n")
         assert any("in-flight depth" in e for e in errors)
 
-    def test_live_snapshot_renders_both_kinds(self):
-        """overlap_snapshot() feeds /metrics: once a model has overlap
-        charges, both kinds and the in-flight gauge must come back."""
+    def test_live_snapshot_counts_dispatches_in_flight(self):
+        """inflight_snapshot() feeds /metrics: a dispatch submitted and
+        not yet delivered is one step in flight, and the depth never goes
+        under zero."""
         from tritonclient_tpu import _stepscope
 
         prev = _stepscope._mode
         _stepscope.configure("counters")
         _stepscope._aggregator.reset()
         try:
-            _stepscope._aggregator.overlap[("m", "exposed")] = 5
             _stepscope.inflight_update("m", 1)
-            overlap_rows, inflight_rows = _stepscope.overlap_snapshot()
-            assert (("m", "exposed", 5) in overlap_rows
-                    and ("m", "hidden", 0) in overlap_rows)
-            assert ("m", 1) in inflight_rows
+            _stepscope.inflight_update("m", 1)
+            _stepscope.inflight_update("m", -1)
+            assert _stepscope.inflight_snapshot() == [("m", 1)]
+            _stepscope.inflight_update("m", -2)
+            assert _stepscope.inflight_snapshot() == [("m", 0)]
         finally:
             _stepscope._aggregator.reset()
             _stepscope.configure(prev)
@@ -779,18 +754,25 @@ def test_layer_scan_carries_the_pools_and_scans_neither(tiny, program):
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described (not attached) v5e chip. Described inside the fixture,
-    never at import: only one process may load the TPU's library."""
+def v5e_devices():
+    """The four described (not attached) chips of a v5e host. Described
+    inside the fixture, never at import: only one process may load the
+    TPU's library."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_devices):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_devices[0])
 
 
 @pytest.fixture()
@@ -806,6 +788,18 @@ def compile_cache_off():
 
 
 _COMPILE_SECONDS = 120.0   # a two-layer program compiles in a few seconds
+
+_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _moves_of(hlo_text, dims, ops=_MOVES):
+    """The instructions of a compiled module's text whose opcode is one of
+    ``ops`` and whose result (a tuple's first part) has one of ``dims``
+    (``"3,48,16,256"``)."""
+    pattern = re.compile(r"\s*(?:ROOT )?\S+ = \(?\w+\[([\d,]*)\]\S* ("
+                         + "|".join(ops) + r")\(")
+    return [line.strip()[:160] for line in hlo_text.splitlines()
+            if (m := pattern.match(line)) and m.group(1) in dims]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
@@ -914,8 +908,6 @@ def test_v5e_compiler_moves_no_latent_pool_and_slices_no_expert_bank(
     (the grouped product takes the stacked banks and the layer's index; a
     scanned bank is sliced out, 2.4 GB a layer at the published sizes).
     Kept in this file for its fixture: one process describes the chip."""
-    import re
-
     import jax.numpy as jnp
 
     from tritonclient_tpu.models import mla_moe
@@ -960,15 +952,146 @@ def test_v5e_compiler_moves_no_latent_pool_and_slices_no_expert_bank(
     watched = {",".join(map(str, d)) for d in (
         shape, shape[1:], (1,) + shape[1:], bank, (bank[0], bank[2], bank[1]),
         (1,) + bank)}
-    moved = []
-    for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?\S+ = \(?\w+\[([\d,]*)\]\S* "
-                     r"(copy|copy-start|dynamic-slice|dynamic-update-slice)"
-                     r"\(", line)
-        if m and m.group(1) in watched:
-            moved.append(line.strip()[:160])
+    moved = _moves_of(compiled.as_text(), watched)
     assert not moved, moved
     # ... nor under another name: the step's temporaries stay under one
     # expert layer's bank (0.8 GB here).
     bank_bytes = 2 * cfg.n_experts * cfg.d_model * cfg.d_expert
     assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes
+
+
+# --------------------------------------------------------------------------- #
+# tensor parallelism: what the partitioned program holds                      #
+# --------------------------------------------------------------------------- #
+
+_COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
+                "collective-permute", "collective-broadcast")
+
+
+def _collectives_by_computation(hlo_text):
+    """``{computation: [collective opcode, ...]}`` of a compiled module's
+    text (an asynchronous pair counts once, at its ``-start``)."""
+    found, name = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            name = line.split("(")[0].replace("ENTRY", "").strip(" %")
+            continue
+        m = re.search(r"[\])}] ([a-z][\w-]*)\(", line.partition(" = ")[2])
+        if m and m.group(1).replace("-start", "") in _COLLECTIVES:
+            found.setdefault(name, []).append(m.group(1).replace("-start", ""))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_tp2_program_holds_the_two_all_reduces_a_layer_the_record_counts(
+        v5e_devices, compile_cache_off, monkeypatch, program):
+    """The collective count on a tp dispatch record comes from a formula
+    (``expected_tp_collectives``: two a layer, GSPMD's, no python call site
+    to count at). Here it is read off the program. Twice, because each
+    compiler shows a part:
+
+    On the tp=2 VIRTUAL mesh, the engine's own jitted step: the lowered
+    module has one manual region (the paged-attention kernel's shard_map)
+    and the partitioned one holds two all-reduces, both in the body of one
+    loop of ``n_layers`` trips; times the trips that is the ``psum`` count
+    the engine charges. The pool cannot be judged there: the kernel runs
+    interpreted and its interpreter copies what it is given.
+
+    Compiled for two DESCRIBED v5e chips, where the kernel is the Mosaic
+    call: the same collectives in one loop body, and no all-gather, copy
+    or slice with the dimensions of the pool, of a shard of it or of one
+    layer's: a shard attends its own heads and the pages stay where they
+    lie.
+
+    What the formula never counted, and both compilers hold: two
+    collective-permutes a layer. ``wqkv`` is column-sharded as one
+    ``[d, 3d]`` matrix, so a shard's columns are not its heads' q, k and
+    v, and ``jnp.split`` re-lays them across the shards (op_name
+    ``.../split``). The record's ``psum`` is the all-reduces' count, not
+    every collective's (ROADMAP A6).
+    """
+    import jax.numpy as jnp
+
+    from tritonclient_tpu.models import gpt_engine
+    from tritonclient_tpu.parallel import (build_mesh, named_sharding,
+                                           tree_shardings)
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 virtual devices")
+    n_layers, block_size = 3, 16      # three trips: an unrolled scan shows
+    cfg = gpt.GptConfig(vocab_size=256, d_model=512, n_layers=n_layers,
+                        n_heads=4, d_ff=2048, max_len=64,
+                        dtype=jnp.bfloat16)
+
+    def arguments(vec):
+        i32, rest = jnp.int32, (vec(jnp.float32, 2), vec(jnp.int32, 2))
+        if program == "prefill_chunk":      # two lanes of 8 rows, 2 pages
+            return (vec(i32, 2, 8), vec(i32, 2, 2)) + (vec(i32, 2),) * 3 + rest
+        return ((vec(i32, 2, cfg.max_len // block_size),)
+                + (vec(i32, 2),) * 4 + rest)
+
+    def all_reduces_of_one_loop(text):
+        found = _collectives_by_computation(text)
+        (body,) = found
+        assert sorted(found[body]) == [
+            "all-reduce", "all-reduce",
+            "collective-permute", "collective-permute"], found
+        (loop,) = [ln for ln in text.splitlines()
+                   if re.search(rf"body=%{re.escape(body)}[,\s]", ln)]
+        return loop
+
+    # -- the virtual mesh: the engine's own executable ---------------------- #
+    mesh = build_mesh({"tp": 2}, jax.devices()[:2])
+    engine = GenerationEngine(
+        cfg, gpt.init_params(jax.random.PRNGKey(0), cfg), max_slots=2,
+        prefill_chunk=8, mesh=mesh)
+    try:
+        fn = (engine._step if program == "decode"
+              else engine._prefill_chunk_fn)
+        lowered = fn.lower(engine.params, *engine._pools, *arguments(
+            lambda dtype, *shape: jnp.zeros(shape, dtype)))
+        assert lowered.as_text().count("sdy.manual_computation") == 1
+        loop = all_reduces_of_one_loop(lowered.compile().as_text())
+        trips = int(re.search(r'"known_trip_count":{"n":"(\d+)"', loop)[1])
+        assert trips == n_layers
+        assert engine._expected_collectives == {"psum": 2 * trips}
+    finally:
+        engine.shutdown()
+
+    # -- two described chips: the pool stays where it lies ------------------ #
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh({"tp": 2}, v5e_devices[:2])
+    model = gpt_engine.GptPaged(cfg)
+    model._mesh = mesh      # ``shard`` would place arrays; these are shapes
+    everywhere = named_sharding(mesh)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=everywhere)
+
+    shapes = jax.eval_shape(
+        lambda: gpt.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=sharding),
+        shapes, tree_shardings(mesh, shapes, gpt.PARTITION_RULES))
+    # 1,024 pages: 25 MB a shard, too large to be staged in fast memory
+    # whole (a toy pool is, and then copied in and out).
+    pools = [jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=named_sharding(mesh, None, None, None,
+                                                  "tp"))
+        for a in jax.eval_shape(lambda: model.pool_arrays(1024, block_size))]
+    fn = (model.decode_step if program == "decode"
+          else model.prefill_chunk)(block_size)
+    began = time.monotonic()
+    text = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, *pools, *arguments(vec)).compile().as_text()
+    assert time.monotonic() - began < _COMPILE_SECONDS
+    all_reduces_of_one_loop(text)
+    assert "tpu_custom_call" in text
+    whole = tuple(pools[0].shape)
+    shard = whole[:3] + (whole[3] // 2,)
+    pool_dims = {",".join(map(str, d)) for full in (whole, shard)
+                 for d in (full, full[1:], (1,) + full[1:])}
+    moved = _moves_of(text, pool_dims, _MOVES + (
+        "all-gather", "all-gather-start", "slice", "slice-start"))
+    assert not moved, moved

@@ -375,3 +375,44 @@ def test_the_gpt_family_goes_through_the_same_seam():
         engine.shutdown()
         engine.release_pools()
     assert engine._pools == (None, None)
+
+
+@pytest.mark.parametrize("family", ["gpt", "mla_moe"])
+def test_a_familys_step_factories_take_the_block_size_and_the_width_alone(
+        family, monkeypatch):
+    """The seam the scheduler calls: ``decode_step(block_size)``,
+    ``decode_fused(block_size, n_steps)``, ``prefill_chunk(block_size)``,
+    in the base class and in the family, no private argument; and an engine
+    built from the family decodes four tokens through the first and
+    through the second, the same four."""
+    import inspect
+
+    from tritonclient_tpu.models import gpt
+
+    if family == "gpt":
+        cfg = gpt.gpt_tiny()
+        model = gpt_engine.GptPaged(cfg)
+        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    else:
+        cfg = mla_moe.mla_moe_tiny()
+        model = mla_moe.MlaMoePaged(cfg)
+        params = mla_moe.init_params(jax.random.PRNGKey(3), cfg)
+    seam = {"decode_step": ["block_size"],
+            "decode_fused": ["block_size", "n_steps"],
+            "prefill_chunk": ["block_size"]}
+    for factory, takes in seam.items():
+        for cls in (gpt_engine.PagedModel, type(model)):
+            assert list(inspect.signature(
+                getattr(cls, factory)).parameters)[1:] == takes, (cls, factory)
+    prompt = np.arange(1, 10, dtype=np.int32).reshape(1, 9)
+    served = {}
+    for fuse in (1, 4):
+        monkeypatch.setenv("TPU_ENGINE_FUSE_STEPS", str(fuse))
+        engine = GenerationEngine(model, params, max_slots=2, prefill_chunk=8)
+        try:
+            # the first token is the last chunk's; four more are decode's
+            served[fuse] = _collect(engine.submit(prompt, 5))
+            assert sorted(engine._multi_step) == ([4] if fuse == 4 else [])
+        finally:
+            engine.shutdown()
+    assert len(served[1]) == 5 and served[4] == served[1]

@@ -3,11 +3,9 @@ invisible except in throughput.
 
 Token identity is checked three ways — fused vs lockstep
 (TPU_ENGINE_FUSE_STEPS=4 vs 1) vs the contiguous single-request
-reference — at tp=1 and on the tp=2 virtual mesh (where the overlap
-projections are live), greedy and sampled. Cancellation must still take
-effect within the in-flight window (max_inflight x fuse micro-steps),
-and the overlap projection itself must be numerically equivalent to the
-plain matmul it replaces.
+reference — at tp=1 and on the tp=2 virtual mesh (where GSPMD's
+all-reduces are live), greedy and sampled. Cancellation must still take
+effect within the in-flight window (max_inflight x fuse micro-steps).
 """
 
 import time
@@ -90,51 +88,20 @@ def test_fused_matches_lockstep_and_reference_tp1(tiny, monkeypatch, samp):
     {"temperature": 0.7, "top_k": 20, "seed": 99},
 ], ids=["greedy", "sampled"])
 def test_fused_matches_lockstep_tp2(tiny, tp2_mesh, monkeypatch, samp):
-    """On the tp=2 mesh both fusion AND the chunked overlap projections
-    are live; the streams must still match the unfused, unchunked run
-    exactly (output-dim chunking preserves per-element accumulation
-    order, so this is equality, not allclose)."""
+    """On the tp=2 mesh the fused window's streams equal the lockstep
+    run's exactly: the scan body is the single step, all-reduces
+    included."""
     cfg, params = tiny
     rng = np.random.default_rng(6)
     prompts = _prompts(cfg, rng, [9, 17])
     max_news = [10, 8]
     monkeypatch.setenv("TPU_ENGINE_FUSE_STEPS", "1")
-    monkeypatch.setenv("TPU_ENGINE_OVERLAP", "0")
     plain = _run_engine(cfg, params, prompts, max_news, mesh=tp2_mesh,
                         **samp)
     monkeypatch.setenv("TPU_ENGINE_FUSE_STEPS", "4")
-    monkeypatch.setenv("TPU_ENGINE_OVERLAP", "1")
     fused = _run_engine(cfg, params, prompts, max_news, mesh=tp2_mesh,
                         **samp)
     assert fused == plain
-
-
-def test_row_parallel_proj_matches_plain_matmul(tp2_mesh):
-    """The chunked matmul+psum projection is the same function as
-    x @ w + b for a replicated-input/row-sharded-weight layout."""
-    from tritonclient_tpu.parallel.overlap import (
-        pick_chunks,
-        row_parallel_proj,
-    )
-
-    import functools
-
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((4, 32)).astype(np.float32)
-    w = rng.standard_normal((32, 48)).astype(np.float32)
-    b = rng.standard_normal((48,)).astype(np.float32)
-    want = x @ w + b
-    for chunks in (1, 2, 3, 4):
-        # Partial-manual shard_map only lowers under jit on this jax
-        # version — the engine always calls it from its jitted step.
-        fn = jax.jit(functools.partial(
-            row_parallel_proj, mesh=tp2_mesh, axis="tp", chunks=chunks,
-            note=False,
-        ))
-        np.testing.assert_allclose(np.asarray(fn(x, w, b)), want,
-                                   rtol=2e-5, atol=2e-5)
-    assert pick_chunks(48, 2, 5) == 4  # 5 does not divide 48; 4 does
-    assert pick_chunks(48, 1, 4) == 1  # trivial tp never chunks
 
 
 def test_cancel_takes_effect_within_inflight_window(tiny, monkeypatch):

@@ -41,15 +41,9 @@ thread:
 - collective count/bytes, accumulated by ``note_collective`` at the
   ``parallel/`` call sites through a thread-local step context, or charged
   as an expected per-step count for GSPMD-implicit all-reduces
-  (``expected_tp_collectives``);
-- overlapped-vs-exposed collective time (``coll_hidden_us`` /
-  ``coll_exposed_us``): with the chunked row-parallel projections of
-  ``parallel/overlap.py`` the all-reduce on chunk *i* can execute under the
-  matmul on chunk *i+1*, so only the trailing chunk's collective sits on
-  the step critical path. The engine charges both sides from structural
-  counts (``expected_overlap_split``) times a per-collective cost it
-  calibrates once on the live mesh, and ``step_report.py --compare`` shows
-  the exposed column before/after.
+  (``expected_tp_collectives``). A count, never a time: what a
+  collective costs, and how much of it no compute hides, is read from a
+  device trace.
 
 **Delivery records** — one per delivery item, written by the engine's
 delivery thread when it is done with the item, and joined to the dispatch
@@ -144,21 +138,10 @@ OUTCOME_ERROR = "error"
 
 STEP_METRIC = "nv_engine_step_duration_us_quantiles"
 COLLECTIVES_METRIC = "nv_engine_collectives_total"
-OVERLAP_METRIC = "nv_engine_collective_overlap_us_total"
 INFLIGHT_METRIC = "nv_engine_inflight_steps"
 KV_BYTES_METRIC = "nv_engine_kv_bytes_touched_total"
 COMPILE_CACHE_METRIC = "nv_engine_compile_cache_entries"
 RETRACE_METRIC = "nv_engine_retrace_total"
-
-# The exposed/hidden vocabulary is spelled once in protocol/_literals (the
-# wire-literal module); the fallback keeps stepscope importable standalone.
-try:  # pragma: no cover - import plumbing
-    from tritonclient_tpu.protocol._literals import (
-        OVERLAP_KIND_EXPOSED, OVERLAP_KIND_HIDDEN, OVERLAP_KINDS)
-except Exception:  # pragma: no cover
-    OVERLAP_KIND_EXPOSED = "exposed"
-    OVERLAP_KIND_HIDDEN = "hidden"
-    OVERLAP_KINDS = (OVERLAP_KIND_EXPOSED, OVERLAP_KIND_HIDDEN)
 
 # Bounded recent-step ring so dumps and Perfetto tracks stay small no
 # matter how long the engine runs.
@@ -186,7 +169,7 @@ class StepRecord:
         "lanes", "ctx_blocks", "ctx_pages", "tokens", "ctx_tokens",
         "t_begin", "t_dispatch", "t_end",
         "dispatch_us", "device_us", "other_us", "total_us",
-        "micro_steps", "coll_exposed_us", "coll_hidden_us",
+        "micro_steps",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
         "_annotation", "_entry",
     )
@@ -220,9 +203,6 @@ class StepRecord:
         # Fused pipelined dispatch: how many decode micro-steps this one
         # dispatch covers (1 for the lockstep path).
         self.micro_steps = 1
-        # Collective time on / off the step critical path (µs).
-        self.coll_exposed_us = 0
-        self.coll_hidden_us = 0
         # op -> [count, bytes]
         self.collectives: Dict[str, List[int]] = {}
         # Paged-KV bytes this step's attention read: ``ctx_pages`` x block
@@ -255,8 +235,6 @@ class StepRecord:
             "dispatch_us": self.dispatch_us,
             "total_us": self.total_us,
             "micro_steps": self.micro_steps,
-            "coll_exposed_us": self.coll_exposed_us,
-            "coll_hidden_us": self.coll_hidden_us,
             "collectives": {
                 op: {"count": c, "bytes": b}
                 for op, (c, b) in sorted(self.collectives.items())
@@ -334,8 +312,6 @@ class _Aggregator:
             self.collectives: Dict[Tuple[str, str], List[int]] = {}
             # (model, phase) -> cumulative paged-KV bytes touched
             self.kv_bytes: Dict[Tuple[str, str], int] = {}
-            # (model, kind) -> cumulative µs; kind in OVERLAP_KINDS
-            self.overlap: Dict[Tuple[str, str], int] = {}
             # model -> decode dispatches currently in flight
             self.inflight: Dict[str, int] = {}
             # (model, callable) -> distinct dispatch-signature keys; the
@@ -380,11 +356,6 @@ class _Aggregator:
                 self.kv_bytes[ck] = (
                     self.kv_bytes.get(ck, 0) + rec.kv_bytes
                 )
-            if rec.coll_exposed_us or rec.coll_hidden_us:
-                for kind, us in ((OVERLAP_KIND_EXPOSED, rec.coll_exposed_us),
-                                 (OVERLAP_KIND_HIDDEN, rec.coll_hidden_us)):
-                    ok = (rec.model, kind)
-                    self.overlap[ok] = self.overlap.get(ok, 0) + us
             worst = self.slowest.get(rec.model)
             if worst is None or rec.total_us > worst["total_us"]:
                 self.slowest[rec.model] = rec.as_dict()
@@ -599,13 +570,10 @@ def request_end(rec: Optional[RequestRecord], outcome: str):
         _aggregator.requests.append(rec.as_dict())
 
 
-def note_collective(op: str, count: int = 1, nbytes: int = 0,
-                    exposed_us: int = 0, hidden_us: int = 0):
+def note_collective(op: str, count: int = 1, nbytes: int = 0):
     """Charge a collective to the step live on this thread (no-op when
     stepscope is off or no step is open). Called from the ``parallel/``
-    call sites at JAX trace time. ``exposed_us``/``hidden_us`` attribute
-    the collective's time on/off the step critical path when the caller
-    knows the split (the overlap projections do)."""
+    call sites at JAX trace time."""
     if _mode == MODE_OFF:
         return
     rec = getattr(_tls, "active", None)
@@ -614,54 +582,33 @@ def note_collective(op: str, count: int = 1, nbytes: int = 0,
     cell = rec.collectives.setdefault(op, [0, 0])
     cell[0] += count
     cell[1] += nbytes
-    rec.coll_exposed_us += int(exposed_us)
-    rec.coll_hidden_us += int(hidden_us)
 
 
 def charge_collectives(rec: Optional[StepRecord], ops: Dict[str, int],
-                       nbytes: int = 0, exposed_us: int = 0,
-                       hidden_us: int = 0):
+                       nbytes: int = 0):
     """Charge an expected per-step collective count (GSPMD-implicit
     all-reduces never hit a python call site — the engine charges the
-    count the sharding provably forces), plus the calibrated
-    exposed/hidden collective time when the engine knows it."""
+    count the sharding provably forces)."""
     if rec is None:
         return
     for op, count in ops.items():
         cell = rec.collectives.setdefault(op, [0, 0])
         cell[0] += count
         cell[1] += nbytes
-    rec.coll_exposed_us += int(exposed_us)
-    rec.coll_hidden_us += int(hidden_us)
 
 
-def expected_tp_collectives(n_layers: int, tp: int,
-                            overlap_chunks: int = 1) -> Dict[str, int]:
+def expected_tp_collectives(n_layers: int, tp: int) -> Dict[str, int]:
     """Per-decode-step collective count the gpt PARTITION_RULES force
     under tensor parallelism: wo and w_out are row-sharded on 'tp', so
     GSPMD inserts one all-reduce after the attention projection and one
     after the FFN output — 2 psums per layer. tp=1 shards nothing.
-
-    With the chunked overlap projections (``parallel/overlap.py``,
-    ``overlap_chunks > 1``) each projection's single all-reduce becomes
-    one per output chunk — same total bytes, ``2 * n_layers *
-    overlap_chunks`` psum launches per step."""
+    tests/test_gpt_engine.py reads the same two off the partitioned
+    program, and beside them two collective-permutes a layer (the fused
+    QKV's split re-laying its columns across the shards) that this
+    count, of all-reduces, leaves out."""
     if tp <= 1:
         return {}
-    return {"psum": 2 * n_layers * max(int(overlap_chunks), 1)}
-
-
-def expected_overlap_split(n_layers: int, tp: int,
-                           overlap_chunks: int = 1) -> Tuple[int, int]:
-    """``(hidden_count, exposed_count)`` per decode step: of the chunked
-    projections' psums, the one on chunk *i < C-1* can run under chunk
-    *i+1*'s matmul, so per projection ``C-1`` hide and the trailing one is
-    exposed. Without chunking every forced psum is exposed."""
-    if tp <= 1:
-        return (0, 0)
-    chunks = max(int(overlap_chunks), 1)
-    per_step = 2 * n_layers
-    return (per_step * (chunks - 1), per_step)
+    return {"psum": 2 * n_layers}
 
 
 def note_compile(model: str, fn: str, key: str):
@@ -714,23 +661,11 @@ def inflight_update(model: str, delta: int):
 # -- sinks ------------------------------------------------------------------ #
 
 
-def overlap_snapshot():
-    """Overlap-plane rows for a /metrics scrape.
-
-    Returns ``(overlap_rows, inflight_rows)``: overlap_rows is
-    ``(model, kind, us)`` with both kinds emitted for every model that
-    recorded overlap time (so the exposition is vocabulary-complete), and
-    inflight_rows is ``(model, depth)``.
-    """
-    agg = _aggregator
-    with agg._lock:
-        models = sorted({model for model, _ in agg.overlap})
-        overlap_rows = [
-            (model, kind, agg.overlap.get((model, kind), 0))
-            for model in models for kind in OVERLAP_KINDS
-        ]
-        inflight_rows = sorted(agg.inflight.items())
-    return overlap_rows, inflight_rows
+def inflight_snapshot() -> List[Tuple[str, int]]:
+    """``(model, decode dispatches in flight)`` rows for the
+    nv_engine_inflight_steps gauge of a /metrics scrape."""
+    with _aggregator._lock:
+        return sorted(_aggregator.inflight.items())
 
 
 def metrics_snapshot(quantiles: Tuple[float, ...]):
@@ -782,7 +717,6 @@ def flight_attributes(model: str) -> Dict[str, object]:
             "step.slowest.batch_size": worst["batch_size"],
             "step.slowest.total_us": worst["total_us"],
             "step.slowest.dispatch_us": worst["dispatch_us"],
-            "step.slowest.coll_exposed_us": worst.get("coll_exposed_us", 0),
             "step.slowest.collectives": sum(
                 c["count"] for c in worst["collectives"].values()
             ),
@@ -857,10 +791,6 @@ def dump() -> dict:
             f"{model}|{op}": {"count": cell[0], "bytes": cell[1]}
             for (model, op), cell in sorted(agg.collectives.items())
         }
-        overlap = {
-            f"{model}|{kind}": us
-            for (model, kind), us in sorted(agg.overlap.items())
-        }
         kv_bytes = {
             f"{model}|{phase}": total
             for (model, phase), total in sorted(agg.kv_bytes.items())
@@ -882,7 +812,6 @@ def dump() -> dict:
         "requests": requests,
         "step_counts": step_counts,
         "collectives": collectives,
-        "overlap": overlap,
         "kv_bytes": kv_bytes,
         "inflight": inflight,
         "slowest": slowest,

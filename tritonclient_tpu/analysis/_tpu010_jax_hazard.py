@@ -2,8 +2,8 @@
 
 The stepscope numbers that motivate this rule: at tp=2 the decode loop
 spends 354.8 ms in host dispatch against 5.3 ms of device time — the
-regime where one hidden device→host sync or one silent retrace erases
-the entire compute/collective-overlap win. This rule makes those
+regime where one hidden device→host sync or one silent retrace costs
+more than the step itself. This rule makes those
 hazards lint errors *on the hot paths only*, so cold setup/debug code
 stays free to coerce arrays however it likes.
 
@@ -11,7 +11,7 @@ stays free to coerce arrays however it likes.
 ``# tpulint: hot-path`` on (or immediately above) its ``def`` line, and
 everything reachable from it in the project call graph is hot. The
 in-tree roots are the engines' decode/step loops, the distributor
-delivery loop, the overlap helpers, and the shm upload path.
+delivery loop, and the shm upload path.
 
 Flagged inside hot regions (``_callgraph.py`` records the candidates via
 local device-taint dataflow — results of ``jax.*``/``jnp.*``/``lax.*``

@@ -215,8 +215,7 @@ def _masked_cache_attention(q, kc, vc, mask):
     return jnp.einsum("nhl,nlhd->nhd", p, vc.astype(jnp.float32))
 
 
-def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, attend,
-                  proj_fn=None):
+def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, attend):
     """Single-token decoder layer, shared by the per-request decode path
     (`decode_step`) and the continuous-batching slot bank
     (models/gpt_engine.py) — one source of truth for the LN/QKV/
@@ -230,16 +229,9 @@ def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, attend,
     the paged engine passes its whole [L, n_blocks, bs, H * Dh] pools,
     which only its ``write_kv`` (a scatter at layer, page, offset) and its
     ``attend`` (the paged-attention kernel, which reads the pages a table
-    holds) index.
-
-    ``proj_fn(x, w, b)`` (optional) computes the two row-parallel
-    projections (attention output ``wo``, FFN down ``w_out``); the tp
-    engine passes ``parallel.overlap.make_row_parallel_proj`` so each
-    projection's all-reduce chunks under the next chunk's matmul. Default
-    is the plain matmul (identical math, GSPMD inserts the psums).
+    holds) index. Under tensor parallelism ``wo`` and ``w_out`` are
+    row-sharded and GSPMD puts one all-reduce behind each: two a layer.
     """
-    if proj_fn is None:
-        proj_fn = lambda x, w, b: x @ w + b  # noqa: E731
     n = h.shape[0]
     a = _layer_norm(h, lp["ln1_scale"], lp["ln1_bias"], cfg.layer_norm_eps)
     qkv = a @ lp["wqkv"] + lp["bqkv"]
@@ -248,10 +240,10 @@ def _decode_layer(h, lp, kc, vc, cfg: GptConfig, write_kv, attend,
     kc, vc = write_kv(kc, vc, k.reshape(hd), v.reshape(hd))
     out = attend(q.reshape(hd), kc, vc)
     out = out.reshape(n, cfg.d_model).astype(h.dtype)
-    h = h + proj_fn(out, lp["wo"], lp["bo"])
+    h = h + (out @ lp["wo"] + lp["bo"])
     m = _layer_norm(h, lp["ln2_scale"], lp["ln2_bias"], cfg.layer_norm_eps)
-    h = h + proj_fn(jax.nn.gelu(m @ lp["w_in"] + lp["b_in"]),
-                    lp["w_out"], lp["b_out"])
+    h = h + (jax.nn.gelu(m @ lp["w_in"] + lp["b_in"]) @ lp["w_out"]
+             + lp["b_out"])
     return h, (kc, vc)
 
 

@@ -51,6 +51,7 @@ Greedy decoding matches `gpt.generate_tokens` token-for-token (tested),
 so continuous batching changes scheduling, never results.
 """
 
+import os
 import queue
 import threading
 import time
@@ -95,7 +96,7 @@ def _block_pool_arrays(cfg: GptConfig, n_blocks: int, block_size: int):
 
 def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
                            rows_per_table: int, lengths, cfg: GptConfig,
-                           proj_fn, mesh=None):
+                           mesh=None):
     """The layer scan of every paged step: ``(h, k_pool, v_pool)`` is the
     CARRY and the scanned inputs are the layer's parameters and its index.
 
@@ -135,8 +136,7 @@ def _scan_layers_over_pool(params: Dict, x, k_pool, v_pool, btabs, dest, off,
             lambda kc, vc, k, v: (write(kc, k), write(vc, v)),
             lambda q, kc, vc: paged_attention(
                 q, kc, vc, li, btabs, plan,
-                rows_per_table=rows_per_table, mesh=mesh),
-            proj_fn=proj_fn)
+                rows_per_table=rows_per_table, mesh=mesh))
         return (h, k_pool, v_pool), None
 
     (x, k_pool, v_pool), _ = lax.scan(
@@ -181,7 +181,7 @@ def _sample_slots(logits, seeds, steps, temps, topks):
 
 def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
                        seeds, steps, temps, topks, cfg: GptConfig,
-                       block_size: int, proj_fn=None, mesh=None):
+                       block_size: int, mesh=None):
     """One step for the whole slot bank against the paged pool.
 
     ``btabs`` [S, max_blocks] int32 maps each slot's logical block index
@@ -208,8 +208,7 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
     off = pos % block_size
     dest = btabs[slot_ids, blk]                              # [S] page ids
     x, k_pool, v_pool = _scan_layers_over_pool(
-        params, x, k_pool, v_pool, btabs, dest, off, 1, pos + 1, cfg,
-        proj_fn, mesh)
+        params, x, k_pool, v_pool, btabs, dest, off, 1, pos + 1, cfg, mesh)
     logits = _head(params, x, cfg)
     # Greedy-only banks (the default) skip the sampler's full-vocab sort.
     nxt = lax.cond(
@@ -223,7 +222,7 @@ def _decode_step_paged(params: Dict, k_pool, v_pool, btabs, tokens, pos,
 def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
                              pos, seeds, steps, temps, topks,
                              cfg: GptConfig, block_size: int, n_steps: int,
-                             proj_fn=None, mesh=None):
+                             mesh=None):
     """``n_steps`` decode micro-steps in ONE dispatch: a ``lax.scan`` over
     the exact single-step body, returning the ``[n_steps, S]`` token
     block plus the advanced carry.
@@ -246,7 +245,7 @@ def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
         tokens, pos, steps, k_pool, v_pool = carry
         nxt, k_pool, v_pool = _decode_step_paged(
             params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
-            temps, topks, cfg, block_size, proj_fn=proj_fn, mesh=mesh,
+            temps, topks, cfg, block_size, mesh=mesh,
         )
         return (nxt, pos + 1, steps + 1, k_pool, v_pool), nxt
 
@@ -258,8 +257,7 @@ def _decode_multi_step_paged(params: Dict, k_pool, v_pool, btabs, tokens,
 
 def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
                          starts, n_valids, seeds, temps, topks,
-                         cfg: GptConfig, block_size: int, proj_fn=None,
-                         mesh=None):
+                         cfg: GptConfig, block_size: int, mesh=None):
     """One fixed-size prompt chunk for K prefilling slots in a SINGLE
     dispatch, K/V written into the pages of ``btabs`` [K, n_ctx] int32.
 
@@ -303,8 +301,7 @@ def _prefill_chunk_paged(params: Dict, k_pool, v_pool, chunks, btabs,
     lengths = jnp.where(valid, positions + 1, 1).reshape(kk * c)
 
     x, k_pool, v_pool = _scan_layers_over_pool(
-        params, x, k_pool, v_pool, btabs, dest, off, c, lengths, cfg,
-        proj_fn, mesh)
+        params, x, k_pool, v_pool, btabs, dest, off, c, lengths, cfg, mesh)
     last = jnp.take_along_axis(
         x.reshape(kk, c, cfg.d_model),
         (n_valids - 1).astype(jnp.int32)[:, None, None], axis=1,
@@ -350,15 +347,15 @@ class PagedModel:
         """``(params laid out on the mesh, the pools' sharding)``."""
         raise NotImplementedError
 
-    def decode_step(self, block_size: int, proj_fn=None):
+    def decode_step(self, block_size: int):
         """The function to jit as the whole-bank decode step; its name is
         the executable's on the device trace."""
         raise NotImplementedError
 
-    def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
+    def decode_fused(self, block_size: int, n_steps: int):
         raise NotImplementedError
 
-    def prefill_chunk(self, block_size: int, proj_fn=None):
+    def prefill_chunk(self, block_size: int):
         raise NotImplementedError
 
     def routing(self, extras) -> Optional[dict]:
@@ -407,19 +404,18 @@ class GptPaged(PagedModel):
         return (shard_tree(mesh, params, PARTITION_RULES),
                 named_sharding(mesh, None, None, None, "tp"))
 
-    def decode_step(self, block_size: int, proj_fn=None):
+    def decode_step(self, block_size: int):
         cfg, mesh = self.cfg, self._mesh
 
         def decode_step(params, k_pool, v_pool, btabs, tokens, pos, seeds,
                         steps, temps, topks):
             return _decode_step_paged(
                 params, k_pool, v_pool, btabs, tokens, pos, seeds, steps,
-                temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn, mesh=mesh)
+                temps, topks, cfg=cfg, block_size=block_size, mesh=mesh)
 
         return decode_step
 
-    def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
+    def decode_fused(self, block_size: int, n_steps: int):
         cfg, mesh = self.cfg, self._mesh
 
         def decode_fused(params, k_pool, v_pool, btabs, tokens, pos,
@@ -427,14 +423,14 @@ class GptPaged(PagedModel):
             return _decode_multi_step_paged(
                 params, k_pool, v_pool, btabs, tokens, pos, seeds,
                 steps, temps, topks, cfg=cfg, block_size=block_size,
-                n_steps=n_steps, proj_fn=proj_fn, mesh=mesh)
+                n_steps=n_steps, mesh=mesh)
 
         # One name per width: jit_decode_fused_<n> on the device trace.
         decode_fused.__name__ = f"decode_fused_{n_steps}"
         decode_fused.__qualname__ = decode_fused.__name__
         return decode_fused
 
-    def prefill_chunk(self, block_size: int, proj_fn=None):
+    def prefill_chunk(self, block_size: int):
         cfg, mesh = self.cfg, self._mesh
 
         def prefill_chunk(params, k_pool, v_pool, chunks, btabs, starts,
@@ -442,7 +438,7 @@ class GptPaged(PagedModel):
             return _prefill_chunk_paged(
                 params, k_pool, v_pool, chunks, btabs, starts, n_valids,
                 seeds, temps, topks, cfg=cfg, block_size=block_size,
-                proj_fn=proj_fn, mesh=mesh)
+                mesh=mesh)
 
         return prefill_chunk
 
@@ -551,10 +547,11 @@ class _Distributor:
     single-threaded.
     """
 
-    __slots__ = ("q", "prio_q", "free_q", "max_inflight", "_sem", "_thread",
-                 "_engine")
+    __slots__ = ("q", "prio_q", "free_q", "_sem", "_thread", "_engine")
 
-    def __init__(self, engine: "GenerationEngine", max_inflight: int = 3):
+    max_inflight = 3    # tickets: the one value every run on record used
+
+    def __init__(self, engine: "GenerationEngine"):
         self.q: "queue.Queue" = queue.Queue()
         # First-token (prefill) deliveries jump the line: a prefill item
         # is always its request's FIRST delivery, so overtaking OTHER
@@ -563,8 +560,7 @@ class _Distributor:
         # readbacks (~a readback RTT each on remote links).
         self.prio_q: "queue.Queue" = queue.Queue()
         self.free_q: "queue.Queue" = queue.Queue()
-        self.max_inflight = max_inflight
-        self._sem = threading.Semaphore(max_inflight)
+        self._sem = threading.Semaphore(self.max_inflight)
         self._thread: Optional[threading.Thread] = None
         self._engine = engine
 
@@ -871,51 +867,23 @@ class GenerationEngine:
         self._thread: Optional[threading.Thread] = None
         self._stopping = False
         self._broken: Optional[BaseException] = None
-        import os
-
-        self._dist = _Distributor(
-            self,
-            max_inflight=int(os.environ.get("TPU_ENGINE_MAX_INFLIGHT", "3")),
-        )
+        self._dist = _Distributor(self)
         # stepscope identity: records carry the serving model's name, and
         # tp engines charge the per-step all-reduce count the gpt
         # PARTITION_RULES provably force (GSPMD inserts them implicitly —
         # there is no python call site to count at).
         self._scope_name = scope_name
         tp = int(dict(mesh.shape).get("tp", 1)) if mesh is not None else 1
-        # Compute/collective overlap: under tp the row-parallel
-        # projections run as chunked matmul+psum pairs (parallel/overlap)
-        # so each chunk's all-reduce executes under the next chunk's
-        # matmul; only the trailing chunk is exposed. TPU_ENGINE_OVERLAP=0
-        # restores the plain GSPMD projections.
-        from tritonclient_tpu.parallel import overlap as _overlap
-
-        self._overlap_chunks = 1
-        self._proj_fn = None
-        if (mesh is not None and tp > 1
-                and _overlap.overlap_enabled_from_env()):
-            chunks = _overlap.pick_chunks(
-                cfg.d_model, tp, _overlap.overlap_chunks_from_env()
-            )
-            if chunks > 1:
-                self._overlap_chunks = chunks
-                self._proj_fn = _overlap.make_row_parallel_proj(
-                    mesh, "tp", chunks, note=False
-                )
         self._expected_collectives = _stepscope.expected_tp_collectives(
-            cfg.n_layers, tp, self._overlap_chunks
+            cfg.n_layers, tp
         )
-        self._overlap_split = _stepscope.expected_overlap_split(
-            cfg.n_layers, tp, self._overlap_chunks
-        )
-        self._coll_us: Optional[float] = None  # lazy calibration
         self._prefill_seq = 0
         # The engine's executables are jitted under the names the family
         # gives its step functions (the GPT family: jit_decode_step /
         # jit_decode_fused_<n> / jit_prefill_chunk on the profile's `XLA
         # Modules` line). Every pool is donated.
         self._donate = tuple(range(1, 1 + len(self._pools)))
-        self._step = jax.jit(model.decode_step(block_size, self._proj_fn),
+        self._step = jax.jit(model.decode_step(block_size),
                              donate_argnums=self._donate)
         # Unfused-branch slot clocks advance through a donating jit so
         # the dead pos/steps buffers are reused in place on TPU.
@@ -930,8 +898,7 @@ class GenerationEngine:
         self._multi_step: Dict[int, object] = {}
         self._dispatched = [0] * max_slots  # decode tokens dispatched/slot
         self._prefill_chunk_fn = jax.jit(
-            model.prefill_chunk(block_size, self._proj_fn),
-            donate_argnums=self._donate)
+            model.prefill_chunk(block_size), donate_argnums=self._donate)
         # /metrics registry: weakly bound so a dropped engine vanishes
         # from the exposition instead of being pinned by it.
         import weakref
@@ -1225,8 +1192,7 @@ class GenerationEngine:
         fn = self._multi_step.get(n_steps)
         if fn is None:
             fn = self._multi_step[n_steps] = jax.jit(
-                self._model.decode_fused(self.block_size, n_steps,
-                                         self._proj_fn),
+                self._model.decode_fused(self.block_size, n_steps),
                 donate_argnums=self._donate)
         return fn
 
@@ -1254,31 +1220,6 @@ class GenerationEngine:
         if left <= 1:
             return 1
         return 1 << (min(left, fuse).bit_length() - 1)
-
-    def _collective_us(self) -> float:  # tpulint: disable=TPU009 - engine-loop-only calibration cache (sole mutator)
-        """Per-launch all-reduce cost (µs) of the projection psum payload
-        on the live mesh, calibrated once and cached. Multiplied by the
-        structural counts of expected_overlap_split to charge each decode
-        record's exposed/hidden collective time — GSPMD/shard_map
-        collectives have no host-visible timestamps, so structural counts
-        × a same-mesh same-payload calibration is the honest attribution
-        (methodology in PERF.md)."""
-        us = self._coll_us
-        if us is None:
-            if self.mesh is None:
-                us = 0.0
-            else:
-                from tritonclient_tpu.parallel.overlap import (
-                    calibrate_collective_us,
-                )
-
-                shape = (self.max_slots,
-                         max(self.cfg.d_model
-                             // max(self._overlap_chunks, 1), 1))
-                us = calibrate_collective_us(self.mesh, shape,
-                                             self.cfg.dtype)
-            self._coll_us = us
-        return us
 
     def _release_cancelled(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """A consumer that went away (stream closed) marks its request
@@ -1859,16 +1800,7 @@ class GenerationEngine:
                     op: c * fuse
                     for op, c in self._expected_collectives.items()
                 }
-                hid_n, exp_n = self._overlap_split
-                if hid_n or exp_n:
-                    us = self._collective_us()
-                    _stepscope.charge_collectives(
-                        scope, ops,
-                        exposed_us=int(exp_n * fuse * us),
-                        hidden_us=int(hid_n * fuse * us),
-                    )
-                else:
-                    _stepscope.charge_collectives(scope, ops)
+                _stepscope.charge_collectives(scope, ops)
             try:
                 toks.copy_to_host_async()
             except AttributeError:

@@ -492,7 +492,7 @@ class MlaMoePaged(PagedModel):
     # device trace: jit_mla_moe_decode_step, jit_mla_moe_decode_fused_<n>,
     # jit_mla_moe_prefill_chunk.
 
-    def decode_step(self, block_size: int, proj_fn=None):
+    def decode_step(self, block_size: int):
         cfg = self.cfg
 
         def mla_moe_decode_step(params, pool, btabs, tokens, pos, seeds,
@@ -503,7 +503,7 @@ class MlaMoePaged(PagedModel):
 
         return mla_moe_decode_step
 
-    def decode_fused(self, block_size: int, n_steps: int, proj_fn=None):
+    def decode_fused(self, block_size: int, n_steps: int):
         cfg = self.cfg
 
         def decode_fused(params, pool, btabs, tokens, pos, seeds, steps,
@@ -516,7 +516,7 @@ class MlaMoePaged(PagedModel):
         decode_fused.__qualname__ = decode_fused.__name__
         return decode_fused
 
-    def prefill_chunk(self, block_size: int, proj_fn=None):
+    def prefill_chunk(self, block_size: int):
         cfg = self.cfg
 
         def mla_moe_prefill_chunk(params, pool, chunks, btabs, starts,

@@ -15,11 +15,6 @@ from tritonclient_tpu.parallel.multihost import (
     initialize,
     process_local_batch,
 )
-from tritonclient_tpu.parallel.overlap import (
-    calibrate_collective_us,
-    make_row_parallel_proj,
-    row_parallel_proj,
-)
 from tritonclient_tpu.parallel.ring_attention import ring_attention
 from tritonclient_tpu.parallel.sharding import (
     named_sharding,
@@ -33,10 +28,7 @@ __all__ = [
     "AXIS_ORDER",
     "auto_mesh",
     "build_mesh",
-    "calibrate_collective_us",
     "hybrid_mesh",
-    "make_row_parallel_proj",
-    "row_parallel_proj",
     "initialize",
     "named_sharding",
     "process_local_batch",

@@ -322,19 +322,6 @@ PREFIX_EVENTS = (
     PREFIX_EVENT_EVICT,
 )
 
-#: ``kind`` label vocabulary of ``nv_engine_collective_overlap_us_total``:
-#: collective time sitting on the engine step's critical path
-#: (``exposed``) vs hidden under the next chunk's matmul by the
-#: ``parallel/overlap.py`` chunked projections (``hidden``). Spelled here
-#: exactly once; ``_stepscope`` and ``check_metrics_exposition.py``
-#: mirror it with an import-or-fallback.
-OVERLAP_KIND_EXPOSED = "exposed"
-OVERLAP_KIND_HIDDEN = "hidden"
-OVERLAP_KINDS = (
-    OVERLAP_KIND_EXPOSED,
-    OVERLAP_KIND_HIDDEN,
-)
-
 # --------------------------------------------------------------------------- #
 # device-memory vocabulary (memscope)                                         #
 # --------------------------------------------------------------------------- #

@@ -1802,30 +1802,13 @@ class InferenceCore:
             lines.append(
                 f'{metric}{{model="{esc(sname)}",op="{esc(op)}"}} {ccount}'
             )
-        # Overlap plane: collective time split into exposed (on the step
-        # critical path) vs hidden (overlapped under the next chunk's
-        # matmul), charged per step from structural counts x calibrated
-        # per-launch cost. Both kinds render per model (zeros included)
-        # so the overlap ratio is computable from any single scrape.
-        overlap_rows, inflight_rows = _stepscope.overlap_snapshot()
-        metric = _stepscope.OVERLAP_METRIC
-        lines.append(
-            f"# HELP {metric} Collective microseconds attributed to "
-            "engine steps, by kind (exposed = on the step critical path, "
-            "hidden = overlapped under compute)"
-        )
-        lines.append(f"# TYPE {metric} counter")
-        for sname, kind, us in overlap_rows:
-            lines.append(
-                f'{metric}{{model="{esc(sname)}",kind="{kind}"}} {us}'
-            )
         metric = _stepscope.INFLIGHT_METRIC
         lines.append(
             f"# HELP {metric} Number of dispatched decode steps whose "
             "token delivery has not completed (pipelined dispatch depth)"
         )
         lines.append(f"# TYPE {metric} gauge")
-        for sname, depth in inflight_rows:
+        for sname, depth in _stepscope.inflight_snapshot():
             lines.append(f'{metric}{{model="{esc(sname)}"}} {depth}')
         metric = _stepscope.KV_BYTES_METRIC
         lines.append(
