@@ -98,10 +98,9 @@ def main():
     for warm_len in (32, 128, 160):
         _drain(engine.submit(np.ones((1, warm_len), np.int32), 2))
     _wait_idle(engine)
-    # Deterministically compile the vectorized admission ops for every
-    # burst size k (a racy concurrent-submit warmup can skip
-    # intermediate k values, leaving first-use compiles to land inside
-    # a measured window).
+    # Load the slot-state update (one executable whatever a burst
+    # carries; warm_prefill makes it for each lane bucket's result), so
+    # no first-use compile lands inside a measured window.
     engine.warm_admission()
     # ... and the batched chunk-prefill family: every lane bucket ×
     # the context buckets the measured prompt lengths pass through

@@ -13,7 +13,9 @@ of the experts held that got one, the most loaded expert against the mean:
 the ``routing <phase>`` rows); per delivery item,
 how long it queued for the delivery thread, its readback and its hand-over;
 what the engine thread did between dispatches (``ticket_wait`` /
-``idle_wait`` / ``admit`` / ``join``); and one timeline per request
+``idle_wait`` / ``admit`` / ``join``) and, inside ``admit`` and ``join``,
+its slot-state updates (how many, the slots each joined and freed, what
+each cost the thread: the ``slot updates`` row); and one timeline per request
 (receipt to core to submit, wait for a slot, the prefill span split at its
 first chunk, last chunk and first token's readback, tokens, worst gap
 between two tokens). A counters-mode dump has no device clock — the engine thread's
@@ -23,8 +25,8 @@ dispatch-/device-bound verdict. It consumes
 
 * a stepscope dump (``tritonclient_tpu._stepscope.dump()`` saved to a
   file) — the primary input: the recent-step ring with full breakdowns,
-  the loop states, the delivery thread's ring and the finished requests'
-  ring;
+  the loop states, the delivery thread's ring, the slot-state updates'
+  ring and the finished requests' ring;
 * a flight-recorder dump (``GET v2/debug/flight_recorder``) — retained
   records carry the slowest step's breakdown as ``step.slowest.*``
   attributes;
@@ -191,20 +193,27 @@ def load_compiles(doc) -> Dict[str, Dict[str, dict]]:
     return out
 
 
-def load_requests(doc) -> List[dict]:
-    """The finished requests' ring of a stepscope dump (empty for every
+def _load_ring(doc, name: str) -> List[dict]:
+    """One of a stepscope dump's rings beside the records (empty for every
     other input, and for dumps from before the ring existed)."""
     if not (isinstance(doc, dict) and doc.get("kind") == "stepscope"):
         return []
-    return list(doc.get("requests") or [])
+    return list(doc.get(name) or [])
+
+
+def load_requests(doc) -> List[dict]:
+    """The finished requests' ring."""
+    return _load_ring(doc, "requests")
 
 
 def load_deliveries(doc) -> List[dict]:
-    """The delivery thread's ring of a stepscope dump (empty for every
-    other input)."""
-    if not (isinstance(doc, dict) and doc.get("kind") == "stepscope"):
-        return []
-    return list(doc.get("deliveries") or [])
+    """The delivery thread's ring."""
+    return _load_ring(doc, "deliveries")
+
+
+def load_slot_updates(doc) -> List[dict]:
+    """The slot-state updates' ring."""
+    return _load_ring(doc, "slot_updates")
 
 
 def load_file(path: str) -> List[dict]:
@@ -287,6 +296,23 @@ def _deliveries(deliveries: List[dict]) -> Dict[str, dict]:
                                   "p95": _percentile(spans, 0.95)}
         out[phase] = cell
     return out
+
+
+def _slot_updates(updates: List[dict]) -> Optional[dict]:
+    """The engine loop's slot-state updates: how many dispatches, the slots
+    they joined and freed in all, and what one cost the loop's thread
+    (host time from building its arrays to the call's return; p50 / p95,
+    ms). None where the dump has none."""
+    if not updates:
+        return None
+    host_ms = sorted(round(int(u.get("host_ns", 0)) / 1e6, 3)
+                     for u in updates)
+    return {"n": len(updates),
+            "joined": sum(int(u.get("joined", 0)) for u in updates),
+            "freed": sum(int(u.get("freed", 0)) for u in updates),
+            "host_ms": {"p50": _percentile(host_ms, 0.50),
+                        "p95": _percentile(host_ms, 0.95)},
+            "host_total_ms": round(sum(host_ms), 3)}
 
 
 #: The ms columns of the per-request table, in the order of the timeline.
@@ -375,11 +401,13 @@ def _routing(recs: List[dict]) -> Optional[dict]:
 def analyze(records: List[dict],
             compiles: Optional[Dict[str, Dict[str, dict]]] = None,
             requests: Optional[List[dict]] = None,
-            deliveries: Optional[List[dict]] = None) -> dict:
+            deliveries: Optional[List[dict]] = None,
+            slot_updates: Optional[List[dict]] = None) -> dict:
     """Per-model verdict + per-phase quantiles and stage means; when the
     dump carries the compile plane, each model also gets its per-callable
     cache-entry/retrace totals, and from a stepscope dump its loop states,
-    its deliveries' table and its requests' table."""
+    its deliveries' table, its slot-state updates' row and its requests'
+    table."""
     by_model: Dict[str, List[dict]] = {}
     for r in records:
         by_model.setdefault(r.get("model", ""), []).append(r)
@@ -444,6 +472,8 @@ def analyze(records: List[dict],
             "loop_states": _loop_states(every),
             "deliveries": _deliveries(
                 [d for d in deliveries or [] if d.get("model") == model]),
+            "slot_updates": _slot_updates(
+                [u for u in slot_updates or [] if u.get("model") == model]),
             "requests": _request_rows(
                 [q for q in requests or [] if q.get("model") == model]),
             "compiles": dict(sorted(((compiles or {}).get(model)
@@ -529,6 +559,17 @@ def render(analysis: dict) -> str:
                 f"  loop {state:<12} {cell['n']:>6} stretches "
                 f"{cell['total_ms']:>10.3f} ms "
                 f"({100 * cell['share']:.1f}% of the recorded span)"
+            )
+        # The writes joins, frees and cancels made to the slot state, inside
+        # the ``admit`` and ``join`` stretches above: one dispatch a burst.
+        updates = m.get("slot_updates")
+        if updates:
+            lines.append(
+                f"  slot updates      {updates['n']:>6} dispatches, "
+                f"{updates['joined']} slots joined, {updates['freed']} "
+                f"freed, host p50/p95 ms: {updates['host_ms']['p50']}/"
+                f"{updates['host_ms']['p95']} "
+                f"({updates['host_total_ms']} ms in all)"
             )
         rows = m.get("requests") or []
         if rows:
@@ -853,8 +894,14 @@ def self_check() -> int:
          "queued_ns": 1_000_000 * i, "taken_ns": 1_000_000 * i + 250_000,
          "ready_ns": 1_000_000 * i + 750_000,
          "delivered_ns": 1_000_000 * i + 800_000} for i in (1, 2, 3)]
+    dump["slot_updates"] = [
+        {"model": "gpt_engine", "joined": 3, "freed": 0,
+         "start_ns": 40_100_000, "host_ns": 300_000},
+        {"model": "gpt_engine", "joined": 0, "freed": 2,
+         "start_ns": 41_000_000, "host_ns": 100_000}]
     analysis = analyze(load_records(dump), load_compiles(dump),
-                       load_requests(dump), load_deliveries(dump))
+                       load_requests(dump), load_deliveries(dump),
+                       load_slot_updates(dump))
     m = analysis["models"]["gpt_engine"]
     rendered = render(analysis)
     if (m["verdict"] != VERDICT_NO_DEVICE_CLOCK
@@ -880,12 +927,16 @@ def self_check() -> int:
                 "n": 3, "queue_wait_ms": {"p50": 0.25, "p95": 0.25},
                 "readback_ms": {"p50": 0.5, "p95": 0.5},
                 "handover_ms": {"p50": 0.05, "p95": 0.05}}}
+            or m["slot_updates"] != {
+                "n": 2, "joined": 3, "freed": 2,
+                "host_ms": {"p50": 0.3, "p95": 0.3}, "host_total_ms": 0.4}
+            or "slot updates" not in rendered
             or "records no device time" not in rendered
             or "loop ticket_wait" not in rendered
             or "delivery decode" not in rendered
             or "worst_gap" not in rendered or "median" not in rendered):
         print("self-check [counters]: device clock invented, or loop "
-              "states / deliveries / requests / routing lost",
+              "states / deliveries / slot updates / requests / routing lost",
               file=sys.stderr)
         failures += 1
     else:
@@ -969,7 +1020,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     analysis = analyze(records, load_compiles(doc), load_requests(doc),
-                       load_deliveries(doc))
+                       load_deliveries(doc), load_slot_updates(doc))
     if args.compare:
         try:
             with open(args.compare) as f:

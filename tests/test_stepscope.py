@@ -13,6 +13,7 @@ import importlib.util
 import json
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -401,9 +402,15 @@ def test_step_report_verdict_from_engine_dump(mode):
     analysis = step_report.analyze(
         step_report.load_records(doc),
         requests=step_report.load_requests(doc),
-        deliveries=step_report.load_deliveries(doc))
+        deliveries=step_report.load_deliveries(doc),
+        slot_updates=step_report.load_slot_updates(doc))
     model = analysis["models"]["gpt_engine"]
     rendered = step_report.render(analysis)
+    # (The engine is shut down with the last tokens: a slot it frees on the
+    # way down is reset by no dispatch.)
+    assert model["slot_updates"]["joined"] == 4 >= model[
+        "slot_updates"]["freed"]
+    assert "  slot updates" in rendered
     if mode == _stepscope.MODE_SYNC:
         assert model["verdict"] in (
             step_report.VERDICT_DISPATCH, step_report.VERDICT_DEVICE,
@@ -757,6 +764,79 @@ def test_loop_states_enter_the_ring_and_nothing_else():
     assert "gpt_engine/admit" in names
 
 
+def test_slot_updates_have_a_ring_of_their_own_and_add_up_to_the_requests():
+    """One record a dispatch of the engine's slot-state update, with its
+    fields; over a run the slots joined equal the requests that finished
+    their prefill and the slots freed those that ended (six requests over
+    four slots, one of them cancelled mid-stream); every update lies
+    inside an ``admit`` (frees) or a ``join`` stretch of the loop, which
+    is why it is no loop state itself; and the ring is not the dispatch
+    ring (the harness's readers see nothing new)."""
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=128)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=4, prefill_chunk=_CHUNK)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, (1, n)).astype(np.int32)
+               for n in (19, 23, 31, 37, 12, 40)]
+    try:
+        reqs = [engine.submit(p, 9) for p in prompts[:-1]]
+        gone = engine.submit(prompts[-1], 60)
+        for r in reqs:
+            while r.out.get(timeout=120) is not None:
+                pass
+        assert gone.out.get(timeout=120) is not None    # it joined
+        gone.cancelled = True
+        while gone.out.get(timeout=120) is not None:
+            pass
+        deadline = time.time() + 30
+        while (any(r is not None for r in engine._slot_req)
+               and time.time() < deadline):
+            time.sleep(0.02)  # tpulint: disable=TPU001 - sync test, no loop
+    finally:
+        engine.shutdown()
+    doc = _stepscope.dump()
+    updates = doc["slot_updates"]
+    assert updates and all(set(u) == {"model", "joined", "freed",
+                                      "start_ns", "host_ns"}
+                           for u in updates)
+    assert all(u["model"] == "gpt_engine" and u["host_ns"] > 0
+               and u["joined"] + u["freed"] > 0
+               and not (u["joined"] and u["freed"]) for u in updates)
+    assert sum(u["joined"] for u in updates) == 6
+    assert sum(u["freed"] for u in updates) == 6     # five ended, one left
+    assert len(doc["requests"]) == 6
+    assert not [r for r in doc["records"] if "joined" in r]
+    stretches = {state: [(r["start_ns"],
+                          r["start_ns"] + 1000 * (r["dispatch_us"] + 1))
+                         for r in doc["records"] if r["phase"] == state]
+                 for state in (_stepscope.LOOP_ADMIT, _stepscope.LOOP_JOIN)}
+    for u in updates:
+        state = (_stepscope.LOOP_JOIN if u["joined"]
+                 else _stepscope.LOOP_ADMIT)
+        assert any(lo <= u["start_ns"] and u["start_ns"] + u["host_ns"] <= hi
+                   for lo, hi in stretches[state]), (u, state)
+    # ... and scripts/step_report.py's row for them.
+    step_report = _load_script("step_report.py", "step_report_slots")
+    analysis = step_report.analyze(
+        step_report.load_records(doc),
+        slot_updates=step_report.load_slot_updates(doc))
+    row = analysis["models"]["gpt_engine"]["slot_updates"]
+    assert row["n"] == len(updates) and row["joined"] == row["freed"] == 6
+    assert 0 < row["host_ms"]["p50"] <= row["host_ms"]["p95"]
+    assert "  slot updates" in step_report.render(analysis)
+    # A dump from before the ring existed has no row, and none is rendered.
+    del doc["slot_updates"]
+    older = step_report.analyze(
+        step_report.load_records(doc),
+        slot_updates=step_report.load_slot_updates(doc))
+    assert older["models"]["gpt_engine"]["slot_updates"] is None
+    assert "slot updates" not in step_report.render(older)
+
+
 def test_idle_wait_is_recorded_when_the_engine_parks():
     """An engine with nothing to do waits on its condition: one idle_wait
     stretch from the last token to the next submit."""
@@ -795,7 +875,7 @@ def test_stepscope_off_stamps_nothing():
         engine.shutdown()
     doc = _stepscope.dump()
     assert doc["records"] == [] and doc["requests"] == []
-    assert doc["deliveries"] == []
+    assert doc["deliveries"] == [] and doc["slot_updates"] == []
     assert doc["step_counts"] == {}
     assert _stepscope.request_begin("m", _PROMPTS_C4[0], 4) is None
     _stepscope.request_end(None, _stepscope.OUTCOME_FINISHED)
@@ -803,7 +883,9 @@ def test_stepscope_off_stamps_nothing():
     _stepscope.delivery_end(None)
     _stepscope.step_abandon()
     _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, 1, 2)
+    _stepscope.slot_update("m", 1, 0, 1, 2)
     assert _stepscope.dump()["records"] == []
+    assert _stepscope.dump()["slot_updates"] == []
 
 
 def test_a_dispatch_that_raises_leaves_no_step_open(monkeypatch):

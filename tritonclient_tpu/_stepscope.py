@@ -5,8 +5,8 @@ the model ran, not where an engine *step* spent its time. This module is
 the missing layer — a low-overhead clock the decode/prefill loops in
 ``models/gpt_engine.py`` and the dynamic batcher's compute phase stamp
 where the work happens. All times are ``time.monotonic_ns()``. It keeps
-three rings (``dump()["records"]``, ``dump()["deliveries"]`` and
-``dump()["requests"]``):
+four rings (``dump()["records"]``, ``dump()["deliveries"]``,
+``dump()["slot_updates"]`` and ``dump()["requests"]``):
 
 **Dispatch records** — one per device dispatch, opened on the dispatching
 thread:
@@ -60,6 +60,14 @@ record, and nothing is added to observe it.
 records in the same ring with ``dispatch_us`` = their duration. They overlap
 neither a dispatch record nor each other, and reach the ring only: no
 sketch, no ``/metrics`` row.
+
+**Slot-update records** — one per dispatch of the engine's slot-state
+update (``gpt_engine._update_slots``: the one program through which joins,
+frees and cancels write the per-slot device state): ``joined`` and
+``freed`` (the slots the call carried) and ``start_ns`` / ``host_ns``, the
+host time from building the call's arrays to its return. They lie INSIDE a
+``join`` or ``admit`` stretch, so they have a ring of their own and are no
+loop state.
 
 **Request records** — one per generation, written once by the thread that
 ends it: receipt and core stamps copied from the request's
@@ -331,6 +339,8 @@ class _Aggregator:
             self.ring: deque = deque(maxlen=max(ring, 1))
             # The delivery thread's records (``delivery_end``).
             self.deliveries: deque = deque(maxlen=max(ring, 1))
+            # The engine loop's slot-state updates (``slot_update``).
+            self.slot_updates: deque = deque(maxlen=max(ring, 1))
             # Finished request records (RequestRecord.as_dict()).
             self.requests: deque = deque(maxlen=max(ring, 1))
 
@@ -543,6 +553,20 @@ def loop_state(model: str, state: str, start_ns: int, end_ns: int,
     }
     with _aggregator._lock:
         _aggregator.ring.append(record)
+
+
+def slot_update(model: str, joined: int, freed: int, start_ns: int,
+                end_ns: int):
+    """One dispatch of the engine's slot-state update: how many slots it
+    joined and freed, and the host time it took the engine loop from
+    building the arrays to the call's return. ``start_ns`` 0 (stepscope
+    was off when the call began) records nothing."""
+    if _mode == MODE_OFF or not start_ns:
+        return
+    record = {"model": model, "joined": joined, "freed": freed,
+              "start_ns": start_ns, "host_ns": end_ns - start_ns}
+    with _aggregator._lock:
+        _aggregator.slot_updates.append(record)
 
 
 # -- request timeline ------------------------------------------------------- #
@@ -777,11 +801,13 @@ def perfetto_events(epoch_ns: int) -> List[dict]:
 def dump() -> dict:
     """Self-describing document ``scripts/step_report.py`` loads: the
     recent-step ring (dispatch records and loop states), the delivery
-    thread's ring, the finished requests' ring, plus aggregate totals."""
+    thread's ring, the slot-state updates' ring, the finished requests'
+    ring, plus aggregate totals."""
     agg = _aggregator
     with agg._lock:
         records = list(agg.ring)
         deliveries = list(agg.deliveries)
+        slot_updates = list(agg.slot_updates)
         requests = list(agg.requests)
         step_counts = {
             f"{model}|{phase}": count
@@ -809,6 +835,7 @@ def dump() -> dict:
         "mode": _mode,
         "records": records,
         "deliveries": deliveries,
+        "slot_updates": slot_updates,
         "requests": requests,
         "step_counts": step_counts,
         "collectives": collectives,

@@ -265,7 +265,7 @@ def build_gpt_engine() -> Model:
                     continue  # tokens only flow once the prefill is done
                 req.remaining = 0
                 req.out.put(None)
-                eng._dist.free_q.put((slot, req))
+                eng._dist.free_q.put([(slot, req)])
                 with eng._cv:
                     eng._cv.notify_all()
                 done.add(id(req))

@@ -517,7 +517,7 @@ class _PrefillState:
     """
 
     __slots__ = ("req", "prompt_len", "blocks", "n_hit", "hashes",
-                 "next", "first")
+                 "next")
 
     def __init__(self, req: "_Request", prompt_len: int,
                  blocks: List[int], n_hit: int, hashes: List[int]):
@@ -527,7 +527,6 @@ class _PrefillState:
         self.n_hit = n_hit
         self.hashes = hashes
         self.next = 0
-        self.first = None
 
 
 class _Distributor:
@@ -543,8 +542,9 @@ class _Distributor:
 
     A bounded window (``max_inflight`` tickets) stops compute running
     unboundedly ahead of delivery. Slot-freeing on completion is routed
-    back to the engine loop through ``free_q`` — slot state stays
-    single-threaded.
+    back to the engine loop through ``free_q`` (one item a dispatch: the
+    ``(slot, request)`` pairs it finished, so the loop frees a burst in one
+    pass) — slot state stays single-threaded.
     """
 
     __slots__ = ("q", "prio_q", "free_q", "_sem", "_thread", "_engine")
@@ -711,6 +711,7 @@ class _Distributor:
         if delivery is not None:
             ready_ns = delivery["ready_ns"] = time.monotonic_ns()
         rows = nxt_np if nxt_np.ndim == 2 else nxt_np[None]
+        finished = []
         for t in range(rows.shape[0]):
             row = rows[t]
             for idx, slot, req in pairs:
@@ -739,11 +740,60 @@ class _Distributor:
                         pass
                 if req.remaining == 0:
                     req.end(None, _stepscope.OUTCOME_FINISHED)
-                    self.free_q.put((slot, req))
-                    with self._engine._cv:
-                        self._engine._cv.notify_all()
+                    finished.append((slot, req))
+        if finished:
+            self.free_q.put(finished)
+            with self._engine._cv:
+                self._engine._cv.notify_all()
         if delivery is not None:
             delivery["delivered_ns"] = time.monotonic_ns()
+
+
+# The columns of ``_update_slots``' one host-built argument, ``[S, 7 +
+# max_blocks]`` int32: a slot's lane in the prefill's result, whether it is
+# joined or freed, its position, seed and top-k, its temperature's float32
+# bits, then its block-table row.
+_W_LANE, _W_JOINED, _W_FREED, _W_POS, _W_SEED, _W_TOPK, _W_TEMP, _W_ROW = (
+    range(8))
+
+
+def _update_slots(btabs, tokens, pos, seeds, steps, temps, topks, firsts,
+                  writes):
+    """Every write a join, a free or a cancel makes to the per-slot device
+    state, as ONE program of fixed shapes: the engine loop calls it at most
+    once for the frees and once for the joins of a pass.
+
+    The state vectors are ``[S]`` (``btabs`` ``[S, max_blocks]``);
+    ``writes`` is one NumPy array over all the slots (columns ``_W_*``). It
+    is ONE array because on the loop's thread every transfer and every
+    dispatch gives the interpreter lock up and has to get it back from the
+    server's stream handlers: under eight contending threads a call with
+    eight NumPy arguments took 31 ms on the chip's host, with one 15, and
+    twenty eager scatters and transfers 544 (PERF.md §6, PR 30). A joined
+    slot takes its block-table row, position, seed, temperature and top-k
+    from ``writes``, ``steps`` 1, and its token from the prefill's result HERE
+    (``firsts[lane]``: no slice, no concatenate and no second host copy
+    on the loop's thread). A freed slot takes the all-scratch row, position
+    0 and temperature 0.0 (an all-greedy bank goes back down the step's
+    cheap argmax branch); every other slot keeps what it has. Nothing
+    depends on how many slots a call carries: ``firsts``' lane bucket is
+    the only shape that varies. Nothing is donated: in the unfused branch
+    ``tokens`` IS the array the delivery thread reads back.
+    """
+    joined = writes[:, _W_JOINED] > 0
+    # A freed slot's other columns are zeros: the scratch page, position
+    # 0, and the bits of 0.0.
+    written = joined | (writes[:, _W_FREED] > 0)
+    temps_new = lax.bitcast_convert_type(writes[:, _W_TEMP], jnp.float32)
+    return (
+        jnp.where(written[:, None], writes[:, _W_ROW:], btabs),
+        jnp.where(joined, firsts[writes[:, _W_LANE]], tokens),
+        jnp.where(written, writes[:, _W_POS], pos),
+        jnp.where(joined, writes[:, _W_SEED], seeds),
+        jnp.where(joined, 1, steps),
+        jnp.where(written, temps_new, temps),
+        jnp.where(joined, writes[:, _W_TOPK], topks),
+    )
 
 
 class GenerationEngine:
@@ -888,6 +938,10 @@ class GenerationEngine:
         # Unfused-branch slot clocks advance through a donating jit so
         # the dead pos/steps buffers are reused in place on TPU.
         self._advance = jax.jit(_advance_slot_clocks, donate_argnums=(0, 1))
+        # Joins, frees and cancels write the slot state through one jitted
+        # update (replicated over a mesh, as the vectors are).
+        self._update_slots = jax.jit(_update_slots,
+                                     out_shardings=self._vec_sharding)
         # Fused pipelined dispatch: TPU_ENGINE_FUSE_STEPS=k scans k decode
         # micro-steps into one dispatch + one readback when the bank is
         # saturated (no prefills, empty admission queue, every active
@@ -1003,7 +1057,7 @@ class GenerationEngine:
             if req is not None:
                 req.end(None, cancelled)
                 self._prefilling.pop(slot, None)
-                self._free_slot_blocks(slot, device_reset=False)
+                self._free_slot_blocks(slot)
                 self._slot_req[slot] = None
 
     # -- client side ---------------------------------------------------------
@@ -1056,17 +1110,19 @@ class GenerationEngine:
 
     # -- block accounting ----------------------------------------------------
 
-    def _free_slot_blocks(self, slot: int, device_reset: bool = True):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
+    def _free_slot_blocks(self, slot: int):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Return a slot's pages (block-granular, immediately reusable).
 
         Registered pages park on the prefix cache's evictable LRU (their
         KV stays warm); unregistered ones go straight to the free list.
-        ``device_reset`` re-points the slot's block-table row at the
-        scratch page so in-flight/surplus decode writes for this slot
-        can no longer land in pages a NEW request may get — the paged
-        equivalent of the contiguous bank's harmless garbage writes.
-        (False only on shutdown/broken paths where no further dispatch
-        will happen and the device may be unusable.)
+        The host half of a free, and all of it on the shutdown and broken
+        paths, where no further dispatch will happen and the device may be
+        unusable. While the engine serves, the caller hands the slot to
+        ``_write_slot_state`` in the same pass: that re-points the slot's
+        block-table row at the scratch page, so in-flight/surplus decode
+        writes for this slot can no longer land in pages a NEW request may
+        get — the paged equivalent of the contiguous bank's harmless
+        garbage writes.
         """
         req = self._slot_req[slot]
         owner = req.mem_owner if req is not None else ""
@@ -1085,11 +1141,31 @@ class GenerationEngine:
             # leak (TPU012 finding under the sanitizer).
             _memscope.owner_finish(self._scope_name,
                                    _memscope.MEM_POOL_KV, owner)
-        if device_reset:
-            self._btabs = self._btabs.at[slot].set(
-                jnp.zeros((self._max_blocks,), jnp.int32)
-            )
-            self._pos = self._pos.at[slot].set(0)
+
+    def _write_slot_state(self, firsts, joins=(), frees=()):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
+        """One ``_update_slots`` dispatch: ``joins`` are ``(lane, slot,
+        prefill state)`` of the prompts the chunk dispatch behind
+        ``firsts`` finished, ``frees`` the slots whose requests ended. What
+        they write is built here as one NumPy array over all the slots, so
+        the executable is the same whatever the burst carries; stepscope
+        gets one record a call (how many slots, and what the call cost
+        this thread)."""
+        began = time.monotonic_ns() if _stepscope.enabled() else 0
+        writes = np.zeros((self.max_slots, _W_ROW + self._max_blocks),
+                          np.int32)
+        writes[list(frees), _W_FREED] = 1
+        for lane, slot, st in joins:
+            req = st.req
+            writes[slot, :_W_ROW] = (
+                lane, 1, 0, st.prompt_len, req.seed, req.top_k,
+                np.float32(req.temperature).view(np.int32))
+            writes[slot, _W_ROW:_W_ROW + len(st.blocks)] = st.blocks
+        (self._btabs, self._tokens, self._pos, self._seeds, self._steps,
+         self._temps, self._topks) = self._update_slots(
+            self._btabs, self._tokens, self._pos, self._seeds, self._steps,
+            self._temps, self._topks, firsts, writes)
+        _stepscope.slot_update(self._scope_name, len(joins), len(frees),
+                               began, time.monotonic_ns())
 
     def _alloc_block(self) -> Optional[int]:
         """A free page, evicting the LRU zero-ref cached page if needed."""
@@ -1230,62 +1306,65 @@ class GenerationEngine:
         thread, in pipeline order. ``cancel_event`` (armed by the
         protocol front-end on disconnect/stream cancel) is polled here —
         between decode steps — so an abandoned generation frees its slot
-        even when its response generator never runs again. Returns
-        whether anything was released."""
-        released = False
+        even when its response generator never runs again. Returns the
+        slots released (a head-of-line request that went away held
+        none)."""
+        slots = []
         for slot, req in enumerate(self._slot_req):
             if req is not None and req.abandoned:
-                released = True
+                slots.append(slot)
                 # Pages back BEFORE the slot reads empty: anything polling
                 # _slot_req for completion (tests, warm_admission callers)
                 # must find the pool already reconciled.
                 self._prefilling.pop(slot, None)
                 self._free_slot_blocks(slot)
-                self._temps = self._temps.at[slot].set(0.0)
                 self._slot_req[slot] = None
                 self._dist.submit_cancel(req)
         if self._pending is not None and self._pending.abandoned:
             self._pending.end(None, _stepscope.OUTCOME_CANCELLED)
             self._pending = None
-            released = True
-        return released
+        return slots
 
     def _process_frees(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Apply slot-completions reported by the delivery thread.
 
         Only the engine loop mutates slot state; the distributor just
-        queues (slot, req) here when a request's final token went out.
-        Pages return to the pool HERE — block-granular, the moment the
-        request finishes, not when the slot's longest cohabitant does.
-        Returns whether a slot was freed.
+        queues the (slot, req) pairs of a dispatch here when their final
+        tokens went out. Pages return to the pool HERE — block-granular,
+        the moment the request finishes, not when the slot's longest
+        cohabitant does. Returns the slots freed.
         """
-        freed = False
+        slots = []
         while True:
             try:
-                slot, req = self._dist.free_q.get_nowait()
+                finished = self._dist.free_q.get_nowait()
             except queue.Empty:
-                return freed
-            if self._slot_req[slot] is req:
-                freed = True
-                # Pages back BEFORE the slot reads empty (same ordering
-                # as _release_cancelled: pollers of _slot_req must find
-                # the pool already reconciled). The temperature reset
-                # sends an all-greedy bank back down the cheap argmax
-                # branch of the step.
-                self._free_slot_blocks(slot)
-                self._temps = self._temps.at[slot].set(0.0)
-                self._slot_req[slot] = None
+                return slots
+            for slot, req in finished:
+                if self._slot_req[slot] is req:
+                    slots.append(slot)
+                    # Pages back BEFORE the slot reads empty (same
+                    # ordering as _release_cancelled: pollers of _slot_req
+                    # must find the pool already reconciled).
+                    self._free_slot_blocks(slot)
+                    self._slot_req[slot] = None
 
     def _housekeep(self) -> bool:  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Frees, cancels and admissions, in that order: what the loop does
-        between dispatches besides waiting. When it did something the
-        stretch is stepscope's ``admit`` loop state; returns whether it
-        did."""
+        between dispatches besides waiting. The slots the pass freed are
+        reset on the device by ONE update, enqueued here: before
+        ``_admit_requests`` can hand their pages on, and before the pass's
+        next model dispatch (an old bank's surplus decode writes already
+        in flight land in those pages BEFORE anything the next holder
+        writes there, and no later dispatch reads the old rows). When the
+        pass did something the stretch is stepscope's ``admit`` loop state;
+        returns whether it did."""
         began = time.monotonic_ns() if _stepscope.enabled() else 0
-        freed = self._process_frees()
-        released = self._release_cancelled()
+        freed = self._process_frees() + self._release_cancelled()
+        if freed:
+            self._write_slot_state(self._tokens, frees=freed)
         admitted = self._admit_requests()
-        worked = freed or released or admitted
+        worked = bool(freed) or admitted
         if worked:
             _stepscope.loop_state(self._scope_name, _stepscope.LOOP_ADMIT,
                                   began, time.monotonic_ns(),
@@ -1337,7 +1416,7 @@ class GenerationEngine:
     def _advance_prefills(self):  # tpulint: disable=TPU002,TPU009 - engine-loop thread is the sole mutator of slot state
         """Dispatch ONE prefill chunk for every still-prefilling slot —
         all slots in a SINGLE batched dispatch — then admit completed
-        ones into the decode bank in a single vectorized burst. One
+        ones into the decode bank by one slot-state update. One
         chunk per slot per loop top is the interleave: decode steps run
         between chunks, so a long prompt streams in without stalling
         anyone's ITL. Batching the chunks across slots is the
@@ -1430,7 +1509,7 @@ class GenerationEngine:
         _stepscope.charge_collectives(scope, self._expected_collectives)
         # The dispatch return, on the requests' timelines too.
         returned_ns = scope.t_dispatch if scope is not None else 0
-        done = []  # (slot, state)
+        done = []  # (lane, slot, state)
         for i, (slot, st, start, n_valid) in enumerate(lanes):
             st.next = start + n_valid
             span = st.req.span
@@ -1441,8 +1520,7 @@ class GenerationEngine:
                 span.last_chunk_ns = returned_ns
                 span.chunks += 1
             if st.next >= st.prompt_len:
-                st.first = firsts_dev[i : i + 1]
-                done.append((slot, st))
+                done.append((i, slot, st))
         if done:
             try:
                 firsts_dev.copy_to_host_async()
@@ -1453,56 +1531,32 @@ class GenerationEngine:
         if not done:
             return True
         # stepscope's ``join`` loop state: from here to the hand-over of
-        # the first tokens, the burst of slot-state writes below.
+        # the first tokens.
         joining_from = time.monotonic_ns() if scope is not None else 0
-        # Slot-state updates are device-op ENQUEUES (several per slot):
-        # a synchronized churn burst (batched steps finish batchmates
+        # A synchronized churn burst (batched steps finish batchmates
         # together, their clients resubmit together) completes many
-        # prefills at one loop top, and per-slot scalar writes would pay
-        # 7 x k enqueues on the burst tail — the TTFT p99 term on
-        # remote-dispatch links. One vectorized write per state vector
-        # (k=1 included: one code path, one warmable shape family), and
-        # ONE batched first-token delivery — k separate prio deliveries
-        # would re-pay the fixed per-readback cost k times on the
-        # delivery thread. Admission never blocks on a readback; order
-        # per request is preserved (the prio entry precedes any step
-        # including these slots). Setting the DEVICE block-table row
-        # here — only after the last chunk — is what routes the slot's
-        # decode writes from the scratch page onto its real pages.
-        for slot, st in done:
+        # prefills at one loop top. Whatever their number, the burst is
+        # ONE slot-state update, enqueued here: after the last chunk's
+        # dispatch and before the decode dispatch that first includes the
+        # slots. Setting the DEVICE block-table row only now is what
+        # routes a slot's decode writes from the scratch page onto its
+        # real pages; the slot's first token is taken from the chunk's
+        # result inside the update. And ONE first-token delivery, of the
+        # chunk's whole result with each pair's index its lane — k
+        # separate prio deliveries would re-pay the fixed per-readback
+        # cost k times on the delivery thread. Admission never blocks on a
+        # readback; order per request is preserved (the prio entry
+        # precedes any step including these slots).
+        for _, slot, st in done:
             del self._prefilling[slot]
             # First token counts against the budget: decode dispatches
             # owe max_new - 1 more (the fuse chooser reads this).
             self._dispatched[slot] = 1
             for i in range(st.n_hit, len(st.hashes)):
                 self._prefix.register(st.hashes[i], st.blocks[i])
-        firsts = jnp.concatenate([st.first for _, st in done])
-        slots = jnp.array([s for s, _ in done], jnp.int32)
-        rows = np.zeros((len(done), self._max_blocks), np.int32)
-        for i, (_, st) in enumerate(done):
-            rows[i, :len(st.blocks)] = st.blocks
-        self._btabs = self._btabs.at[slots].set(jnp.asarray(rows))
-        self._tokens = self._tokens.at[slots].set(firsts)
-        self._pos = self._pos.at[slots].set(
-            jnp.array([st.prompt_len for _, st in done], jnp.int32)
-        )
-        self._seeds = self._seeds.at[slots].set(
-            jnp.array([st.req.seed for _, st in done], jnp.int32)
-        )
-        self._steps = self._steps.at[slots].set(1)
-        self._temps = self._temps.at[slots].set(
-            jnp.array([st.req.temperature for _, st in done], jnp.float32)
-        )
-        self._topks = self._topks.at[slots].set(
-            jnp.array([st.req.top_k for _, st in done], jnp.int32)
-        )
-        try:
-            firsts.copy_to_host_async()
-        except AttributeError:
-            pass
+        self._write_slot_state(firsts_dev, joins=done)
         self._dist.submit(
-            firsts,
-            [(i, slot, st.req) for i, (slot, st) in enumerate(done)],
+            firsts_dev, [(lane, slot, st.req) for lane, slot, st in done],
             first_token=True, scope=scope,
         )
         _stepscope.loop_state(self._scope_name, _stepscope.LOOP_JOIN,
@@ -1511,68 +1565,38 @@ class GenerationEngine:
         return True
 
     def warm_admission(self):
-        """Pre-execute the vectorized admission ops for every burst size
-        (each k compiles its own scatter/concat shapes on first use —
-        multi-second stalls on remote-compile links that must not land
-        inside a serving window). Only safe on an idle engine: the loop
-        rewrites slot state with zeros, which would silently corrupt any
-        in-flight generation — so idleness is now enforced under the cv
-        instead of being a docstring contract (ADVICE r5 #1).
+        """Run the slot-state update once with nothing joined and nothing
+        freed, so its executable is loaded before a serving window (the
+        update has one shape whatever a burst carries; ``warm_prefill``
+        makes it for each lane bucket of a prefill's result). The state
+        keeps its values, but the vectors are rebound, so idleness is
+        enforced under the cv instead of being a docstring contract
+        (ADVICE r5 #1).
 
-        The whole rewrite runs UNDER ``self._cv``: an actively-serving
-        engine (occupied slots or queued admissions) raises, and holding
-        the cv for the duration excludes concurrent ``submit()``s — an
+        The call runs UNDER ``self._cv``: an actively-serving engine
+        (occupied slots or queued admissions) raises, and holding the cv
+        for the duration excludes concurrent ``submit()``s — an
         alive-but-idle engine thread is then harmless, since its loop
         only mutates slot state in response to admissions, frees, or
         cancels, none of which can arrive while the cv is held. (The
         idle loop itself blocks on this cv, so it cannot even re-check.)
         """
-        import jax
-
         with self._cv:
-            if self._stopping or self._broken is not None:
-                raise RuntimeError(
-                    "warm_admission on a stopped or broken engine"
-                )
-            busy = [s for s, r in enumerate(self._slot_req) if r is not None]
-            if busy or not self._admit.empty() or self._pending is not None:
-                raise RuntimeError(
-                    "warm_admission requires an idle engine: all slots "
-                    "free and an empty admission queue (busy slots: "
-                    f"{busy}, queued admissions: {self._admit.qsize()})"
-                )
-            for k in range(1, self.max_slots + 1):
-                # Mirror the admission path's exact op shapes: host-array
-                # scatters for the request fields and block-table rows,
-                # device-concat for tokens.
-                slots = jnp.array(list(range(k)), jnp.int32)
-                firsts = jnp.concatenate(
-                    [self._tokens[s : s + 1] for s in range(k)]
-                )
-                self._btabs = self._btabs.at[slots].set(
-                    jnp.asarray(np.zeros((k, self._max_blocks), np.int32))
-                )
-                self._tokens = self._tokens.at[slots].set(firsts)
-                self._pos = self._pos.at[slots].set(
-                    jnp.array([0] * k, jnp.int32)
-                )
-                self._seeds = self._seeds.at[slots].set(
-                    jnp.array([0] * k, jnp.int32)
-                )
-                self._steps = self._steps.at[slots].set(1)
-                self._temps = self._temps.at[slots].set(
-                    jnp.array([0.0] * k, jnp.float32)
-                )
-                self._topks = self._topks.at[slots].set(
-                    jnp.array([0] * k, jnp.int32)
-                )
-            # Admission leaves _steps at 1 for warmed rows; the real
-            # admission path writes every vector, so the warm state is
-            # rewritten before any request decodes against it.
-            self._steps = self._steps.at[
-                jnp.arange(self.max_slots)
-            ].set(0)
+            self._require_idle("warm_admission")
+            self._write_slot_state(self._tokens)
             jax.block_until_ready(self._tokens)
+
+    def _require_idle(self, what: str):  # tpulint: disable=TPU002,TPU009 - both callers hold self._cv
+        """Raise unless nothing is served or queued (under ``self._cv``)."""
+        if self._stopping or self._broken is not None:
+            raise RuntimeError(f"{what} on a stopped or broken engine")
+        busy = [s for s, r in enumerate(self._slot_req) if r is not None]
+        if busy or not self._admit.empty() or self._pending is not None:
+            raise RuntimeError(
+                f"{what} requires an idle engine: all slots "
+                "free and an empty admission queue (busy slots: "
+                f"{busy}, queued admissions: {self._admit.qsize()})"
+            )
 
     def warm_prefill(self, ctx_blocks=(1,)):
         """Compile the chunk-prefill shape family — every power-of-two
@@ -1582,23 +1606,15 @@ class GenerationEngine:
         multi-second XLA compile lands inside a measured window when a
         synchronized churn burst first produces that batch shape. Warm
         lanes carry all-scratch tables, so every write routes to the
-        scratch page and no pool pages are touched. Same idle-only
-        contract as ``warm_admission`` (the chunk fn donates the pools,
-        so it must not race the engine loop's own dispatches)."""
-        import jax
-
+        scratch page and no pool pages are touched. Each lane bucket's
+        prefill is followed by the slot-state update on that prefill's
+        own result (nothing joined: the state keeps its values), so the
+        update is compiled for an array placed as a window's will be. Same
+        idle-only contract as ``warm_admission`` (the chunk fn donates
+        the pools, so it must not race the engine loop's own
+        dispatches)."""
         with self._cv:
-            if self._stopping or self._broken is not None:
-                raise RuntimeError(
-                    "warm_prefill on a stopped or broken engine"
-                )
-            busy = [s for s, r in enumerate(self._slot_req) if r is not None]
-            if busy or not self._admit.empty() or self._pending is not None:
-                raise RuntimeError(
-                    "warm_prefill requires an idle engine: all slots "
-                    "free and an empty admission queue (busy slots: "
-                    f"{busy}, queued admissions: {self._admit.qsize()})"
-                )
+            self._require_idle("warm_prefill")
             c = self.prefill_chunk
             buckets = sorted(
                 {_pow2_bucket(max(1, int(b)), self._max_blocks)
@@ -1608,13 +1624,14 @@ class GenerationEngine:
             while True:
                 for n_ctx in buckets:
                     z = jnp.zeros((kk,), jnp.int32)
-                    self._keep_pools(self._prefill_chunk_fn(
+                    (firsts,), _ = self._keep_pools(self._prefill_chunk_fn(
                         self.params, *self._pools,
                         jnp.zeros((kk, c), jnp.int32),
                         jnp.zeros((kk, n_ctx), jnp.int32),
                         z, jnp.ones((kk,), jnp.int32), z,
                         jnp.zeros((kk,), jnp.float32), z,
                     ), 1)
+                self._write_slot_state(firsts)
                 if kk >= self.max_slots:
                     break
                 kk = min(kk * 2, self.max_slots)
@@ -1653,7 +1670,7 @@ class GenerationEngine:
                     self._slot_req[slot] = None
                     self._prefilling.pop(slot, None)
                     # Host bookkeeping only: the device is suspect.
-                    self._free_slot_blocks(slot, device_reset=False)
+                    self._free_slot_blocks(slot)
 
     # tpulint: hot-path
     def _run_loop(self):  # tpulint: disable=TPU002,TPU009,TPU011 - engine loop is the sole mutator of slot state AND the sole _cv waiter: it cannot sleep across its own updates
