@@ -219,7 +219,8 @@ def test_rows_that_carry_no_request_reach_no_expert(tiny):
     live = jnp.asarray([True, False, True, True, False, True])
     y, counts = mla_moe.routed_experts(x, experts, weights, live, banks, cfg,
                                        layer)
-    assert counts.shape == (cfg.n_experts,)
+    # every expert is held here: the histogram, then no pair elsewhere
+    assert counts.shape == (cfg.n_experts + 1,) and int(counts[-1]) == 0
     assert int(counts.sum()) == 4 * cfg.experts_per_token
     assert not np.asarray(y[1]).any() and not np.asarray(y[4]).any()
     # a live row's result is its own whatever its neighbours are

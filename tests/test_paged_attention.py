@@ -3,6 +3,8 @@ Pallas interpreter, against the float32 masked einsum it replaced in the
 paged engine's layers (models/gpt.py ``_masked_cache_attention``) on the
 same pool."""
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,3 +122,132 @@ def test_kernel_refuses_rows_that_do_not_fill_the_tables():
     with pytest.raises(ValueError, match="tables x"):
         paged_attention(q, k_pool, v_pool, jnp.int32(0), btabs, lengths,
                         rows_per_table=4)
+
+
+# --------------------------------------------------------------------------- #
+# grouped query heads and a window                                            #
+# --------------------------------------------------------------------------- #
+
+_WIDE_CTX = 12      # pages a table: contexts that leave a window's first chunk
+
+
+def _grouped_bank(kv_heads, group, head_dim, dtype, rows):
+    """Six tables of ``_WIDE_CTX`` live pages each (a ring has no dead
+    entry), their longest rows at ``rows``, 40, 64, 100, 129 and the whole
+    table; ``group`` query heads a K/V head."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(kv_heads + head_dim), 3)
+    pool_shape = (2, 60, _BS, kv_heads * head_dim)
+    k_pool = jax.random.normal(kk, pool_shape, jnp.float32).astype(dtype)
+    v_pool = jax.random.normal(kv, pool_shape, jnp.float32).astype(dtype)
+    longest = [rows, 40, 64, 100, 129, _WIDE_CTX * _BS]
+    q = jax.random.normal(kq, (len(longest) * rows, kv_heads * group,
+                               head_dim), jnp.float32).astype(dtype)
+    rng = np.random.RandomState(1)
+    lengths = np.stack([np.maximum(1, top - np.arange(rows)[::-1])
+                        for top in longest]).astype(np.int32)
+    btabs = np.stack([rng.choice(np.arange(1, 60), _WIDE_CTX, replace=False)
+                      for _ in longest]).astype(np.int32)
+    return q, k_pool, v_pool, jnp.asarray(btabs), jnp.asarray(
+        lengths.reshape(-1))
+
+
+def _grouped_reference(q, k_pool, v_pool, layer, btabs, lengths, rows,
+                       window):
+    """A masked einsum over each table's gathered view: query head i against
+    K/V head i // group, keys [length - window, length)."""
+    tables, n_ctx = btabs.shape
+    head_dim = q.shape[2]
+    kv_heads = k_pool.shape[3] // head_dim
+    f32 = jnp.float32
+    keys = k_pool[layer][btabs].reshape(tables, n_ctx * _BS, kv_heads,
+                                        head_dim).astype(f32)
+    values = v_pool[layer][btabs].reshape(keys.shape).astype(f32)
+    grouped = q.astype(f32).reshape(tables, rows, kv_heads, -1, head_dim)
+    at = jnp.arange(n_ctx * _BS)[None, None, :]
+    upto = lengths.reshape(tables, rows)[:, :, None]
+    seen = at < upto
+    if window is not None:
+        seen &= at >= upto - window
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.einsum("trkgd,tlkd->trkgl", grouped, keys) / np.sqrt(
+            head_dim)
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, :, None, None, :], scores, -jnp.inf), axis=-1)
+        out = jnp.einsum("trkgl,tlkd->trkgd", probs, values)
+    return out.reshape(q.shape)
+
+
+@pytest.mark.parametrize("tile_rows", [None, 16], ids=["one_tile", "tiles"])
+@pytest.mark.parametrize("window", [None, 24, 128],
+                         ids=["global", "window24", "window128"])
+@pytest.mark.parametrize("kv_heads,group,head_dim,dtype,rows", [
+    (2, 4, 16, jnp.float32, 1), (2, 4, 16, jnp.float32, 8),
+    (1, 8, 128, jnp.bfloat16, 1), (2, 4, 64, jnp.bfloat16, 8)],
+    ids=["2x4x16_decode", "2x4x16_chunk", "1x8x128_decode", "2x4x64_chunk"])
+def test_grouped_and_windowed_kernel_is_the_masked_einsum(
+        kv_heads, group, head_dim, dtype, rows, window, tile_rows,
+        monkeypatch):
+    """Fewer K/V heads than query heads (a K/V head's columns read by
+    ``group`` row blocks), rows that see their last ``window`` keys only
+    (the grid starts at the chunk that holds the earliest of them: tables of
+    40 to 192 positions under windows of 24 and 128), and a table's rows
+    cut into tiles of 16 (each a table of its own to the kernel): the
+    result is the masked einsum's to 1e-5, in float32 and over a bfloat16
+    pool read as stored."""
+    from tritonclient_tpu.ops import paged_attention as module
+
+    if tile_rows is not None:
+        if rows * group <= tile_rows:
+            pytest.skip("one tile either way")
+        monkeypatch.setattr(sys.modules[module.__module__], "_TILE_ROWS",
+                            tile_rows)
+    q, k_pool, v_pool, btabs, lengths = _grouped_bank(
+        kv_heads, group, head_dim, dtype, rows)
+    got = paged_attention(q, k_pool, v_pool, jnp.int32(1), btabs, lengths,
+                          rows_per_table=rows, window=window)
+    assert got.shape == q.shape and got.dtype == jnp.float32
+    want = _grouped_reference(q, k_pool, v_pool, 1, btabs, lengths, rows,
+                              window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-5)
+
+
+def test_a_windows_grid_holds_the_windows_pages_and_not_the_contexts():
+    """The plan of a window layer: a table of 192 positions under a window
+    of 24 takes the one chunk of 8 pages that holds positions 168-191, not
+    the two under the context; a row at 130 takes chunk 0 too (its window
+    starts at 106, page 6) and one at 160 only chunk 1."""
+    from tritonclient_tpu.ops.paged_attention import plan_pages
+
+    btabs = jnp.arange(1, 1 + 3 * _WIDE_CTX, dtype=jnp.int32).reshape(3, -1)
+    lengths = jnp.asarray([192, 130, 160], jnp.int32)
+    whole = plan_pages(btabs, lengths, block_size=_BS)
+    window = plan_pages(btabs, lengths, block_size=_BS, window=24)
+    assert int(whole.n_steps) == 2 + 2 + 2 and whole.lows is None
+    assert int(window.n_steps) == 1 + 2 + 1
+    steps = int(window.n_steps)
+    assert window.table_of[:steps].tolist() == [0, 1, 1, 2]
+    assert window.chunk_of[:steps].tolist() == [1, 0, 1, 1]
+    assert window.lows[:, 0, 0].tolist() == [168, 106, 136]
+    # table 0's chunk 1: entries 8-11 of its row; operands past the last
+    # live entry stay where chunk 0 would have left them (entries 4-7: read
+    # once, and masked by their positions, 192 and up)
+    assert window.page_of[:, 0].tolist() == [9, 10, 11, 12, 5, 6, 7, 8]
+
+
+def test_a_full_multi_head_call_traces_as_it_did_before_grouping():
+    """What the GPT cells run: no group, no window, one tile. The plan has
+    no lower bounds and the kernel is given none (one ``lens`` operand, the
+    ``chunk == 0`` start), so nothing of the additions is in its trace."""
+    q, k_pool, v_pool, btabs, lengths = _bank(16, 128, jnp.bfloat16, 8)
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: paged_attention(*a, rows_per_table=8)
+    )(q, k_pool, v_pool, jnp.int32(0), btabs, lengths))
+    assert "transpose" not in jaxpr.split("pallas_call")[0]
+    call = jax.make_jaxpr(
+        lambda *a: paged_attention(*a, rows_per_table=8)
+    )(q, k_pool, v_pool, jnp.int32(0), btabs, lengths)
+    (eqn,) = [e for e in call.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    # the grid's length, layer, 4 maps, q, lens, then the K and V operands
+    # of a chunk's pages (the table's 6): no lows
+    assert len(eqn.invars) == 1 + 5 + 2 + 2 * _N_CTX

@@ -19,7 +19,13 @@ thread:
   ``ctx_pages`` (the table entries under the real lanes' lengths, summed
   over the micro-steps: what a paged-attention kernel visits, and the GPT
   family's does), ``tokens`` (positions computed) and ``ctx_tokens``
-  (context really held by the real lanes), all from host-side state;
+  (context really held by the real lanes), all from host-side state; for a
+  family whose layers are of two kinds, ``ctx_pages_global`` and
+  ``ctx_pages_window`` (the pages a layer of each kind read: a window
+  layer's are its window's, not the context's) and
+  ``kv_held_global_bytes`` / ``kv_held_window_bytes`` (what the dispatch's
+  requests hold in the layers of each kind: the window layers' stops
+  growing at their ring);
 - ``dispatch_us``: host time from step begin to dispatch return (trace +
   XLA dispatch of the jitted call); the same bracket is a
   ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
@@ -30,7 +36,9 @@ thread:
   experts that got a token, summed over the expert layers and the
   micro-steps) of ``experts_held`` (layers x micro-steps x experts),
   ``expert_load_max`` (the most tokens on one expert of one layer) against
-  ``expert_load_mean``. They come from the histogram the step returns,
+  ``expert_load_mean``, and ``pairs_elsewhere`` (the (token, expert) pairs
+  whose expert another chip holds: 0 where the program holds them all; the
+  experts counted are the held ones). They come from the histogram the step returns,
   which the delivery thread reads back behind the tokens (``step_routing``):
   a record that is read before that has no such fields yet;
 - ``device_us`` / ``other_us``: **``sync`` mode only** — a bracketed
@@ -179,6 +187,8 @@ class StepRecord:
         "dispatch_us", "device_us", "other_us", "total_us",
         "micro_steps",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
+        "ctx_pages_global", "ctx_pages_window", "kv_held_global",
+        "kv_held_window",
         "_annotation", "_entry",
     )
 
@@ -218,6 +228,11 @@ class StepRecord:
         # block-table extent x block bytes where it gathers the table; the
         # engine sets it on the thread-owned record before step_end.
         self.kv_bytes = 0
+        # A family with window layers only (set by the engine): the pages
+        # the kernel read in a layer of each kind, and the bytes the
+        # dispatch's requests hold in the layers of each kind.
+        self.ctx_pages_window: Optional[int] = None
+        self.ctx_pages_global = self.kv_held_global = self.kv_held_window = 0
         thread = threading.current_thread()
         self.thread_ident = thread.ident or 0
         self.thread_name = thread.name
@@ -254,6 +269,11 @@ class StepRecord:
         if self.device_us is not None:
             out["device_us"] = self.device_us
             out["other_us"] = self.other_us
+        if self.ctx_pages_window is not None:
+            out.update(ctx_pages_global=self.ctx_pages_global,
+                       ctx_pages_window=self.ctx_pages_window,
+                       kv_held_global_bytes=self.kv_held_global,
+                       kv_held_window_bytes=self.kv_held_window)
         return out
 
 
@@ -498,7 +518,7 @@ def step_end(rec: Optional[StepRecord], outputs=None):
 
 
 ROUTING_FIELDS = ("routed_tokens", "experts_hit", "experts_held",
-                  "expert_load_max", "expert_load_mean")
+                  "expert_load_max", "expert_load_mean", "pairs_elsewhere")
 
 
 def step_routing(rec: Optional[StepRecord], counters: Optional[dict]):

@@ -334,14 +334,46 @@ class PagedModel:
     # (a paged kernel) or gathers its table's whole width: what a
     # dispatch's ``kv_bytes`` are reckoned from.
     reads_pages_held = False
+    # Whether a prompt's full pages may be handed to a later request with
+    # the same prefix. A family whose layers keep state that those pages do
+    # not hold (a window layer's ring) declines: ``_reserve`` then matches
+    # and registers nothing.
+    shares_prefix = True
+    # Whether a prefill chunk's executable is specialised by its context (a
+    # power-of-two bucket of table entries: what a table-wide gather wants),
+    # or always given the whole table (a kernel whose grid is traced).
+    prefill_by_context = True
 
     def pool_arrays(self, n_blocks: int, block_size: int) -> tuple:
         """The page pools, each ``[n_layers, n_blocks, block_size, ...]``."""
         raise NotImplementedError
 
     def block_bytes(self, block_size: int) -> int:
-        """Bytes one block-table entry stands for, over every layer."""
+        """Bytes one block-table entry stands for, over every layer that
+        keeps every position (a GLOBAL layer: all, but for a family with
+        window layers)."""
         raise NotImplementedError
+
+    # A family may have layers of a second kind: WINDOW layers, which keep a
+    # bounded set of pages a slot whatever the request's length (a ring),
+    # beside the block table's. The three below say what the scheduler has
+    # to know of them; the defaults are a family without any.
+
+    def ring_pages(self, block_size: int) -> int:
+        """Entries of a slot's ring: a table row is ``[ring | block table]``,
+        ring entry j of slot s being page ``1 + s * ring + j`` of the window
+        layers' region (0 its scratch page)."""
+        return 0
+
+    def kind_bytes(self, block_size: int) -> tuple:
+        """Bytes one page stands for over the layers of each kind:
+        ``(global, window)``."""
+        return self.block_bytes(block_size), 0
+
+    def pages_read(self, length: int, rows: int, block_size: int) -> tuple:
+        """Pages a layer of each kind reads for ``rows`` query rows of one
+        table that end at context ``length``: ``(global, window)``."""
+        return -(-length // block_size), 0
 
     def shard(self, mesh, params):
         """``(params laid out on the mesh, the pools' sharding)``."""
@@ -835,9 +867,15 @@ class GenerationEngine:
             )
         self.block_size = block_size
         self._max_blocks = cfg.max_len // block_size   # per-slot table width
-        # Bytes one block-table entry makes a step touch, across every
-        # layer's pages (the stepscope kv_bytes accounting unit).
-        self._block_kv_bytes = model.block_bytes(block_size)
+        # Bytes one page stands for over the layers of each kind (global,
+        # window): the accounting units of admission, memscope and
+        # stepscope's kv_bytes. A block-table entry is a global page.
+        self._kind_bytes = tuple(model.kind_bytes(block_size))
+        self._block_kv_bytes = self._kind_bytes[0]
+        # Entries of a slot's ring (0: the family has no window layers); a
+        # table row is [ring | block table].
+        self._ring = model.ring_pages(block_size)
+        self._table_width = self._ring + self._max_blocks
         if n_blocks is None:
             n_blocks = 1 + max_slots * self._max_blocks
         self.prefill_chunk = max(1, min(int(prefill_chunk), cfg.max_len))
@@ -881,7 +919,7 @@ class GenerationEngine:
         self._slot_blocks: List[List[int]] = [[] for _ in range(max_slots)]
         self._prefilling: Dict[int, _PrefillState] = {}
         self._pending: Optional[_Request] = None  # head-of-line, blocked on pages
-        self._btabs = jnp.zeros((max_slots, self._max_blocks), jnp.int32)
+        self._btabs = jnp.zeros((max_slots, self._table_width), jnp.int32)
         self._tokens = jnp.zeros((max_slots,), jnp.int32)
         self._pos = jnp.zeros((max_slots,), jnp.int32)
         # Per-slot sampling state (request settings + the (seed, step)
@@ -908,6 +946,14 @@ class GenerationEngine:
                 self._steps, self._temps, self._topks))),
             {"buffers": "btabs/tokens/pos/seeds/steps/temps/topks"},
         )
+        if self._ring:
+            # The window layers' rings belong to the slots, not to the
+            # requests: a static population beside the block pool's pages.
+            _memscope.set_static(
+                scope_name, _memscope.MEM_POOL_SCRATCH, "kv_window_rings",
+                (1 + max_slots * self._ring) * self._kind_bytes[1],
+                {"pages_a_slot": self._ring, "slots": max_slots},
+            )
         self._slot_req: List[Optional[_Request]] = [None] * max_slots
         self._req_seq = 0  # memscope owner tokens (guarded by _cv)
         self._admit: "queue.Queue" = queue.Queue()
@@ -978,13 +1024,41 @@ class GenerationEngine:
 
         atexit.register(lambda: (lambda e: e and e.shutdown())(ref()))
 
-    def _attention_bytes(self, ctx_pages: int, table_pages: int) -> int:
-        """KV bytes a dispatch's attention reads: the pages under its
-        lanes' lengths where the family's kernel reads the pages held (hit
-        pages too), every lane's and micro-step's whole table extent where
-        it gathers the table."""
-        return self._block_kv_bytes * (
-            ctx_pages if self._model.reads_pages_held else table_pages)
+    def _note_attention(self, scope, lanes, table_pages: int, slots):
+        """What a dispatch's attention reads, on its record. ``lanes``:
+        ``(context length, query rows)`` of each real lane (and micro-step).
+        ``ctx_pages``: the table entries under the lanes' lengths, what a
+        global layer's kernel visits. ``kv_bytes``: by kind, the pages the
+        kernel visits where the family's kernel reads the pages held (hit
+        pages too); every lane's and micro-step's whole table extent
+        (``table_pages``) where it gathers the table. And on a family with
+        window layers the split by kind: pages read, and bytes held by the
+        requests of ``slots``."""
+        bs, most = self.block_size, self._max_blocks
+        each = [self._model.pages_read(length, rows, bs)
+                for length, rows in lanes]
+        read = (sum(min(g, most) for g, _ in each),
+                sum(min(w, most) for _, w in each))
+        scope.ctx_pages = read[0]
+        scope.kv_bytes = (
+            sum(n * b for n, b in zip(read, self._kind_bytes))
+            if self._model.reads_pages_held
+            else self._block_kv_bytes * table_pages)
+        if not self._ring:
+            return
+        scope.ctx_pages_global, scope.ctx_pages_window = read
+        held = [self.held_bytes(self._slot_req[s].kv_pages_held)
+                for s in slots if self._slot_req[s] is not None]
+        scope.kv_held_global = sum(g for g, _ in held)
+        scope.kv_held_window = sum(w for _, w in held)
+
+    def held_bytes(self, n_pages: int) -> tuple:
+        """Bytes a request with ``n_pages`` block-table entries holds, by
+        kind ``(global, window)``: a window layer holds its ring's pages and
+        no more, whatever the request's length."""
+        return (n_pages * self._kind_bytes[0],
+                min(n_pages, self._ring) * self._kind_bytes[1])
+
 
     def _keep_pools(self, result, lead: int):  # tpulint: disable=TPU002,TPU009 - the pools change hands on the engine-loop thread only (warm-ups and take-down run on an idle or stopped engine)
         """Take the pools back from a step's result ``(lead arrays, *pools,
@@ -1151,7 +1225,7 @@ class GenerationEngine:
         gets one record a call (how many slots, and what the call cost
         this thread)."""
         began = time.monotonic_ns() if _stepscope.enabled() else 0
-        writes = np.zeros((self.max_slots, _W_ROW + self._max_blocks),
+        writes = np.zeros((self.max_slots, _W_ROW + self._table_width),
                           np.int32)
         writes[list(frees), _W_FREED] = 1
         for lane, slot, st in joins:
@@ -1159,13 +1233,25 @@ class GenerationEngine:
             writes[slot, :_W_ROW] = (
                 lane, 1, 0, st.prompt_len, req.seed, req.top_k,
                 np.float32(req.temperature).view(np.int32))
-            writes[slot, _W_ROW:_W_ROW + len(st.blocks)] = st.blocks
+            writes[slot, _W_ROW:] = self._table_row(
+                slot, st.blocks, self._max_blocks)
         (self._btabs, self._tokens, self._pos, self._seeds, self._steps,
          self._temps, self._topks) = self._update_slots(
             self._btabs, self._tokens, self._pos, self._seeds, self._steps,
             self._temps, self._topks, firsts, writes)
         _stepscope.slot_update(self._scope_name, len(joins), len(frees),
                                began, time.monotonic_ns())
+
+    def _table_row(self, slot: int, blocks, n_ctx: int) -> np.ndarray:
+        """A slot's table row as the family's steps take it: its ring's
+        entries (none where the family has no window layers), then the
+        first ``n_ctx`` entries of its block table."""
+        ring = self._ring
+        row = np.zeros((ring + n_ctx,), np.int32)
+        row[:ring] = 1 + slot * ring + np.arange(ring)
+        k_ctx = min(len(blocks), n_ctx)
+        row[ring:ring + k_ctx] = blocks[:k_ctx]
+        return row
 
     def _alloc_block(self) -> Optional[int]:
         """A free page, evicting the LRU zero-ref cached page if needed."""
@@ -1197,7 +1283,10 @@ class GenerationEngine:
         prompt_row = req.prompt[0]
         hashes: List[int] = []
         h = 0
-        for i in range((l - 1) // bs):
+        # A family that declines sharing matches nothing and, with no
+        # hashes, registers nothing when the prompt is in.
+        shared = (l - 1) // bs if self._model.shares_prefix else 0
+        for i in range(shared):
             h = _kvcache.block_hash(h, prompt_row[i * bs:(i + 1) * bs])
             hashes.append(h)
         blocks: List[int] = []
@@ -1250,9 +1339,8 @@ class GenerationEngine:
             # finalization, exactly like steps_completed in _deliver.
             try:
                 req.cancel_event.kv_pages_held = n_total
-                req.cancel_event.kv_bytes_held = (
-                    n_total * self._block_kv_bytes
-                )
+                req.cancel_event.kv_bytes_held = sum(
+                    self.held_bytes(n_total))
             except AttributeError:
                 pass
         st = _PrefillState(req, l, blocks, n_hit, hashes)
@@ -1463,8 +1551,9 @@ class GenerationEngine:
             needed = max(
                 needed, -(-(start + n_valid) // self.block_size)
             )
-        n_ctx = _pow2_bucket(needed, self._max_blocks)
-        btab_rows = np.zeros((kk, n_ctx), np.int32)
+        n_ctx = (_pow2_bucket(needed, self._max_blocks)
+                 if self._model.prefill_by_context else self._max_blocks)
+        btab_rows = np.zeros((kk, self._ring + n_ctx), np.int32)
         for i, (slot, st, start, n_valid) in enumerate(lanes):
             chunks[i, :n_valid] = st.req.prompt[0, start:start + n_valid]
             starts[i] = start
@@ -1472,8 +1561,7 @@ class GenerationEngine:
             seeds[i] = st.req.seed
             temps[i] = st.req.temperature
             topks[i] = st.req.top_k
-            k_ctx = min(len(st.blocks), n_ctx)
-            btab_rows[i, :k_ctx] = st.blocks[:k_ctx]
+            btab_rows[i] = self._table_row(slot, st.blocks, n_ctx)
         # No dispatch ticket for prefill chunks: admissions are bounded
         # by the slot count, and blocking a NEW request's prefill on a
         # step-readback ticket is the TTFT-under-load term.
@@ -1488,10 +1576,9 @@ class GenerationEngine:
             # tokens and in table entries.
             scope.tokens = sum(n for _, _, _, n in lanes)
             scope.ctx_tokens = sum(s + n for _, _, s, n in lanes)
-            scope.ctx_pages = sum(
-                -(-(s + n) // self.block_size) for _, _, s, n in lanes)
-            scope.kv_bytes = self._attention_bytes(scope.ctx_pages,
-                                                   kk * n_ctx)
+            self._note_attention(
+                scope, [(s + n, n) for _, _, s, n in lanes], kk * n_ctx,
+                active)
         self._prefill_seq += 1
         # One compile-cache entry per (lane, context) bucket: the key is
         # the traced-shape identity XLA uses, so the retrace counter and
@@ -1619,7 +1706,7 @@ class GenerationEngine:
             buckets = sorted(
                 {_pow2_bucket(max(1, int(b)), self._max_blocks)
                  for b in ctx_blocks}
-            )
+            ) if self._model.prefill_by_context else [self._max_blocks]
             kk = 1
             while True:
                 for n_ctx in buckets:
@@ -1627,7 +1714,7 @@ class GenerationEngine:
                     (firsts,), _ = self._keep_pools(self._prefill_chunk_fn(
                         self.params, *self._pools,
                         jnp.zeros((kk, c), jnp.int32),
-                        jnp.zeros((kk, n_ctx), jnp.int32),
+                        jnp.zeros((kk, self._ring + n_ctx), jnp.int32),
                         z, jnp.ones((kk,), jnp.int32), z,
                         jnp.zeros((kk,), jnp.float32), z,
                     ), 1)
@@ -1777,12 +1864,9 @@ class GenerationEngine:
                 scope.ctx_tokens = sum(held)
                 # Table entries under the active slots' lengths, every
                 # micro-step's: what a paged kernel visits.
-                scope.ctx_pages = sum(
-                    min(-(-(n + i) // self.block_size), self._max_blocks)
-                    for n in held for i in range(fuse))
-                scope.kv_bytes = self._attention_bytes(
-                    scope.ctx_pages,
-                    fuse * self.max_slots * self._max_blocks)
+                self._note_attention(
+                    scope, [(n + i, 1) for n in held for i in range(fuse)],
+                    fuse * self.max_slots * self._max_blocks, active)
             step_seq += fuse
             # Whole-bank decode traces one shape per fuse width: the
             # unfused branch is a single cache entry, the fused branch
@@ -1888,8 +1972,9 @@ class GptEngineModel(Model):
     def estimate_request_bytes(self, input_shapes):
         """KV page reservation this request will hold: the engine's
         admission formula ``ceil((prompt + max_new) / block_size)``
-        pages at block_kv_bytes each (max_new estimated at infer's
-        default of 16 — MAX_TOKENS data is not resolved at stamp time).
+        pages, reckoned by kind through the family (a window layer holds
+        its ring's pages and no more; max_new estimated at infer's default
+        of 16 — MAX_TOKENS data is not resolved at stamp time).
         """
         shape = input_shapes.get("INPUT_IDS")
         if not shape:
@@ -1897,7 +1982,7 @@ class GptEngineModel(Model):
         length = int(shape[-1])
         e = self.engine
         n = min(-(-(length + 16) // e.block_size), e._max_blocks)
-        return int(n * e._block_kv_bytes)
+        return int(sum(e.held_bytes(n)))
 
     def infer(self, inputs, parameters=None) -> Iterator[dict]:
         prompt = np.asarray(inputs["INPUT_IDS"], dtype=np.int32)

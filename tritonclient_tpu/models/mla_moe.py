@@ -33,7 +33,8 @@ sorted by expert: the TPU compiler lowers it to a grouped matrix product
 that visits the experts the tokens hit, so a decode step of 8 slots reads
 about 57 of a layer's 256 experts and not all of them. Rows that carry no
 request (idle slots, a chunk's padding) are given to no expert. Each step
-returns, beside its tokens, the per-layer histogram of tokens per expert;
+returns, beside its tokens, the per-layer histogram of tokens per expert
+(and, last, the pairs whose expert is held elsewhere: none here);
 the engine's delivery thread reads it when stepscope is on.
 """
 
@@ -84,6 +85,16 @@ class MlaMoeConfig:
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.n_dense_layers
+
+    # The share of the router's experts this program holds (``routed_experts``):
+    # all of them, from the first.
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts
+
+    @property
+    def first_expert(self) -> int:
+        return 0
 
     @property
     def latent_dim(self) -> int:
@@ -196,12 +207,15 @@ def _swiglu(x, w_gate, w_up, w_down):
     return _dot(jax.nn.silu(_dot(x, w_gate)) * _dot(x, w_up), w_down)
 
 
-def route(x, router, bias, cfg: MlaMoeConfig):
-    """x [T, d] -> (experts [T, k] int32, weights [T, k] float32).
+def route(x, router, bias, cfg):
+    """x [T, d] -> (experts [T, k] int32, weights [T, k] float32), over ALL
+    the experts the router knows (its width), held here or not.
 
     The scores are float32 whatever the model's type (a bfloat16 score
     moves tokens between experts); the choice is by ``s + b``, the weight
-    is ``s`` alone, normalised over the chosen and scaled."""
+    is ``s`` alone, normalised over the chosen and scaled. ``cfg`` is any
+    family's config with ``experts_per_token`` and
+    ``routed_scaling_factor``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=_HI))
     _, experts = lax.top_k(scores + bias.astype(jnp.float32),
@@ -211,41 +225,73 @@ def route(x, router, bias, cfg: MlaMoeConfig):
     return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
 
 
-def routed_experts(x, experts, weights, live, banks, cfg: MlaMoeConfig,
-                   layer=0):
-    """``sum_e w_e SwiGLU_e(x)`` over each token's chosen experts, as grouped
-    products over the (token, expert) pairs sorted by expert.
+def routed_experts(x, experts, weights, live, banks, cfg, layer=0):
+    """``sum_e w_e SwiGLU_e(x)`` over those of each token's chosen experts
+    that this program HOLDS, as grouped products over the (token, expert)
+    pairs sorted by expert.
+
+    The share: ``cfg.experts_held`` experts from ``cfg.first_expert`` on, of
+    the ``cfg.n_experts`` the router chooses among (one chip's share of an
+    expert-parallel layer; a program that holds them all is the case
+    ``first_expert`` 0, ``experts_held`` = ``n_experts``). A pair whose
+    expert lives elsewhere adds nothing here: the chip that holds it adds
+    its part, with the weight this chip would have given it (``weights``
+    are normalised over all the chosen, held or not). Nothing stands in for
+    the other chips or for the exchange.
 
     ``banks``: ``w_gate``/``w_up`` [G, d, f] and ``w_down`` [G, f, d] with
-    G = layers * E, EVERY expert layer's experts in one group axis, and
-    ``layer`` (traced) says whose turn it is: the groups of the other
-    layers are empty. Slicing one layer's [E, d, f] out of the stack
+    G = layers * held, EVERY expert layer's held experts in one group axis,
+    and ``layer`` (traced) says whose turn it is: the groups of the other
+    layers are empty. Slicing one layer's [held, d, f] out of the stack
     instead would copy it on its way into the product (measured on the
     v5e: 2.4 GB a layer, 29 ms of a 38 ms decode step).
 
-    ``live`` [T] bool: a row that carries no request is given to no expert
-    (its pairs sort past every group), so the product neither computes it
-    nor reads an expert for it. Returns (y [T, d], tokens per expert [E]).
+    ``live`` [T] bool: a row that carries no request is given to no expert.
+    Its pairs, like those of an expert held elsewhere, sort past every
+    group, so the product neither computes them nor reads an expert for
+    them. Returns (y [T, d], counts [held + 1]: tokens per held expert,
+    then the live pairs that fell elsewhere).
     """
     t, k = experts.shape
-    e = cfg.n_experts
+    held = cfg.experts_held
     groups = banks["w_gate"].shape[0]
-    flat = jnp.where(live[:, None], experts, e).reshape(t * k)
+    local = experts - cfg.first_expert
+    mine = (local >= 0) & (local < held)
+    flat = jnp.where(live[:, None] & mine, local, held).reshape(t * k)
     order = jnp.argsort(flat)                      # stable: pairs by expert
-    counts = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
+    counts = jnp.zeros((held,), jnp.int32).at[flat].add(1, mode="drop")
+    elsewhere = jnp.sum(live[:, None] & ~mine, dtype=jnp.int32)
     sizes = lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32), counts,
-                                     (layer * e,))
+                                     (layer * held,))
     rows = x[order // k]                           # [T * k, d]
     grouped = functools.partial(lax.ragged_dot, group_sizes=sizes,
                                 preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(grouped(rows, banks["w_gate"]))
               * grouped(rows, banks["w_up"])).astype(x.dtype)
     y = grouped(hidden, banks["w_down"])           # [T * k, d] float32
-    kept = (flat[order] < e)[:, None]              # rows past the groups
+    kept = (flat[order] < held)[:, None]           # rows past the groups
     y = jnp.where(kept, y * weights.reshape(t * k)[order][:, None], 0.0)
     # Back to token order: each token's k rows, summed.
     y = y[jnp.argsort(order)].reshape(t, k, -1).sum(axis=1)
-    return y.astype(x.dtype), counts
+    return y.astype(x.dtype), jnp.append(counts, elsewhere)
+
+
+def routing_counters(histograms, n_layers: int, held: int, k: int) -> dict:
+    """A dispatch's routing counters (stepscope ``ROUTING_FIELDS``) from the
+    histograms its expert layers returned (``routed_experts``' counts,
+    ``[n_layers, held + 1]`` or one such per micro-step)."""
+    counts = np.asarray(histograms).reshape(-1, n_layers, held + 1)
+    mine, elsewhere = counts[..., :held], counts[..., held]
+    # Every expert layer routes the same rows: count them at the first.
+    routed = int(mine[:, 0].sum() + elsewhere[:, 0].sum()) // k
+    return {
+        "routed_tokens": routed,
+        "experts_hit": int((mine > 0).sum()),
+        "experts_held": int(mine.size),
+        "expert_load_max": int(mine.max()),
+        "expert_load_mean": float(mine.mean()),
+        "pairs_elsewhere": int(elsewhere.sum()),
+    }
 
 
 _EXPERT_BANKS = ("w_gate", "w_up", "w_down")
@@ -316,7 +362,7 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
     attended by R consecutive rows; ``dest``/``off``/``positions``/``live``
     are per row. A layer writes its N latent rows at ``(layer, page,
     offset)`` and gathers ``pool[layer, btabs]``. Returns (h, pool, tokens
-    per expert [n_moe_layers, E]).
+    per expert [n_moe_layers, E + 1]).
     """
     n_tables, n_ctx = btabs.shape
     n = x.shape[0]
@@ -400,7 +446,7 @@ def _decode_step_latent(params: Dict, pool, btabs, tokens, pos, seeds, steps,
     A slot whose table starts at the scratch page (0) holds no request: it
     still advances, its latent lands on the scratch page, and it is routed
     to no expert. Returns (next tokens [S], pool, tokens per expert
-    [n_moe_layers, E])."""
+    [n_moe_layers, E + 1])."""
     s_count, max_blocks = btabs.shape
     l_eff = max_blocks * block_size
     x = params["embed"]["tok"][tokens]
@@ -419,7 +465,7 @@ def _decode_multi_step_latent(params: Dict, pool, btabs, tokens, pos, seeds,
                               block_size: int, n_steps: int):
     """``n_steps`` micro-steps in one dispatch: a scan over the single step
     (``gpt_engine._decode_multi_step_paged``). The histogram comes back per
-    micro-step, [n_steps, n_moe_layers, E]."""
+    micro-step, [n_steps, n_moe_layers, E + 1]."""
 
     def one(carry, _):
         tokens, pos, steps, pool = carry
@@ -440,7 +486,7 @@ def _prefill_chunk_latent(params: Dict, pool, chunks, btabs, starts,
     latents written into the pages of ``btabs`` [K, n_ctx]; the arguments
     and the causality-by-position are ``gpt_engine._prefill_chunk_paged``'s.
     Pad rows and pad lanes write to the scratch page and reach no expert.
-    Returns (first tokens [K], pool, tokens per expert [n_moe_layers, E])."""
+    Returns (first tokens [K], pool, tokens per expert [n_moe_layers, E + 1])."""
     kk, c = chunks.shape
     n_ctx = btabs.shape[1]
     l_eff = n_ctx * block_size
@@ -528,21 +574,12 @@ class MlaMoePaged(PagedModel):
         return mla_moe_prefill_chunk
 
     def routing(self, extras) -> Optional[dict]:
-        """A dispatch's routing counters from the histogram it returned
-        (``[n_moe_layers, E]``, or one such per micro-step): read on the
-        engine's delivery thread, for stepscope's dispatch record."""
+        """A dispatch's routing counters from the histogram it returned:
+        read on the engine's delivery thread, for stepscope's dispatch
+        record."""
         cfg = self.cfg
-        counts = np.asarray(extras[0]).reshape(
-            -1, cfg.n_moe_layers, cfg.n_experts)      # micro-step first
-        # Every expert layer routes the same rows: count them at the first.
-        routed = int(counts[:, 0].sum()) // cfg.experts_per_token
-        return {
-            "routed_tokens": routed,
-            "experts_hit": int((counts > 0).sum()),
-            "experts_held": int(counts.size),
-            "expert_load_max": int(counts.max()),
-            "expert_load_mean": float(counts.mean()),
-        }
+        return routing_counters(extras[0], cfg.n_moe_layers,
+                                cfg.experts_held, cfg.experts_per_token)
 
 
 class MlaMoeEngineModel(GptEngineModel):

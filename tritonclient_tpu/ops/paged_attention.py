@@ -40,10 +40,31 @@ One kernel serves decode (one query row a table) and the prefill chunk
 (``rows_per_table`` rows a table, each with its own length: causality
 among a chunk's rows is ``key position < row length``). Off the TPU it
 runs under the Pallas interpreter; there is no second implementation.
+
+Two things a call may add, and a call that adds neither lowers as it did
+before they existed:
+
+* GROUPED query heads: the pools hold ``H_kv`` heads and ``q`` has ``group``
+  times as many, query head *i* reading K/V head ``i // group``. A K/V
+  head's columns are one column group whatever reads it, so the ``group``
+  query heads of a K/V head go in as ``group`` row blocks over the same
+  columns: to the kernel a table then has ``group x rows`` rows and ``H_kv``
+  heads, and nothing in it knows of grouping.
+* a WINDOW: row *n* attends positions ``[lengths[n] - window, lengths[n])``
+  only. The plan starts a table's chunks at the one that holds the earliest
+  such position of its rows, so the grid holds the window's pages and not
+  the context's; inside the kernel a key is live if it lies at or above the
+  row's lower bound too. Entries of the table below that chunk are never
+  read, so they may point at pages that hold later positions (a ring).
+
+A table of many rows (a long prefill chunk, times the group) is cut into
+TILES of at most ``_TILE_ROWS`` rows, each a table of its own to the plan
+and the kernel: the buffers in fast memory keep one size, and a tile of
+earlier rows visits the pages under its own rows alone.
 """
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +77,7 @@ from jax.sharding import PartitionSpec as P
 _NEG_BIG = -1e30      # a masked score; finite, so no inf - inf
 _LANES = 128
 _ROW_TILE = 16        # query rows a table are padded to whole bf16 tiles
+_TILE_ROWS = 512      # most rows (group x positions) the kernel takes a table
 
 
 def _round_up(n: int, m: int) -> int:
@@ -94,8 +116,10 @@ def _dot_parts(a, b, contract_b: int, n_parts: int):
 
 def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
             lens_ref, *refs, head_dim: int, heads_per_group: int,
-            pages_per_chunk: int, n_parts: int):
+            pages_per_chunk: int, n_parts: int, windowed: bool = False):
     g, ppc = heads_per_group, pages_per_chunk
+    if windowed:
+        lows_ref, *refs = refs
     k_pages, v_pages = refs[:ppc], refs[ppc:2 * ppc]
     o_ref, kbuf, vbuf, qpad, qparts, m_ref, l_ref, acc_ref = refs[2 * ppc:]
     step = pl.program_id(0)
@@ -130,7 +154,15 @@ def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
         return lax.shift_right_logical(
             col, int(np.log2(head_dim))) & (g - 1)
 
-    @pl.when(chunk == 0)
+    if windowed:
+        # A windowed table's first chunk is the one that holds its rows'
+        # earliest key, not chunk 0: the step before is another table's.
+        first = (step == 0) | (
+            table_ref[step] != table_ref[jnp.maximum(step - 1, 0)])
+    else:
+        first = chunk == 0
+
+    @pl.when(first)
     def _():
         # The table's query rows, scaled, one copy a head of a group and
         # each zero outside its head's columns, in parts of the pool's type.
@@ -159,6 +191,14 @@ def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
     lens = lens_ref[...]                                   # [rp, 1]
     live = position < (lens if g == 1
                        else jnp.concatenate([lens] * g, axis=0))
+    if windowed:
+        # A row whose window lies wholly outside this chunk sees no live
+        # key here: what that adds to its sum is multiplied by exp(-1e30 -
+        # m) = 0 at the row's first live key, which every row has (its own
+        # position), and after it a dead chunk adds exp(-1e30 - m) = 0.
+        lows = lows_ref[...]
+        live &= position >= (lows if g == 1
+                             else jnp.concatenate([lows] * g, axis=0))
 
     def attend(c, cols, _):
         s = _dot_parts(qparts[:, cols], kbuf[:, cols], 1, n_parts)
@@ -190,13 +230,15 @@ def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
 
 class PagePlan(NamedTuple):
     """What a bank's block tables and lengths say of the kernel's grid. It
-    is the same for every layer of a step: make it once (``plan_pages``),
-    outside the layer scan, whose body XLA does not hoist it from. The
-    grid is the bank's live chunks, table after table, and no more; every
-    map runs one entry past the longest grid there can be, and an entry
-    past the grid's end repeats the last step's: the pipeline works out a
-    step's block indices one step ahead, past the last step too (without
-    the entry a two-table prefill halted the core on the chip)."""
+    is the same for every layer of a step (of one kind: a window layer's is
+    its own): make it once (``plan_pages``), outside the layer scan, whose
+    body XLA does not hoist it from. The grid is the bank's live chunks,
+    table after table, and no more; every map runs one entry past the
+    longest grid there can be, and an entry past the grid's end repeats the
+    last step's: the pipeline works out a step's block indices one step
+    ahead, past the last step too (without the entry a two-table prefill
+    halted the core on the chip). Where a table's rows were cut into tiles,
+    "table" below is a tile."""
 
     n_steps: jax.Array    # []: the bank's live chunks, the kernel's grid
     page_of: jax.Array    # [pages a chunk, steps]: grid step -> pool pages
@@ -204,23 +246,41 @@ class PagePlan(NamedTuple):
     chunk_of: jax.Array   # [steps]: ... -> chunk of that table
     last_of: jax.Array    # [steps]: 1 on a table's last chunk
     lens: jax.Array       # [T, padded rows, 1]: each row's length
+    lows: Optional[jax.Array] = None   # windowed: each row's first live key
 
 
 def _pages_per_chunk(block_size: int, n_ctx: int) -> int:
     return max(1, min(_LANES // block_size, n_ctx))
 
 
-def plan_pages(btabs, lengths, *, rows_per_table: int = 1,
-               block_size: int) -> PagePlan:
+def _tile_positions(rows_per_table: int, group: int) -> int:
+    """Positions a tile: the most that divide a table's rows and keep a
+    tile's ``group x positions`` rows within ``_TILE_ROWS``."""
+    most = max(_TILE_ROWS // group, 1)
+    return next(t for t in range(min(rows_per_table, most), 0, -1)
+                if rows_per_table % t == 0)
+
+
+def plan_pages(btabs, lengths, *, rows_per_table: int = 1, block_size: int,
+               group: int = 1, window: Optional[int] = None) -> PagePlan:
     """The plan for tables ``btabs`` [T, n_ctx] whose N = T *
-    ``rows_per_table`` rows attend positions ``[0, lengths[n])``."""
-    n_tables, n_ctx = btabs.shape
-    r, rp = rows_per_table, _round_up(rows_per_table, _ROW_TILE)
+    ``rows_per_table`` rows attend positions ``[0, lengths[n])``, or with a
+    ``window`` its last ``window`` positions; each row stands for ``group``
+    query heads a K/V head."""
+    n_ctx = btabs.shape[1]
     ppc = _pages_per_chunk(block_size, n_ctx)
     lens = jnp.clip(lengths.astype(jnp.int32), 1, n_ctx * block_size)
-    lens = lens.reshape(n_tables, r)
+    tile = _tile_positions(rows_per_table, group)
+    if tile < rows_per_table:
+        btabs = jnp.repeat(btabs, rows_per_table // tile, axis=0)
+    n_tables = btabs.shape[0]
+    lens = lens.reshape(n_tables, tile)
     n_pages = -(-lens.max(axis=1) // block_size)   # entries under the longest
     n_chunks = -(-n_pages // ppc)
+    if window is not None:
+        lows = jnp.maximum(lens - window, 0)
+        first_chunk = lows.min(axis=1) // (block_size * ppc)
+        n_chunks = n_chunks - first_chunk
     ends = jnp.cumsum(n_chunks)
     steps = jnp.minimum(
         jnp.arange(n_tables * -(-n_ctx // ppc) + 1, dtype=jnp.int32),
@@ -229,6 +289,8 @@ def plan_pages(btabs, lengths, *, rows_per_table: int = 1,
     table_of = before.sum(axis=1).astype(jnp.int32)
     chunk_of = (steps - (before * n_chunks[None, :]).sum(axis=1)
                 ).astype(jnp.int32)
+    if window is not None:
+        chunk_of = chunk_of + first_chunk[table_of]
     # Operand i of a step is its table's entry chunk * ppc + i. Past the
     # table's last live entry it stays on the last live one it had (or,
     # where it had none, on the table's last live page), so a dead page is
@@ -241,18 +303,30 @@ def plan_pages(btabs, lengths, *, rows_per_table: int = 1,
     entry = jnp.minimum(chunk_of[None, :] * ppc + i, stay)
     page_of = btabs.astype(jnp.int32)[table_of[None, :], entry]
     last_of = ((chunk_of + 1) * ppc >= live).astype(jnp.int32)
-    # Pad rows attend position 0 only.
-    lens = jnp.pad(lens, ((0, 0), (0, rp - r)), constant_values=1)
+    # The rows of a table, once a query head of its group; pad rows attend
+    # position 0 only (none, under a window that starts past it).
+    rows = group * tile
+    pad = ((0, 0), (0, _round_up(rows, _ROW_TILE) - rows))
+    if group > 1:
+        lens = jnp.tile(lens, (1, group))
+    lens = jnp.pad(lens, pad, constant_values=1)
+    if window is None:
+        return PagePlan(ends[-1], page_of, table_of, chunk_of, last_of,
+                        lens[..., None])
+    lows = jnp.pad(jnp.tile(lows, (1, group)), pad)
     return PagePlan(ends[-1], page_of, table_of, chunk_of, last_of,
-                    lens[..., None])
+                    lens[..., None], lows[..., None])
 
 
-def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan, *,
-                     rows_per_table: int):
-    n, n_heads, head_dim = q.shape
+def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan):
+    n, q_heads, head_dim = q.shape
     _, _, bs, hd = k_pool.shape
-    r = rows_per_table
-    n_tables = n // r
+    n_heads = hd // head_dim                  # K/V heads
+    group = q_heads // n_heads                # query heads a K/V head
+    n_tables = plan.lens.shape[0]             # tiles, where tables were cut
+    tile = n // n_tables                      # positions a tile
+    r = group * tile                          # rows a table, to the kernel
+    windowed = plan.lows is not None
     # As many whole heads as fill a lane tile make one column group.
     g = _LANES // head_dim if _LANES % head_dim == 0 else 1
     g = max(1, min(g, n_heads))
@@ -274,6 +348,11 @@ def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan, *,
             lambda step, layer_ref, page_ref, table_ref, *_: (
                 table_ref[step], 0, 0))
 
+    if group > 1:
+        # [tile, position, K/V head, query head of it, Dh] -> a row block a
+        # query head of the group, over its K/V head's columns.
+        q = q.reshape(n_tables, tile, n_heads, group, head_dim).transpose(
+            0, 3, 1, 2, 4)
     pool_item = k_pool.dtype.itemsize
     hd_pad = _round_up(hd, _LANES)
     vmem = (6 * ppc * bs * hd_pad * pool_item        # pages (x 2) and buffers
@@ -283,13 +362,13 @@ def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan, *,
             + 12 * n_parts * m_rows * max(ppc * bs, _LANES) * 4)
     kernel = functools.partial(
         _kernel, head_dim=head_dim, heads_per_group=g, pages_per_chunk=ppc,
-        n_parts=n_parts)
+        n_parts=n_parts, **({"windowed": True} if windowed else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(plan.n_steps,),
-            in_specs=([table(r, hd), table(rp, 1)]
+            in_specs=([table(r, hd)] + [table(rp, 1)] * (1 + windowed)
                       + [page(i) for i in range(ppc)] * 2),
             out_specs=table(r, hd),
             scratch_shapes=[
@@ -311,47 +390,57 @@ def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan, *,
         name="paged_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), plan.page_of, plan.table_of,
       plan.chunk_of, plan.last_of, q.reshape(n_tables, r, hd), plan.lens,
+      *([plan.lows] if windowed else []),
       *([k_pool] * ppc), *([v_pool] * ppc))
-    return out.reshape(n, n_heads, head_dim)
+    if group > 1:
+        out = out.reshape(n_tables, group, tile, n_heads, head_dim).transpose(
+            0, 2, 3, 1, 4)
+    return out.reshape(n, q_heads, head_dim)
 
 
 def paged_attention(q, k_pool, v_pool, layer, btabs, lengths, *,
-                    rows_per_table: int = 1, mesh=None, axis: str = "tp"):
+                    rows_per_table: int = 1, window: Optional[int] = None,
+                    mesh=None, axis: str = "tp"):
     """Attention of ``q`` [N, H, Dh] over the pages its table holds →
     [N, H, Dh] float32.
 
     ``k_pool`` / ``v_pool`` are the whole pools ``[n_layers, n_blocks,
-    block_size, H * Dh]`` and ``layer`` the (traced) index of the layer to
-    read. ``btabs`` [T, n_ctx] int32 are the block tables, each attended by
-    ``rows_per_table`` consecutive rows of ``q`` (N = T * rows_per_table);
-    row *n* attends positions ``[0, lengths[n])`` of its table, at least
-    one and at most the table's extent. Table entries past a table's
-    longest row are never read. ``lengths`` is the [N] int32 array, or the
-    ``PagePlan`` made from it (``plan_pages``) by a caller that attends
-    the same tables in many layers.
+    block_size, H_kv * Dh]`` and ``layer`` the (traced) index of the layer
+    to read; ``H`` is ``H_kv`` or a whole multiple of it (grouped query
+    heads: head *i* reads K/V head ``i // (H / H_kv)``). ``btabs`` [T,
+    n_ctx] int32 are the block tables, each attended by ``rows_per_table``
+    consecutive rows of ``q`` (N = T * rows_per_table); row *n* attends
+    positions ``[0, lengths[n])`` of its table, at least one and at most the
+    table's extent, or with a ``window`` the last ``window`` of them. Table
+    entries past a table's longest row are never read, nor those below the
+    chunk that holds its rows' earliest window. ``lengths`` is the [N] int32
+    array, or the ``PagePlan`` made from it (``plan_pages``, given the same
+    grouping and window) by a caller that attends the same tables in many
+    layers.
 
     With a ``mesh`` that has ``axis``, the pools' flat axis and the heads
     of ``q`` are taken to be sharded on it, heads whole a shard: each
     shard runs the kernel on its own heads (the head size comes from
     ``q``'s shape, the number of heads from the shard's).
     """
+    kv_heads, rest = divmod(k_pool.shape[3], q.shape[2])
     if (q.shape[0] != btabs.shape[0] * rows_per_table
-            or k_pool.shape[3] != q.shape[1] * q.shape[2]
+            or rest or kv_heads == 0 or q.shape[1] % kv_heads
             or v_pool.shape != k_pool.shape):
         raise ValueError(
             f"q {q.shape} is not {btabs.shape[0]} tables x {rows_per_table} "
             f"rows over two pools {k_pool.shape}, {v_pool.shape}")
     plan = lengths if isinstance(lengths, PagePlan) else plan_pages(
         btabs, lengths, rows_per_table=rows_per_table,
-        block_size=k_pool.shape[2])
-    call = functools.partial(_paged_attention, rows_per_table=rows_per_table)
+        block_size=k_pool.shape[2], group=q.shape[1] // kv_heads,
+        window=window)
     if mesh is None or mesh.shape.get(axis, 1) <= 1:
-        return call(q, k_pool, v_pool, layer, plan)
+        return _paged_attention(q, k_pool, v_pool, layer, plan)
     pool = P(None, None, None, axis)
     return jax.shard_map(
-        call, mesh=mesh,
+        _paged_attention, mesh=mesh,
         in_specs=(P(None, axis, None), pool, pool, P(),
-                  PagePlan(*(P() for _ in plan))),
+                  PagePlan(*(None if f is None else P() for f in plan))),
         out_specs=P(None, axis, None),
         axis_names={axis}, check_vma=False,
     )(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), plan)
