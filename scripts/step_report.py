@@ -369,6 +369,27 @@ def _pages_read_share(recs: List[dict]) -> Optional[float]:
     return round(pages / table, 3)
 
 
+def _straight_share(recs: List[dict]) -> Optional[dict]:
+    """Of a phase's dispatches whose attention is the paged kernel
+    (``attn_straight`` on the record: which of the kernel's two bodies the
+    executable holds, a fact of its shapes), the share that ran the
+    straight-line body, by dispatches and by ``ctx_pages`` read. None where
+    no record carries the field (another kind of attention, an older dump,
+    a phase with no block table)."""
+    told = [r for r in recs if "attn_straight" in r]
+    if not told:
+        return None
+    straight = [r for r in told if r["attn_straight"]]
+    pages = sum(int(r.get("ctx_pages", 0)) for r in told)
+    return {
+        "n": len(told),
+        "dispatches": round(len(straight) / len(told), 3),
+        "ctx_pages": (round(sum(int(r.get("ctx_pages", 0))
+                                for r in straight) / pages, 3)
+                      if pages else None),
+    }
+
+
 def _dash(value) -> str:
     return "-" if value is None else str(value)
 
@@ -456,6 +477,9 @@ def analyze(records: List[dict],
             routing = _routing(ph)
             if routing is not None:
                 phases[phase]["routing"] = routing
+            straight = _straight_share(ph)
+            if straight is not None:
+                phases[phase]["attention"] = straight
         n = len(recs)
         means = _stage_means(recs)
         coll = sum(_coll_count(r.get("collectives")) for r in recs) / n
@@ -539,6 +563,18 @@ def render(analysis: dict) -> str:
                     f"each, {100 * routing['experts_hit_share']:.1f}% of "
                     f"the experts held hit, load max/mean "
                     f"{routing['load_max_over_mean']}"
+                )
+        # Which body of the paged-attention kernel a phase's executables
+        # hold: few-row tables (decode) take the straight-line one.
+        for phase, ph in m["phases"].items():
+            cell = ph.get("attention")
+            if cell:
+                of_pages = ("-" if cell["ctx_pages"] is None
+                            else f"{100 * cell['ctx_pages']:.1f}%")
+                lines.append(
+                    f"  attention {phase:<12} {cell['n']:>6} dispatches, "
+                    f"{100 * cell['dispatches']:.1f}% on the kernel's "
+                    f"straight-line body, {of_pages} of the pages read"
                 )
         # The delivery thread's view of each dispatch's result (ms): a long
         # queue wait says items stand behind one another, a long readback
@@ -885,7 +921,10 @@ def self_check() -> int:
     for r in dump["records"]:
         r["tokens"], r["ctx_tokens"] = 4, 400
         if r["phase"] == "decode":      # 8 lanes x 64 entries, 128 live
-            r.update(lanes=8, ctx_blocks=64, ctx_pages=128)
+            r.update(lanes=8, ctx_blocks=64, ctx_pages=128,
+                     attn_straight=True)
+        if r["phase"] == "prefill_chunk":   # the kernel's looped body
+            r.update(ctx_pages=32, attn_straight=False)
         if r["phase"] == "decode":      # a routed family's counters
             r.update(routed_tokens=4, experts_hit=24, experts_held=64,
                      expert_load_max=2, expert_load_mean=0.5)
@@ -918,6 +957,15 @@ def self_check() -> int:
             or m["phases"]["decode"]["ctx_tokens_per_step"] != 400
             or m["phases"]["decode"]["pages_read_share"] != 0.25
             or "pages/table" not in rendered
+            or m["phases"]["decode"].get("attention") != {
+                "n": m["phases"]["decode"]["n"], "dispatches": 1.0,
+                "ctx_pages": 1.0}
+            or m["phases"]["prefill_chunk"].get("attention") != {
+                "n": m["phases"]["prefill_chunk"]["n"], "dispatches": 0.0,
+                "ctx_pages": 0.0}
+            or "attention" in m["phases"]["prefill"]
+            or "attention decode" not in rendered
+            or "100.0% on the kernel's straight-line body" not in rendered
             or m["phases"]["decode"].get("routing") != {
                 "n": m["phases"]["decode"]["n"],
                 "routed_tokens_per_step": 4.0, "experts_hit_share": 0.375,
@@ -936,7 +984,8 @@ def self_check() -> int:
             or "delivery decode" not in rendered
             or "worst_gap" not in rendered or "median" not in rendered):
         print("self-check [counters]: device clock invented, or loop "
-              "states / deliveries / slot updates / requests / routing lost",
+              "states / deliveries / slot updates / requests / routing / "
+              "the attention body lost",
               file=sys.stderr)
         failures += 1
     else:

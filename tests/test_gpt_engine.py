@@ -1133,6 +1133,39 @@ def test_v5e_compiler_moves_no_pool_and_allocates_none(
     layer_pool_bytes = (n_blocks * block_size * heads * head_dim
                         * np.dtype(k_pool.dtype).itemsize)
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
+    # The decode step's kernel is the straight-line body (one row a table,
+    # every head's row in one product: 25 x 64 is a 1,600-wide contraction,
+    # twelve and a half lane tiles), which Mosaic has just taken.
+    from tritonclient_tpu.ops.paged_attention import straight_line
+
+    if program == "decode":
+        assert straight_line(1, heads, heads)
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["global", "window"])
+def test_v5e_compiler_takes_the_grouped_decode_kernel(
+        v5e_chip, compile_cache_off, monkeypatch, window):
+    """K-EXAONE's decode call, 64 query heads on 8 K/V heads of 128 over a
+    16,384-position table, global and under the window of 128, compiles for
+    the v5e on the body the rule gives it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    import jax.numpy as jnp
+
+    from tritonclient_tpu.ops.paged_attention import paged_attention
+
+    def on_chip(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    slots, n_ctx = 8, 1024
+    pool = on_chip(jnp.bfloat16, 1, 2 * n_ctx + 1, 16, 8 * 128)
+    compiled = jax.jit(
+        lambda q, k, v, btabs, lengths: paged_attention(
+            q, k, v, 0, btabs, lengths, window=window)
+    ).lower(on_chip(jnp.bfloat16, slots, 64, 128), pool, pool,
+            on_chip(jnp.int32, slots, n_ctx), on_chip(jnp.int32, slots)
+            ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])

@@ -13,7 +13,27 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from tritonclient_tpu.models.gpt import _masked_cache_attention
-from tritonclient_tpu.ops.paged_attention import paged_attention
+from tritonclient_tpu.ops.paged_attention import paged_attention, straight_line
+
+_KERNEL = sys.modules[paged_attention.__module__]
+
+
+@pytest.fixture(params=["ruled", "looped"])
+def body(request, monkeypatch):
+    """Which body of the kernel a case runs: the one the rule gives its
+    shapes (``straight_line``), or the looped one whatever they are (the
+    straight-line body then takes no rows at all). Both are one algorithm,
+    so every few-row shape is held to the reference on each. Call the
+    fixture's value with the case's shapes before the kernel."""
+    def choose(rows, q_heads, kv_heads):
+        if request.param == "ruled":
+            return
+        if not straight_line(rows, q_heads, kv_heads):
+            pytest.skip("the rule gives these shapes the looped body already")
+        monkeypatch.setattr(_KERNEL, "_STRAIGHT_ROWS", 0)
+
+    return choose
+
 
 _BS, _N_CTX, _LAYERS, _BLOCKS = 16, 6, 3, 40
 _NAN_PAGE = _BLOCKS - 1
@@ -70,18 +90,22 @@ def _reference(q, k_pool, v_pool, layer, btabs, lengths, rows):
         return _masked_cache_attention(q, view(k_pool), view(v_pool), mask)
 
 
-@pytest.mark.parametrize("rows", [1, 8])
+@pytest.mark.parametrize("rows", [1, 8, 32])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("heads,head_dim", [(25, 64), (16, 128), (4, 32)])
 def test_kernel_is_the_masked_einsum_over_the_pages_held(
-        heads, head_dim, dtype, rows):
+        heads, head_dim, dtype, rows, body):
     """Lengths 1, 15, 16, 17 and the full table in one bank, an idle slot
     on the scratch page, rows of one table with lengths of their own: the
     kernel's float32 result is the einsum's to 1e-5. A bfloat16 pool is
     read as stored by both, so it agrees as closely (the stored values'
     rounding is in both). No page past a table's longest row reaches the
-    result: those entries point at NaN."""
+    result: those entries point at NaN. One row a table (decode) takes the
+    straight-line body at every head shape, 8 and 32 rows (a prefill
+    chunk) the looped one but for four heads of 32 at 8 rows; each few-row
+    shape is run on the looped body too."""
+    body(rows, heads, heads)
     q, k_pool, v_pool, btabs, lengths = _bank(heads, head_dim, dtype, rows)
     layer = 1
     got = jax.jit(
@@ -182,25 +206,27 @@ def _grouped_reference(q, k_pool, v_pool, layer, btabs, lengths, rows,
                          ids=["global", "window24", "window128"])
 @pytest.mark.parametrize("kv_heads,group,head_dim,dtype,rows", [
     (2, 4, 16, jnp.float32, 1), (2, 4, 16, jnp.float32, 8),
-    (1, 8, 128, jnp.bfloat16, 1), (2, 4, 64, jnp.bfloat16, 8)],
-    ids=["2x4x16_decode", "2x4x16_chunk", "1x8x128_decode", "2x4x64_chunk"])
+    (1, 8, 128, jnp.bfloat16, 1), (2, 4, 64, jnp.bfloat16, 8),
+    (8, 8, 128, jnp.bfloat16, 1)],
+    ids=["2x4x16_decode", "2x4x16_chunk", "1x8x128_decode", "2x4x64_chunk",
+         "8x8x128_decode"])
 def test_grouped_and_windowed_kernel_is_the_masked_einsum(
         kv_heads, group, head_dim, dtype, rows, window, tile_rows,
-        monkeypatch):
+        monkeypatch, body):
     """Fewer K/V heads than query heads (a K/V head's columns read by
     ``group`` row blocks), rows that see their last ``window`` keys only
     (the grid starts at the chunk that holds the earliest of them: tables of
     40 to 192 positions under windows of 24 and 128), and a table's rows
     cut into tiles of 16 (each a table of its own to the kernel): the
     result is the masked einsum's to 1e-5, in float32 and over a bfloat16
-    pool read as stored."""
-    from tritonclient_tpu.ops import paged_attention as module
-
+    pool read as stored. ``8x8x128`` is K-EXAONE's decode call: 8 K/V heads
+    each read by 8 query heads. Every few-row shape runs the straight-line
+    body and the looped one."""
     if tile_rows is not None:
         if rows * group <= tile_rows:
             pytest.skip("one tile either way")
-        monkeypatch.setattr(sys.modules[module.__module__], "_TILE_ROWS",
-                            tile_rows)
+        monkeypatch.setattr(_KERNEL, "_TILE_ROWS", tile_rows)
+    body(rows, kv_heads * group, kv_heads)
     q, k_pool, v_pool, btabs, lengths = _grouped_bank(
         kv_heads, group, head_dim, dtype, rows)
     got = paged_attention(q, k_pool, v_pool, jnp.int32(1), btabs, lengths,
@@ -251,3 +277,82 @@ def test_a_full_multi_head_call_traces_as_it_did_before_grouping():
     # the grid's length, layer, 4 maps, q, lens, then the K and V operands
     # of a chunk's pages (the table's 6): no lows
     assert len(eqn.invars) == 1 + 5 + 2 + 2 * _N_CTX
+
+
+# --------------------------------------------------------------------------- #
+# which body a call takes: read off the traced program                        #
+# --------------------------------------------------------------------------- #
+
+
+def _kernel_equations(heads, head_dim, rows):
+    """``(equations, primitives)`` of the kernel a call of these shapes
+    traces, nested bodies (``cond``, the column groups' loop) counted in."""
+    q, k_pool, v_pool, btabs, lengths = _bank(heads, head_dim, jnp.bfloat16,
+                                              rows)
+    call = jax.make_jaxpr(
+        lambda *a: paged_attention(*a, rows_per_table=rows)
+    )(q, k_pool, v_pool, jnp.int32(0), btabs, lengths)
+    (eqn,) = [e for e in call.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+    def walk(jaxpr):
+        count, names = 0, set()
+        for e in jaxpr.eqns:
+            count, names = count + 1, names | {e.primitive.name}
+            for param in e.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n, more = walk(sub)
+                        count, names = count + n, names | more
+        return count, names
+
+    return walk(eqn.params["jaxpr"])
+
+
+def test_the_rule_is_read_off_the_shapes_and_the_traced_kernel_obeys_it():
+    """A one-row call's kernel holds no loop (every head's row goes through
+    one product), a 32-row call's holds the column groups' one; and the
+    exported rule says the same of every call the four configurations make:
+    the GPT cells' decode straight and their 32-row chunks looped, K-EXAONE's
+    512-row tiles looped; JoyAI's latent attention is no call of this
+    kernel, so its family answers None."""
+    from tritonclient_tpu.models import gpt, mla_moe, swa_moe
+    from tritonclient_tpu.models.gpt_engine import GptPaged
+
+    loops = {"while", "scan"}
+    for heads, head_dim in ((25, 64), (16, 128)):
+        _, one_row = _kernel_equations(heads, head_dim, 1)
+        _, chunk = _kernel_equations(heads, head_dim, 32)
+        assert not one_row & loops, one_row & loops
+        assert len(chunk & loops) == 1, chunk & loops
+        assert straight_line(1, heads, heads)
+        assert not straight_line(32, heads, heads)
+    # gpt2-xl, cerebras-gpt-1.3b: decode and the 32-row prefill chunk
+    for heads, head_dim in ((25, 64), (16, 128)):
+        family = GptPaged(gpt.GptConfig(
+            vocab_size=64, d_model=heads * head_dim, n_layers=1,
+            n_heads=heads, d_ff=64, max_len=64))
+        assert family.attends_straight(1) is True
+        assert family.attends_straight(32) is False
+    # k-exaone-236b-a23b: 64 query heads on 8 K/V heads of 128; a 512-row
+    # chunk is cut into tiles of 64 positions x 8 heads
+    exaone = swa_moe.SwaMoePaged(
+        swa_moe.SwaMoeConfig(n_heads=64, n_kv_heads=8, head_dim=128), 8, 512)
+    assert exaone.attends_straight(1) is True and straight_line(1, 64, 8)
+    assert exaone.attends_straight(512) is False
+    assert not straight_line(512, 64, 8)
+    # joyai-llm-flash: the latent pool is gathered, not read by this kernel
+    joyai = mla_moe.MlaMoePaged(mla_moe.mla_moe_tiny())
+    assert joyai.attends_straight(1) is None
+
+
+@pytest.mark.parametrize("heads,head_dim", [(25, 64), (16, 128)])
+def test_the_straight_body_stays_as_short_as_it_shipped(heads, head_dim):
+    """What a set-up pays for the straight-line body is tracing and lowering
+    it, once an executable: its kernel's equations (nested ones counted)
+    stay under one and a half times the count it shipped with, at both GPT
+    head shapes. (A count, not a time: five workers share the cores.)"""
+    shipped = 171       # both head shapes (PR 32); the looped body: 222, 114
+    count, _ = _kernel_equations(heads, head_dim, 1)
+    assert count <= 1.5 * shipped, (count, shipped)

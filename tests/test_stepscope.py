@@ -715,6 +715,52 @@ def test_dispatch_records_count_the_pages_under_the_lanes_lengths(lens):
             < r["ctx_tokens"] / bs + r["batch_size"]
 
 
+@pytest.mark.parametrize("mode", [_stepscope.MODE_COUNTERS,
+                                  _stepscope.MODE_OFF])
+def test_dispatch_records_say_which_body_of_the_paged_kernel_ran(mode):
+    """``attn_straight`` is a fact of the executable's shapes
+    (``ops.paged_attention.straight_line``): true on every decode record
+    (one query row a table, four heads), false on every chunk of 32 rows
+    (128 stacked rows), and ``step_report.py`` gives each phase its share
+    by dispatches and by pages read. With stepscope off there is no record
+    and nothing is stamped."""
+    from tritonclient_tpu.models.gpt_engine import GenerationEngine
+
+    _stepscope.configure(mode)
+    _stepscope.reset()
+    cfg = gpt.gpt_tiny(max_len=128)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    engine = GenerationEngine(cfg, params, max_slots=4, prefill_chunk=32)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, (1, n)).astype(np.int32)
+               for n in (40, 70)]
+    try:
+        _drain(engine, prompts, 5)
+    finally:
+        engine.shutdown()
+    doc = _stepscope.dump()
+    records = _dispatches(doc["records"])
+    if mode == _stepscope.MODE_OFF:
+        assert not records      # nothing to stamp
+        return
+    decode = [r for r in records if r["phase"] == _stepscope.PHASE_DECODE]
+    chunks = [r for r in records
+              if r["phase"] == _stepscope.PHASE_PREFILL_CHUNK]
+    assert decode and len(chunks) >= 3      # lanes may share a dispatch
+    assert all(r["attn_straight"] is True for r in decode)
+    assert all(r["attn_straight"] is False for r in chunks)
+    step_report = _load_script("step_report.py", "step_report_attn")
+    analysis = step_report.analyze(step_report.load_records(doc))
+    phases = analysis["models"]["gpt_engine"]["phases"]
+    assert phases["decode"]["attention"] == {
+        "n": len(decode), "dispatches": 1.0, "ctx_pages": 1.0}
+    assert phases["prefill_chunk"]["attention"] == {
+        "n": len(chunks), "dispatches": 0.0, "ctx_pages": 0.0}
+    rendered = step_report.render(analysis)
+    assert "attention decode" in rendered
+    assert "attention prefill_chunk" in rendered
+
+
 def test_loop_states_enter_the_ring_and_nothing_else():
     """ticket_wait / idle_wait / admit are records in the ring with the
     harness's fields, overlap neither a dispatch's bracket nor each other,

@@ -25,7 +25,10 @@ thread:
   layer's are its window's, not the context's) and
   ``kv_held_global_bytes`` / ``kv_held_window_bytes`` (what the dispatch's
   requests hold in the layers of each kind: the window layers' stops
-  growing at their ring);
+  growing at their ring); for a family whose attention is the paged
+  kernel, ``attn_straight``: whether this dispatch's executable holds the
+  kernel's straight-line body (few-row tables: decode) or its looped one
+  (``ops.paged_attention.straight_line``, a fact of the shapes);
 - ``dispatch_us``: host time from step begin to dispatch return (trace +
   XLA dispatch of the jitted call); the same bracket is a
   ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
@@ -188,7 +191,7 @@ class StepRecord:
         "micro_steps",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
         "ctx_pages_global", "ctx_pages_window", "kv_held_global",
-        "kv_held_window",
+        "kv_held_window", "attn_straight",
         "_annotation", "_entry",
     )
 
@@ -233,6 +236,9 @@ class StepRecord:
         # dispatch's requests hold in the layers of each kind.
         self.ctx_pages_window: Optional[int] = None
         self.ctx_pages_global = self.kv_held_global = self.kv_held_window = 0
+        # A family whose attention is the paged kernel only (set by the
+        # engine): which of the kernel's two bodies this executable holds.
+        self.attn_straight: Optional[bool] = None
         thread = threading.current_thread()
         self.thread_ident = thread.ident or 0
         self.thread_name = thread.name
@@ -269,6 +275,8 @@ class StepRecord:
         if self.device_us is not None:
             out["device_us"] = self.device_us
             out["other_us"] = self.other_us
+        if self.attn_straight is not None:
+            out["attn_straight"] = self.attn_straight
         if self.ctx_pages_window is not None:
             out.update(ctx_pages_global=self.ctx_pages_global,
                        ctx_pages_window=self.ctx_pages_window,

@@ -74,7 +74,11 @@ from tritonclient_tpu.models.gpt import (
     sampling_inputs,
     sampling_key,
 )
-from tritonclient_tpu.ops.paged_attention import paged_attention, plan_pages
+from tritonclient_tpu.ops.paged_attention import (
+    paged_attention,
+    plan_pages,
+    straight_line,
+)
 from tritonclient_tpu.protocol._literals import (
     PREFIX_EVENT_HIT,
     PREFIX_EVENT_MISS,
@@ -375,6 +379,13 @@ class PagedModel:
         table that end at context ``length``: ``(global, window)``."""
         return -(-length // block_size), 0
 
+    def attends_straight(self, rows: int) -> Optional[bool]:
+        """Whether the family's paged-attention kernel takes its
+        straight-line body for tables of ``rows`` query positions
+        (``ops.paged_attention.straight_line`` of the family's head
+        counts); None: its attention is no such kernel."""
+        return None
+
     def shard(self, mesh, params):
         """``(params laid out on the mesh, the pools' sharding)``."""
         raise NotImplementedError
@@ -420,6 +431,13 @@ class GptPaged(PagedModel):
             itemsize = 2  # bf16-family default
         return (cfg.n_layers * 2 * block_size * cfg.n_heads * cfg.head_dim
                 * itemsize)
+
+    def attends_straight(self, rows: int) -> bool:
+        cfg, mesh = self.cfg, self._mesh
+        # Heads are whole a shard: a shard's kernel sees its own.
+        heads = cfg.n_heads // (1 if mesh is None
+                                else int(dict(mesh.shape).get("tp", 1)))
+        return straight_line(rows, heads, heads)
 
     def shard(self, mesh, params):
         from tritonclient_tpu.models.gpt import PARTITION_RULES
@@ -1024,9 +1042,13 @@ class GenerationEngine:
 
         atexit.register(lambda: (lambda e: e and e.shutdown())(ref()))
 
-    def _note_attention(self, scope, lanes, table_pages: int, slots):
+    def _note_attention(self, scope, lanes, table_pages: int, slots,
+                        rows_per_table: int = 1):
         """What a dispatch's attention reads, on its record. ``lanes``:
-        ``(context length, query rows)`` of each real lane (and micro-step).
+        ``(context length, query rows)`` of each real lane (and micro-step);
+        ``rows_per_table``: the query positions a table of the executable
+        (the chunk's length; 1 in decode), from which ``attn_straight``:
+        which body of the paged kernel the executable holds.
         ``ctx_pages``: the table entries under the lanes' lengths, what a
         global layer's kernel visits. ``kv_bytes``: by kind, the pages the
         kernel visits where the family's kernel reads the pages held (hit
@@ -1044,6 +1066,7 @@ class GenerationEngine:
             sum(n * b for n, b in zip(read, self._kind_bytes))
             if self._model.reads_pages_held
             else self._block_kv_bytes * table_pages)
+        scope.attn_straight = self._model.attends_straight(rows_per_table)
         if not self._ring:
             return
         scope.ctx_pages_global, scope.ctx_pages_window = read
@@ -1578,7 +1601,7 @@ class GenerationEngine:
             scope.ctx_tokens = sum(s + n for _, _, s, n in lanes)
             self._note_attention(
                 scope, [(s + n, n) for _, _, s, n in lanes], kk * n_ctx,
-                active)
+                active, rows_per_table=c)
         self._prefill_seq += 1
         # One compile-cache entry per (lane, context) bucket: the key is
         # the traced-shape identity XLA uses, so the retrace counter and

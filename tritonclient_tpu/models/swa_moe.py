@@ -68,7 +68,11 @@ from tritonclient_tpu.models.mla_moe import (
     routed_experts,
     routing_counters,
 )
-from tritonclient_tpu.ops.paged_attention import paged_attention, plan_pages
+from tritonclient_tpu.ops.paged_attention import (
+    paged_attention,
+    plan_pages,
+    straight_line,
+)
 
 WINDOW, GLOBAL = "window", "global"
 
@@ -442,6 +446,10 @@ class SwaMoePaged(PagedModel):
         last = -(-length // block_size)
         first_key = max(length - rows + 1 - self.cfg.window, 0)
         return last, last - first_key // block_size
+
+    def attends_straight(self, rows: int) -> bool:
+        cfg = self.cfg
+        return straight_line(rows, cfg.n_heads, cfg.n_kv_heads)
 
     def _pages(self, block_size: int, pool) -> _Pages:
         return _Pages(self.cfg, pool.shape[1], self.max_slots,

@@ -21,13 +21,34 @@ they lie:
   context under a wide table costs what it holds and an idle slot (length
   1 on the scratch page) one step. In a table's last chunk an operand past
   the last live entry stays on the page it had, which is not copied again;
-* heads are column groups of the flat axis, taken inside the kernel: a
-  group is as many whole heads as fill 128 lanes (two of 64, one of 128,
-  four of 32), so every slice of the page buffer starts on a lane tile at
-  ``25 x 64`` and at ``16 x 128`` alike. A group's query rows are laid one
-  head under the other, each zero outside its own head's columns, so one
-  product with the group's key columns gives every head's scores, and the
-  cross-head blocks of the value product are masked off at the end;
+* heads are columns of the flat axis, told apart inside the kernel by
+  laying query rows one head under the other, each zero outside its own
+  head's columns: one product with the key columns then gives every such
+  head's scores (a zeroed column adds exactly 0 to a float32 sum), and the
+  cross-head blocks of the value product are masked off at the end. How
+  many heads go through one product is what the kernel's TWO BODIES differ
+  in, and the shapes decide (``straight_line``, the one rule; no option):
+
+  - a table of FEW rows (decode: one query row a table, times the group)
+    takes the STRAIGHT-LINE body: ALL the K/V heads' rows are stacked,
+    ``H_kv x rows`` of them (at most ``_STRAIGHT_ROWS``: 25 -> 32 for 25 x
+    64, 16 for 16 x 128, 64 for 8 K/V heads read by 8 query heads each),
+    and a grid step is one score product over the whole flat axis, one
+    softmax, one value product. With one row a table the rows that enter
+    the matrix unit are padding either way, so this costs it what the
+    column groups did (four times that at 64 rows) and drops everything
+    else a group paid: on the chip a decode call fell from 155 / 198 us
+    to 37 / 39 (gpt2-xl / cerebras contexts; PERF.md, PR 32), and the
+    traced kernel holds no loop;
+  - every other table (a prefill chunk's 32 rows, a 512-row tile) takes
+    the LOOPED body: a column group is as many whole heads as fill 128
+    lanes (two of 64, one of 128, four of 32), so every slice of the page
+    buffer starts on a lane tile at ``25 x 64`` and at ``16 x 128`` alike,
+    and the groups run one after the other in ONE traced loop body
+    whatever their number: stacking every head's 32 rows would multiply
+    the matrix unit's work by the number of heads, and unrolling the
+    groups costs tracing and lowering in each of a set-up's 24 prefill
+    executables;
 * keys and values enter the matrix unit as stored. A float32 left operand
   (the scaled query, the probabilities) goes in as three pool-typed parts
   that sum to it exactly, so each product with a bfloat16 page is exact and
@@ -78,6 +99,7 @@ _NEG_BIG = -1e30      # a masked score; finite, so no inf - inf
 _LANES = 128
 _ROW_TILE = 16        # query rows a table are padded to whole bf16 tiles
 _TILE_ROWS = 512      # most rows (group x positions) the kernel takes a table
+_STRAIGHT_ROWS = 64   # most rows, every head's, the straight-line body takes
 
 
 def _round_up(n: int, m: int) -> int:
@@ -114,19 +136,133 @@ def _dot_parts(a, b, contract_b: int, n_parts: int):
     return total
 
 
+def straight_line(rows_per_table: int, q_heads: int, kv_heads: int) -> bool:
+    """Whether a call whose tables have ``rows_per_table`` query positions
+    each and ``q_heads`` query heads over ``kv_heads`` K/V heads (a
+    shard's, under a mesh) takes the straight-line body (module docstring):
+    every K/V head's rows, one head under the other, are few enough for one
+    pass. A fact of the shapes and nothing else, whatever the head size:
+    the kernel asks it when traced, the engine's dispatch records say what
+    it answered."""
+    group = q_heads // kv_heads
+    rows = group * _tile_positions(rows_per_table, group)
+    return kv_heads * rows <= _STRAIGHT_ROWS
+
+
 def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
             lens_ref, *refs, head_dim: int, heads_per_group: int,
-            pages_per_chunk: int, n_parts: int, windowed: bool = False):
+            pages_per_chunk: int, n_parts: int, windowed: bool = False,
+            straight: bool = False):
     g, ppc = heads_per_group, pages_per_chunk
     if windowed:
         lows_ref, *refs = refs
     k_pages, v_pages = refs[:ppc], refs[ppc:2 * ppc]
-    o_ref, kbuf, vbuf, qpad, qparts, m_ref, l_ref, acc_ref = refs[2 * ppc:]
+    o_ref, kbuf, vbuf, qparts, m_ref, l_ref, acc_ref, *qpad = refs[2 * ppc:]
     step = pl.program_id(0)
     chunk = chunk_ref[step]
     bs = k_pages[0].shape[0]
     chunk_tokens, hd = kbuf.shape
-    r, rp = q_ref.shape[0], qpad.shape[0]     # query rows a table; padded
+    r = q_ref.shape[0]                        # query rows a table
+
+    if windowed:
+        # A windowed table's first chunk is the one that holds its rows'
+        # earliest key, not chunk 0: the step before is another table's.
+        first = (step == 0) | (
+            table_ref[step] != table_ref[jnp.maximum(step - 1, 0)])
+    else:
+        first = chunk == 0
+    last = last_ref[step] == 1
+
+    def start(x):
+        """A table's first chunk: its query rows ``x`` as they enter the
+        score product, in parts of the pool's type, and an empty sum."""
+        qparts[...] = _parts(x, qparts.dtype, n_parts)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # The chunk's pages side by side. A page past the table's last live
+    # one holds an earlier live page (the plan's clamp): finite, and masked
+    # below by its position.
+    for i in range(ppc):
+        kbuf[i * bs:(i + 1) * bs, :] = k_pages[i][...]
+        vbuf[i * bs:(i + 1) * bs, :] = v_pages[i][...]
+    position = chunk * chunk_tokens + lax.broadcasted_iota(
+        jnp.int32, (1, chunk_tokens), 1)
+
+    def live_keys(copies: int):
+        """[rows, chunk_tokens]: the keys of this chunk each row sees, the
+        rows ``copies`` times one under the other."""
+        def rows(ref):
+            return (ref[...] if copies == 1
+                    else jnp.concatenate([ref[...]] * copies, axis=0))
+
+        live = position < rows(lens_ref)
+        if windowed:
+            # A row whose window lies wholly outside this chunk sees no
+            # live key here: what that adds to its sum is multiplied by
+            # exp(-1e30 - m) = 0 at the row's first live key, which every
+            # row has (its own position), and after it a dead chunk adds
+            # exp(-1e30 - m) = 0.
+            live &= position >= rows(lows_ref)
+        return live
+
+    def attend(c, cols, live):
+        """One online-softmax update of the rows' running maximum, sum and
+        accumulator (entry ``c`` of the first two) with the chunk's keys
+        and values at ``cols``."""
+        s = _dot_parts(qparts[:, cols], kbuf[:, cols], 1, n_parts)
+        s = jnp.where(live, s, _NEG_BIG)
+        m_prev = m_ref[c]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[c] = alpha * l_ref[c] + p.sum(axis=1, keepdims=True)
+        acc_ref[:, cols] = alpha * acc_ref[:, cols] + _dot_parts(
+            _parts(p, vbuf.dtype, n_parts), vbuf[:, cols], 0, n_parts)
+        m_ref[c] = m_new
+
+    if straight:
+        # The straight-line body. EVERY head's rows one head under the
+        # other (row block h is head h's ``r`` rows), each zero outside its
+        # own head's columns: one product over the whole flat axis, one
+        # softmax, one value product, and at the end row block h keeps head
+        # h's columns. The lengths come in stacked the same way (the plan).
+        # A row past the last head's block is all zero and kept by no
+        # column.
+        big = acc_ref.shape[0]                # the stacked rows, padded
+        n_heads = hd // head_dim
+
+        def own_columns():
+            return (lax.broadcasted_iota(jnp.int32, (1, hd), 1) // head_dim
+                    == lax.broadcasted_iota(jnp.int32, (big, 1), 0) // r)
+
+        @pl.when(first)
+        def _():
+            x = q_ref[...].astype(jnp.float32) / np.sqrt(head_dim)
+            if r == 1:
+                x = jnp.broadcast_to(x, (big, hd))
+            else:
+                blocks = [x] * n_heads
+                if big > n_heads * r:
+                    blocks.append(
+                        jnp.zeros((big - n_heads * r, hd), jnp.float32))
+                x = jnp.concatenate(blocks, axis=0)
+            start(jnp.where(own_columns(), x, 0.0))
+
+        attend(0, slice(None), live_keys(1))
+
+        @pl.when(last)
+        def _():
+            out = jnp.where(own_columns(), acc_ref[...] / l_ref[0], 0.0)
+            o_ref[...] = (out.sum(axis=0, keepdims=True) if r == 1 else
+                          sum(out[h * r:(h + 1) * r] for h in range(n_heads)))
+
+        return
+
+    # The looped body: the column groups one after the other.
+    (qpad,) = qpad
+    rp = qpad.shape[0]                        # the rows, padded
     width = g * head_dim                      # columns of a group
     n_full, tail = hd // width, hd % width    # whole groups; a narrower last
 
@@ -154,18 +290,10 @@ def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
         return lax.shift_right_logical(
             col, int(np.log2(head_dim))) & (g - 1)
 
-    if windowed:
-        # A windowed table's first chunk is the one that holds its rows'
-        # earliest key, not chunk 0: the step before is another table's.
-        first = (step == 0) | (
-            table_ref[step] != table_ref[jnp.maximum(step - 1, 0)])
-    else:
-        first = chunk == 0
-
     @pl.when(first)
     def _():
         # The table's query rows, scaled, one copy a head of a group and
-        # each zero outside its head's columns, in parts of the pool's type.
+        # each zero outside its head's columns.
         x = q_ref[...].astype(jnp.float32) / np.sqrt(head_dim)
         if r < rp:
             qpad[...] = jnp.zeros_like(qpad)
@@ -175,46 +303,12 @@ def _kernel(layer_ref, page_ref, table_ref, chunk_ref, last_ref, q_ref,
             x = jnp.concatenate(
                 [jnp.where(head_in_group(hd) == j, x, 0.0)
                  for j in range(g)], axis=0)
-        qparts[...] = _parts(x, qparts.dtype, n_parts)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        start(x)
 
-    # The chunk's pages side by side. A page past the table's last live
-    # one holds an earlier live page (the plan's clamp): finite, and masked
-    # below by its position.
-    for i in range(ppc):
-        kbuf[i * bs:(i + 1) * bs, :] = k_pages[i][...]
-        vbuf[i * bs:(i + 1) * bs, :] = v_pages[i][...]
-    position = chunk * chunk_tokens + lax.broadcasted_iota(
-        jnp.int32, (1, chunk_tokens), 1)
-    lens = lens_ref[...]                                   # [rp, 1]
-    live = position < (lens if g == 1
-                       else jnp.concatenate([lens] * g, axis=0))
-    if windowed:
-        # A row whose window lies wholly outside this chunk sees no live
-        # key here: what that adds to its sum is multiplied by exp(-1e30 -
-        # m) = 0 at the row's first live key, which every row has (its own
-        # position), and after it a dead chunk adds exp(-1e30 - m) = 0.
-        lows = lows_ref[...]
-        live &= position >= (lows if g == 1
-                             else jnp.concatenate([lows] * g, axis=0))
+    live = live_keys(g)
+    each_group(lambda c, cols, _: attend(c, cols, live))
 
-    def attend(c, cols, _):
-        s = _dot_parts(qparts[:, cols], kbuf[:, cols], 1, n_parts)
-        s = jnp.where(live, s, _NEG_BIG)
-        m_prev = m_ref[c]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[c] = alpha * l_ref[c] + p.sum(axis=1, keepdims=True)
-        acc_ref[:, cols] = alpha * acc_ref[:, cols] + _dot_parts(
-            _parts(p, vbuf.dtype, n_parts), vbuf[:, cols], 0, n_parts)
-        m_ref[c] = m_new
-
-    each_group(attend)
-
-    @pl.when(last_ref[step] == 1)
+    @pl.when(last)
     def _():
         def finish(c, cols, n_columns):
             out = acc_ref[:, cols] / l_ref[c]              # [g * rp, columns]
@@ -245,8 +339,8 @@ class PagePlan(NamedTuple):
     table_of: jax.Array   # [steps]: grid step -> table
     chunk_of: jax.Array   # [steps]: ... -> chunk of that table
     last_of: jax.Array    # [steps]: 1 on a table's last chunk
-    lens: jax.Array       # [T, padded rows, 1]: each row's length
-    lows: Optional[jax.Array] = None   # windowed: each row's first live key
+    lens: jax.Array       # [T, padded rows, 1]: row i % rows' length
+    lows: Optional[jax.Array] = None   # windowed: ... and first live key
 
 
 def _pages_per_chunk(block_size: int, n_ctx: int) -> int:
@@ -303,19 +397,21 @@ def plan_pages(btabs, lengths, *, rows_per_table: int = 1, block_size: int,
     entry = jnp.minimum(chunk_of[None, :] * ppc + i, stay)
     page_of = btabs.astype(jnp.int32)[table_of[None, :], entry]
     last_of = ((chunk_of + 1) * ppc >= live).astype(jnp.int32)
-    # The rows of a table, once a query head of its group; pad rows attend
-    # position 0 only (none, under a window that starts past it).
+    # Each row's bounds, entry i those of row i % rows: the rows of a table
+    # once a query head of its group, and then again from the top, as far
+    # as the straight-line body can stack them. It finds row j of K/V head
+    # h at h * rows + j whatever the number of heads (which the plan is not
+    # told: under a mesh it is the shard's); the looped body reads the
+    # first rows. An entry past the last real row repeats a real row's
+    # bounds: a pad row attends what that row does, and is dropped.
     rows = group * tile
-    pad = ((0, 0), (0, _round_up(rows, _ROW_TILE) - rows))
-    if group > 1:
-        lens = jnp.tile(lens, (1, group))
-    lens = jnp.pad(lens, pad, constant_values=1)
-    if window is None:
-        return PagePlan(ends[-1], page_of, table_of, chunk_of, last_of,
-                        lens[..., None])
-    lows = jnp.pad(jnp.tile(lows, (1, group)), pad)
+    padded = _round_up(max(rows, _STRAIGHT_ROWS), _ROW_TILE)
+
+    def stacked(per_row):                                  # [T, tile]
+        return jnp.tile(per_row, (1, -(-padded // tile)))[:, :padded, None]
+
     return PagePlan(ends[-1], page_of, table_of, chunk_of, last_of,
-                    lens[..., None], lows[..., None])
+                    stacked(lens), None if window is None else stacked(lows))
 
 
 def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan):
@@ -327,13 +423,19 @@ def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan):
     tile = n // n_tables                      # positions a tile
     r = group * tile                          # rows a table, to the kernel
     windowed = plan.lows is not None
-    # As many whole heads as fill a lane tile make one column group.
-    g = _LANES // head_dim if _LANES % head_dim == 0 else 1
-    g = max(1, min(g, n_heads))
-    rp = plan.lens.shape[1]
-    m_rows = g * rp
+    straight = straight_line(tile, q_heads, n_heads)
+    rp = _round_up(r, _ROW_TILE)
+    if straight:
+        # Every head's rows at once: one "group" of all the columns.
+        g, n_groups = 1, 1
+        m_rows = lens_rows = _round_up(n_heads * r, _ROW_TILE)
+    else:
+        # As many whole heads as fill a lane tile make one column group.
+        g = _LANES // head_dim if _LANES % head_dim == 0 else 1
+        g = max(1, min(g, n_heads))
+        m_rows, lens_rows = g * rp, rp
+        n_groups = -(-hd // (g * head_dim))
     ppc = plan.page_of.shape[0]
-    n_groups = -(-hd // (g * head_dim))
     n_parts = 1 if k_pool.dtype == jnp.float32 else 3
 
     def page(i):
@@ -362,24 +464,24 @@ def _paged_attention(q, k_pool, v_pool, layer, plan: PagePlan):
             + 12 * n_parts * m_rows * max(ppc * bs, _LANES) * 4)
     kernel = functools.partial(
         _kernel, head_dim=head_dim, heads_per_group=g, pages_per_chunk=ppc,
-        n_parts=n_parts, **({"windowed": True} if windowed else {}))
+        n_parts=n_parts, straight=straight,
+        **({"windowed": True} if windowed else {}))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(plan.n_steps,),
-            in_specs=([table(r, hd)] + [table(rp, 1)] * (1 + windowed)
+            in_specs=([table(r, hd)] + [table(lens_rows, 1)] * (1 + windowed)
                       + [page(i) for i in range(ppc)] * 2),
             out_specs=table(r, hd),
             scratch_shapes=[
                 pltpu.VMEM((ppc * bs, hd), k_pool.dtype),
                 pltpu.VMEM((ppc * bs, hd), v_pool.dtype),
-                pltpu.VMEM((rp, hd), jnp.float32),
                 pltpu.VMEM((n_parts * m_rows, hd), k_pool.dtype),
                 pltpu.VMEM((n_groups, m_rows, 1), jnp.float32),
                 pltpu.VMEM((n_groups, m_rows, 1), jnp.float32),
                 pltpu.VMEM((m_rows, hd), jnp.float32),
-            ],
+            ] + ([] if straight else [pltpu.VMEM((rp, hd), jnp.float32)]),
         ),
         out_shape=jax.ShapeDtypeStruct((n_tables, r, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
