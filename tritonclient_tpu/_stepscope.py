@@ -43,7 +43,13 @@ thread:
   whose expert another chip holds: 0 where the program holds them all; the
   experts counted are the held ones). They come from the histogram the step returns,
   which the delivery thread reads back behind the tokens (``step_routing``):
-  a record that is read before that has no such fields yet;
+  a record that is read before that has no such fields yet; for a family
+  whose residual path is several streams mixed by per-token maps
+  (``models/mhc.py``) the same read-back adds ``RESIDUAL_FIELDS``:
+  ``hc_streams`` (the streams a token, n) and ``hc_rows`` (live rows x the
+  sublayers whose maps they passed, two a layer, summed over the
+  micro-steps), from which a reader reckons the maps' bytes (``hc_rows`` x
+  3 passes x n x hidden x the streams' item size);
 - ``device_us`` / ``other_us``: **``sync`` mode only** — a bracketed
   ``jax.block_until_ready`` (true device wait) and the clamped remainder.
   Counters mode has no device clock: the engine thread's post-dispatch
@@ -527,6 +533,9 @@ def step_end(rec: Optional[StepRecord], outputs=None):
 
 ROUTING_FIELDS = ("routed_tokens", "experts_hit", "experts_held",
                   "expert_load_max", "expert_load_mean", "pairs_elsewhere")
+# Beside them, from a family with a multi-stream residual path: the streams
+# a token and the (live row, sublayer) pairs that passed the maps.
+RESIDUAL_FIELDS = ("hc_streams", "hc_rows")
 
 
 def step_routing(rec: Optional[StepRecord], counters: Optional[dict]):

@@ -1,8 +1,11 @@
 """A DeepSeek-V3-shaped decoder behind the paged engine: multi-head latent
-attention (MLA) over one LATENT page pool, rotary positions, RMSNorm, a
-leading dense SwiGLU layer and then routed expert layers with a shared
-expert. ``JoyAI-LLM-Flash`` publishes this block; nothing here is specific
-to its sizes.
+attention (MLA) over one LATENT page pool, rotary positions (plain or
+YaRN-scaled), RMSNorm, any number of leading dense SwiGLU layers and then
+routed expert layers with a shared expert, on a residual path of one stream
+or of several mixed by per-token maps (mHC, ``models/mhc.py``).
+``JoyAI-LLM-Flash`` publishes this block with one stream, plain rotary and
+one dense layer; ``Xing4.0-29B-A4B`` with four streams, YaRN and two.
+Nothing here is specific to their sizes.
 
 The layer, per token ``x`` (every norm RMSNorm):
 
@@ -11,13 +14,25 @@ The layer, per token ``x`` (every norm RMSNorm):
     norm(first)``, ``k_r = RoPE(last)``, one for all heads; ``q_r =
     RoPE(q's rope part)``; ``[k_nope, v] = c_kv W_kvb`` per head; scores
     ``(q_nope . k_nope + q_r . k_r) / sqrt(nope + rope)``, causal softmax,
-    ``. v``, ``W_o``. RoPE rotates interleaved pairs ``(2i, 2i + 1)``.
+    ``. v``, ``W_o``. RoPE rotates interleaved pairs ``(2i, 2i + 1)``. With
+    ``rope_scaling`` (YaRN) the pairs' frequencies are blended between
+    kept and divided by ``factor`` (``rope_frequencies``) and the scores are
+    also multiplied by ``yarn_mscale(factor, mscale_all_dim) ** 2``.
   * dense layers: ``W_down(silu(x W_gate) * x W_up)``.
   * expert layers: ``s = sigmoid(x W_g)`` in float32; the top k of ``s + b``
     are chosen (``b`` = ``e_score_correction_bias``; no group limit), their
     weights are ``s`` of the chosen (without ``b``) over their sum, times
     ``routed_scaling_factor``; ``y = sum_e w_e SwiGLU_e(x) + SwiGLU_shared(x)``.
     No token is dropped and there is no capacity factor.
+  * the residual path: ``hc_mult`` 1 is ``x + F(norm(x))`` for attention and
+    for the feed-forward. ``hc_mult`` n > 1 keeps n streams a token ``X [n,
+    d]``, every stream the token's embedding at the start; each of a
+    layer's two sublayers has maps of its own (leaves ``hc_att`` /
+    ``hc_ffn``): ``h = H_pre . X`` goes into ``F(norm(h))`` and ``X' = H_res
+    X + H_post^T F``, with ``H_res`` made doubly stochastic by
+    ``hc_sinkhorn_iters`` Sinkhorn iterations (``mhc.py`` is the
+    equations); the streams are summed before the final norm. 1 is a static
+    Python branch: its programs are what they were before the field.
 
 What the paged engine needs of it is ``MlaMoePaged`` (``gpt_engine.
 PagedModel``): the pool is ONE array ``[n_layers, n_blocks, block_size,
@@ -26,7 +41,9 @@ kv_lora_rank + rope (+ zeros to whole lane tiles)]`` holding the normalised
 layers, then the expert layers) and updated in place, as the GPT family's
 two pools are. Decode attends it ABSORBED (``q_nope W_UK`` against the
 latent itself, ``P . c_kv`` then ``W_UV``); a prefill chunk EXPANDS the
-gathered latent to per-head keys and values. Both are the same mathematics.
+gathered latent to per-head keys and values (all its tables at once, or,
+past ``_EXPAND_AT_ONCE`` positions, a table at a time). All the same
+mathematics.
 
 The routed product is ``lax.ragged_dot`` over the (token, expert) pairs
 sorted by expert: the TPU compiler lowers it to a grouped matrix product
@@ -35,18 +52,21 @@ about 57 of a layer's 256 experts and not all of them. Rows that carry no
 request (idle slots, a chunk's padding) are given to no expert. Each step
 returns, beside its tokens, the per-layer histogram of tokens per expert
 (and, last, the pairs whose expert is held elsewhere: none here);
-the engine's delivery thread reads it when stepscope is on.
+the engine's delivery thread reads it when stepscope is on, and for a
+multi-stream configuration adds the streams a token and the (live row,
+sublayer) pairs that passed the maps (``hc_streams``, ``hc_rows``).
 """
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tritonclient_tpu.models import mhc
 from tritonclient_tpu.models._base import Model
 from tritonclient_tpu.models.gpt_engine import (
     GenerationEngine,
@@ -60,11 +80,28 @@ _HI = lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """``rope_scaling`` of type ``yarn``, under the published key names
+    (``original_max_len`` = ``original_max_position_embeddings``)."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+@dataclass(frozen=True)
 class MlaMoeConfig:
     vocab_size: int = 129280
     d_model: int = 2048
     n_layers: int = 5              # the first ``n_dense_layers`` are dense
-    n_dense_layers: int = 1
+    n_dense_layers: int = 1        # any number, 0 included: a scan of their own
     n_heads: int = 32
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512
@@ -78,13 +115,31 @@ class MlaMoeConfig:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
     rope_theta: float = 32e6
+    rope_scaling: Optional[YarnScaling] = None     # None: plain rotary
     rms_norm_eps: float = 1e-6
     max_len: int = 4096            # positions served (the block table's width)
     dtype: jnp.dtype = jnp.bfloat16
+    # The residual path (``models/mhc.py``): 1 = one stream, ``x + F(norm(x))``,
+    # a static branch that leaves the programs as they were; n > 1 = n
+    # streams mixed around every sublayer by maps of its own.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.n_dense_layers
+
+    @property
+    def softmax_scale(self) -> float:
+        """``1 / sqrt(nope + rope)``, times YaRN's ``mscale(factor,
+        mscale_all_dim)`` squared where the positions are scaled."""
+        scale = 1.0 / np.sqrt(self.qk_head_dim)
+        ys = self.rope_scaling
+        if ys is not None and ys.mscale_all_dim:
+            scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+        return scale
 
     # The share of the router's experts this program holds (``routed_experts``):
     # all of them, from the first.
@@ -153,7 +208,7 @@ def init_params(key: jax.Array, cfg: MlaMoeConfig) -> Dict:
 
     nd, nm, e = cfg.n_dense_layers, cfg.n_moe_layers, cfg.n_experts
     f, fe, fs = cfg.d_ff, cfg.d_expert, cfg.d_expert * cfg.n_shared_experts
-    return {
+    params = {
         "embed": {"tok": dense((cfg.vocab_size, d), d)},
         "dense": dict(
             attention(nd),
@@ -171,6 +226,17 @@ def init_params(key: jax.Array, cfg: MlaMoeConfig) -> Dict:
         "final_norm": jnp.ones((d,), cfg.dtype),
         "head": dense((d, cfg.vocab_size), d),
     }
+    if cfg.hc_mult > 1:
+        # Keys of their own, so the other leaves are what one stream draws.
+        hc = iter(jax.random.split(jax.random.fold_in(key, cfg.hc_mult), 4))
+        for stack, n in (("dense", nd), ("moe", nm)):
+            for sub in _HC_SUBLAYERS:
+                params[stack][sub] = mhc.init_maps(
+                    next(hc), n, cfg.hc_mult, d, cfg.dtype)
+    return params
+
+
+_HC_SUBLAYERS = ("hc_att", "hc_ffn")    # a layer's two sets of maps
 
 
 # --------------------------------------------------------------------------- #
@@ -184,14 +250,44 @@ def _rms_norm(x, weight, eps: float):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
+def rope_frequencies(cfg: MlaMoeConfig, dim: int):
+    """(the ``dim / 2`` rotation frequencies, what cos and sin are
+    multiplied by). Plain rotary: ``theta ** (-2i / dim)`` and 1. YaRN
+    (``cfg.rope_scaling``): pair i keeps its frequency where it turns more
+    than ``beta_fast`` times over the original positions, has it divided
+    by ``factor`` where it turns fewer than ``beta_slow`` times, and a
+    linear blend between (pairs ``low``..``high``); cos and sin times
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    ys = cfg.rope_scaling
+    if ys is None:
+        return cfg.rope_theta ** (
+            -jnp.arange(0, dim, 2, dtype=jnp.float32) / dim), 1.0
+    plain = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_turning(turns):        # the pair that turns ``turns`` times
+        return dim * np.log(ys.original_max_len / (turns * 2 * np.pi)) / (
+            2 * np.log(cfg.rope_theta))
+
+    low = max(np.floor(pair_turning(ys.beta_fast)), 0)
+    high = min(np.ceil(pair_turning(ys.beta_slow)), dim - 1)
+    kept = 1.0 - np.clip((np.arange(dim // 2) - low)
+                         / max(high - low, 1e-3), 0, 1)
+    blended = (1.0 - kept) * plain / ys.factor + kept * plain
+    return (jnp.asarray(blended, jnp.float32),
+            float(yarn_mscale(ys.factor, ys.mscale)
+                  / yarn_mscale(ys.factor, ys.mscale_all_dim)))
+
+
+def _rope(x, positions, cfg: MlaMoeConfig):
     """Rotate the interleaved pairs ``(2i, 2i + 1)`` of the last axis by
-    ``positions * theta ** (-2i / dim)``; ``positions`` broadcasts against
+    ``positions`` x ``rope_frequencies``; ``positions`` broadcasts against
     ``x`` without its last axis."""
     dim = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inv_freq, magnitude = rope_frequencies(cfg, dim)
     angle = positions.astype(jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (dim // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -304,6 +400,11 @@ def expert_banks(moe: Dict) -> Dict:
             for k in _EXPERT_BANKS}
 
 
+# The widest table (positions) a prefill chunk expands for all its tables
+# at once; a wider one is expanded and attended a table at a time.
+_EXPAND_AT_ONCE = 2048
+
+
 def _attend(q_nope, q_rope, table, mask, lp, cfg: MlaMoeConfig,
             absorbed: bool):
     """q_nope [T, R, H, nope], q_rope [T, R, H, rope] (rotated) against
@@ -316,12 +417,26 @@ def _attend(q_nope, q_rope, table, mask, lp, cfg: MlaMoeConfig,
     nothing of ``[L, H, ...]`` is made (decode: R = 1). Otherwise the
     latent is expanded to per-head keys and values first (a prefill
     chunk). The same mathematics either way.
+
+    Expanded over more than ``_EXPAND_AT_ONCE`` positions, the tables go
+    one after another (a fact of the shapes, no option): every table's
+    ``[L, H, nope + v]`` keys and values and ``[R, H, L]`` float32 scores
+    at once are 3.3 GB for 8 tables of 128 rows over 8,192 positions, and
+    the compiler lays those scores out rows-minor, where the softmax's
+    reduce-and-subtract took 70 ms a layer for what a table alone does in
+    0.2 ms (v5e; PERF.md section 6, PR 33).
     """
+    if not absorbed and table.shape[0] > 1 and (
+            table.shape[1] > _EXPAND_AT_ONCE):
+        return lax.map(
+            lambda one: _attend(*(a[None] for a in one), lp, cfg, False)[0],
+            (q_nope, q_rope, table,
+             jnp.broadcast_to(mask, table.shape[:1] + mask.shape[1:])))
     dc, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
     f32 = jnp.float32
     dtype = table.dtype
     wkv_b = lp["wkv_b"].reshape(dc, cfg.n_heads, dn + dv)
-    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+    scale = cfg.softmax_scale
     q_rope = jnp.pad(q_rope, ((0, 0),) * 3 + (
         (0, table.shape[-1] - dc - q_rope.shape[-1]),))
     if absorbed:
@@ -363,6 +478,12 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
     are per row. A layer writes its N latent rows at ``(layer, page,
     offset)`` and gathers ``pool[layer, btabs]``. Returns (h, pool, tokens
     per expert [n_moe_layers, E + 1]).
+
+    With ``cfg.hc_mult`` n > 1 the carried state is ``[N, n, d]``: every
+    stream starts as the row's embedding, attention and the feed-forward
+    are each wrapped ``pre -> F -> post`` by maps of their own
+    (``models/mhc.py``, under the scopes ``mhc_pre`` / ``mhc_post``), and the streams are summed before they are
+    returned, so the callers see ``[N, d]`` either way.
     """
     n_tables, n_ctx = btabs.shape
     n = x.shape[0]
@@ -370,16 +491,13 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
     h_, dn = cfg.n_heads, cfg.qk_nope_head_dim
     dc, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
 
-    def layer(ffn, carry, xs):
-        h, pool = carry
-        lp, li = xs
-        a = _rms_norm(h, lp["norm1"], eps)
+    def attention(a, pool, lp, li):
         q = _dot(_rms_norm(_dot(a, lp["wq_a"]), lp["q_norm"], eps),
                  lp["wq_b"]).reshape(n, h_, cfg.qk_head_dim)
         kv = _dot(a, lp["wkv_a"])
         latent = jnp.concatenate(
             [_rms_norm(kv[:, :dc], lp["kv_norm"], eps),
-             _rope(kv[:, dc:], positions, cfg.rope_theta),
+             _rope(kv[:, dc:], positions, cfg),
              jnp.zeros((n, cfg.pool_width - cfg.latent_dim), kv.dtype)],
             axis=-1)
         # One scatter at (layer, page, offset), then only the tables' pages
@@ -388,17 +506,38 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
         table = pool[li, btabs].reshape(n_tables, -1, cfg.pool_width)
         out = _attend(
             q[..., :dn].reshape(n_tables, rows, h_, dn),
-            _rope(q[..., dn:], positions[:, None], cfg.rope_theta).reshape(
+            _rope(q[..., dn:], positions[:, None], cfg).reshape(
                 n_tables, rows, h_, -1),
             table, mask, lp, cfg, absorbed)
-        h = h + _dot(out.reshape(n, -1), lp["wo"])
+        return _dot(out.reshape(n, -1), lp["wo"]), pool
+
+    def plain_layer(ffn, carry, xs):
+        h, pool = carry
+        lp, li = xs
+        y, pool = attention(_rms_norm(h, lp["norm1"], eps), pool, lp, li)
+        h = h + y
         y, counts = ffn(_rms_norm(h, lp["norm2"], eps), lp, li)
         return (h + y, pool), counts
+
+    def layer_of_streams(ffn, carry, xs):
+        state, pool = carry
+        lp, li = xs
+        h, back = mhc.pre(state, lp["hc_att"], cfg)
+        y, pool = attention(_rms_norm(h, lp["norm1"], eps), pool, lp, li)
+        state = mhc.post(state, y, back)
+        h, back = mhc.pre(state, lp["hc_ffn"], cfg)
+        y, counts = ffn(_rms_norm(h, lp["norm2"], eps), lp, li)
+        return (mhc.post(state, y, back), pool), counts
 
     def dense_ffn(x, lp, li):
         return _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), None
 
     nd, moe = cfg.n_dense_layers, params["moe"]
+    if cfg.hc_mult > 1:
+        layer = layer_of_streams
+        x = jnp.broadcast_to(x[:, None, :], (n, cfg.hc_mult, x.shape[-1]))
+    else:
+        layer = plain_layer
     carry, _ = lax.scan(functools.partial(layer, dense_ffn), (x, pool),
                         (params["dense"], jnp.arange(nd)))
     # The experts' matrices are not scanned: the scan would slice (copy) a
@@ -417,6 +556,8 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
         functools.partial(layer, moe_ffn), carry,
         ({k: v for k, v in moe.items() if k not in _EXPERT_BANKS},
          nd + jnp.arange(cfg.n_moe_layers)))
+    if cfg.hc_mult > 1:
+        x = x.astype(jnp.float32).sum(axis=1).astype(x.dtype)
     return x, pool, counts
 
 
@@ -578,8 +719,14 @@ class MlaMoePaged(PagedModel):
         read on the engine's delivery thread, for stepscope's dispatch
         record."""
         cfg = self.cfg
-        return routing_counters(extras[0], cfg.n_moe_layers,
-                                cfg.experts_held, cfg.experts_per_token)
+        counters = routing_counters(extras[0], cfg.n_moe_layers,
+                                    cfg.experts_held, cfg.experts_per_token)
+        if cfg.hc_mult > 1:
+            # Every live row passes two sublayers' maps a layer.
+            counters["hc_streams"] = cfg.hc_mult
+            counters["hc_rows"] = (counters["routed_tokens"] * 2
+                                   * cfg.n_layers)
+        return counters
 
 
 class MlaMoeEngineModel(GptEngineModel):
