@@ -9,8 +9,9 @@ this report goes one level down, into what stepscope
 ``sync`` mode only, device time and the clamped remainder, plus collectives
 charged per step, positions computed and context held and, for a family
 with a routed expert layer, what its router did (tokens routed, the share
-of the experts held that got one, the most loaded expert against the mean:
-the ``routing <phase>`` rows); per delivery item,
+of the experts held that got one, the most loaded expert against the mean,
+how often the experts' product streamed an expert for each one hit: the
+``routing <phase>`` rows); per delivery item,
 how long it queued for the delivery thread, its readback and its hand-over;
 what the engine thread did between dispatches (``ticket_wait`` /
 ``idle_wait`` / ``admit`` / ``join``) and, inside ``admit`` and ``join``,
@@ -398,25 +399,33 @@ def _routing(recs: List[dict]) -> Optional[dict]:
     """What the router of a routed family did in a phase's dispatches
     (stepscope ``ROUTING_FIELDS``, read back by the delivery thread): the
     tokens routed a dispatch, the share of the experts held that got a
-    token, and the most loaded expert against the mean. None where no
-    record carries the counters (another family, or an older dump)."""
+    token, the most loaded expert against the mean, and how often the
+    experts' product streamed an expert's matrices for each expert hit (1 =
+    once each; more where the product takes ``f`` in tiles and an expert's
+    rows lie in two row tiles; absent from a dump older than the counter).
+    None where no record carries the counters (another family, or an older
+    dump)."""
     routed = [r for r in recs
               if r.get("experts_held") and r.get("routed_tokens")]
     if not routed:
         return None
     n = len(routed)
-    return {
+    hit = sum(r["experts_hit"] for r in routed)
+    row = {
         "n": n,
         "routed_tokens_per_step": round(
             sum(r["routed_tokens"] for r in routed) / n, 1),
         "experts_hit_share": round(
-            sum(r["experts_hit"] for r in routed)
-            / sum(r["experts_held"] for r in routed), 4),
+            hit / sum(r["experts_held"] for r in routed), 4),
         # sum over sum: a dispatch counts by the tokens it routed
         "load_max_over_mean": round(
             sum(r["expert_load_max"] for r in routed)
             / sum(r["expert_load_mean"] for r in routed), 2),
     }
+    if hit and all("expert_passes" in r for r in routed):
+        row["passes_per_hit"] = round(
+            sum(r["expert_passes"] for r in routed) / hit, 3)
+    return row
 
 
 def analyze(records: List[dict],
@@ -563,6 +572,8 @@ def render(analysis: dict) -> str:
                     f"each, {100 * routing['experts_hit_share']:.1f}% of "
                     f"the experts held hit, load max/mean "
                     f"{routing['load_max_over_mean']}"
+                    + (f", {routing['passes_per_hit']} passes an expert hit"
+                       if "passes_per_hit" in routing else "")
                 )
         # Which body of the paged-attention kernel a phase's executables
         # hold: few-row tables (decode) take the straight-line one.
@@ -927,7 +938,8 @@ def self_check() -> int:
             r.update(ctx_pages=32, attn_straight=False)
         if r["phase"] == "decode":      # a routed family's counters
             r.update(routed_tokens=4, experts_hit=24, experts_held=64,
-                     expert_load_max=2, expert_load_mean=0.5)
+                     expert_load_max=2, expert_load_mean=0.5,
+                     expert_passes=30)
     dump["deliveries"] = [
         {"model": "gpt_engine", "phase": "decode", "step_index": i,
          "queued_ns": 1_000_000 * i, "taken_ns": 1_000_000 * i + 250_000,
@@ -969,8 +981,8 @@ def self_check() -> int:
             or m["phases"]["decode"].get("routing") != {
                 "n": m["phases"]["decode"]["n"],
                 "routed_tokens_per_step": 4.0, "experts_hit_share": 0.375,
-                "load_max_over_mean": 4.0}
-            or "routing decode" not in rendered
+                "load_max_over_mean": 4.0, "passes_per_hit": 1.25}
+            or "1.25 passes an expert hit" not in rendered
             or m["deliveries"] != {"decode": {
                 "n": 3, "queue_wait_ms": {"p50": 0.25, "p95": 0.25},
                 "readback_ms": {"p50": 0.5, "p95": 0.5},

@@ -1170,7 +1170,7 @@ def test_v5e_compiler_takes_the_grouped_decode_kernel(
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
 def test_v5e_compiler_moves_no_latent_pool_and_slices_no_expert_bank(
-        v5e_chip, compile_cache_off, program):
+        v5e_chip, compile_cache_off, monkeypatch, program):
     """The MLA / routed-expert family's steps (models/mla_moe.py), compiled
     for the v5e at the published latent width (512 + 64, a page row padded
     to 640 lanes: at 576 the chip lays the pool page-minor and every step
@@ -1183,6 +1183,9 @@ def test_v5e_compiler_moves_no_latent_pool_and_slices_no_expert_bank(
 
     from tritonclient_tpu.models import mla_moe
 
+    # The routed product is a Pallas kernel (ops/grouped_experts.py) that
+    # picks the interpreter from the backend: compile the chip's form.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     # Every published width of JoyAI-LLM-Flash (the config's defaults), one
     # dense and two expert layers, a small vocabulary: at toy widths the
     # compiler unrolls the layers and stages whole banks in fast memory,

@@ -341,11 +341,14 @@ def test_a_wide_table_is_attended_a_table_at_a_time_and_gives_the_same(
 # programs are those programs, operation for operation, so every output of
 # ``tests/test_mla_moe.py`` is what it was, bit for bit. A PR that changes
 # the one-stream path on purpose records them anew (the text has no file
-# names or line numbers in it).
+# names or line numbers in it). PR 35 did: the routed experts' product
+# became the kernel of ``ops/grouped_experts.py`` (interpreted here) in
+# place of three ``lax.ragged_dot``; recorded with ``_program_digests`` on
+# its final tree.
 _ONE_STREAM_PROGRAMS = {
-    "decode": "1a0311b48757744e",
-    "fused_2": "0963d80efbb2d2f0",
-    "prefill_chunk": "1298f14690050d89",
+    "decode": "6f8df725e5eddbbc",
+    "fused_2": "8869fc03991d740d",
+    "prefill_chunk": "a0061543d29cf202",
 }
 
 

@@ -5,6 +5,7 @@ plain reference's full forward pass ON LOGITS; absorbed against expanded
 attention; the router's choice by ``s + b`` and weight by ``s``; the seam
 the engine holds the family by. Nothing here is a device number."""
 
+import dataclasses
 import os
 import sys
 import time
@@ -355,9 +356,33 @@ def test_dispatch_records_gain_what_the_router_did(tiny):
         assert r["expert_load_max"] >= r["expert_load_mean"] > 0
         assert abs(r["expert_load_mean"] * r["experts_held"]
                    - layers * k * r["routed_tokens"]) < 1e-6
+        # the product takes these widths' f whole: each hit expert once
+        assert r["expert_passes"] == r["experts_hit"]
     # one request alone: a decode micro-step routes one token
     assert {r["routed_tokens"] // r["micro_steps"] for r in decodes} == {1}
     assert any(r["micro_steps"] > 1 for r in decodes)
+
+
+def test_routing_counters_from_a_hand_histogram():
+    """Two micro-steps of two expert layers of 4 held experts, top 2: the
+    fields by hand. The passes are the experts hit where the widths let the
+    product take ``f`` whole, and one more for the expert whose 600 rows lie
+    in two row tiles of 512 where it takes ``f`` in tiles (6144 x 2048)."""
+    histograms = np.asarray([
+        [[600, 0, 20, 0, 4], [0, 0, 310, 200, 4]],
+        [[1, 1, 0, 0, 0], [2, 0, 0, 0, 0]]])
+    narrow = mla_moe.MlaMoeConfig(
+        n_layers=3, n_experts=4, experts_per_token=2, d_model=2048,
+        d_expert=768, dtype=jnp.bfloat16)
+    assert narrow.n_moe_layers == 2 and narrow.experts_held == 4
+    want = {"routed_tokens": 313, "experts_hit": 7, "experts_held": 16,
+            "expert_load_max": 600, "expert_load_mean": 1134 / 16,
+            "pairs_elsewhere": 8, "expert_passes": 7}
+    assert mla_moe.routing_counters(histograms, narrow) == want
+    assert set(want) == set(_stepscope.ROUTING_FIELDS)
+    wide = dataclasses.replace(narrow, d_model=6144, d_expert=2048)
+    assert mla_moe.routing_counters(histograms, wide) == dict(
+        want, expert_passes=8)
 
 
 def test_the_gpt_family_goes_through_the_same_seam():

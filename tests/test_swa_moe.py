@@ -330,6 +330,7 @@ def test_a_requests_held_bytes_in_window_layers_stop_growing(tiny):
         assert abs(pairs + r["pairs_elsewhere"]
                    - layers * k * r["routed_tokens"]) < 1e-6
         assert r["pairs_elsewhere"] > 0
+        assert r["expert_passes"] == r["experts_hit"]     # f is whole here
 
 
 def test_a_repeated_prompt_is_computed_again_and_gives_the_same_logits(

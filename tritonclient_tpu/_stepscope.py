@@ -39,9 +39,12 @@ thread:
   experts that got a token, summed over the expert layers and the
   micro-steps) of ``experts_held`` (layers x micro-steps x experts),
   ``expert_load_max`` (the most tokens on one expert of one layer) against
-  ``expert_load_mean``, and ``pairs_elsewhere`` (the (token, expert) pairs
+  ``expert_load_mean``, ``pairs_elsewhere`` (the (token, expert) pairs
   whose expert another chip holds: 0 where the program holds them all; the
-  experts counted are the held ones). They come from the histogram the step returns,
+  experts counted are the held ones) and ``expert_passes`` (how often the
+  experts' product streamed an expert's matrices,
+  ``ops.grouped_experts.passes``: ``experts_hit`` where every hit expert
+  was read once). They come from the histogram the step returns,
   which the delivery thread reads back behind the tokens (``step_routing``):
   a record that is read before that has no such fields yet; for a family
   whose residual path is several streams mixed by per-token maps
@@ -532,7 +535,8 @@ def step_end(rec: Optional[StepRecord], outputs=None):
 
 
 ROUTING_FIELDS = ("routed_tokens", "experts_hit", "experts_held",
-                  "expert_load_max", "expert_load_mean", "pairs_elsewhere")
+                  "expert_load_max", "expert_load_mean", "pairs_elsewhere",
+                  "expert_passes")
 # Beside them, from a family with a multi-stream residual path: the streams
 # a token and the (live row, sublayer) pairs that passed the maps.
 RESIDUAL_FIELDS = ("hc_streams", "hc_rows")

@@ -45,10 +45,12 @@ gathered latent to per-head keys and values (all its tables at once, or,
 past ``_EXPAND_AT_ONCE`` positions, a table at a time). All the same
 mathematics.
 
-The routed product is ``lax.ragged_dot`` over the (token, expert) pairs
-sorted by expert: the TPU compiler lowers it to a grouped matrix product
-that visits the experts the tokens hit, so a decode step of 8 slots reads
-about 57 of a layer's 256 experts and not all of them. Rows that carry no
+The routed product is ONE Pallas kernel of the repo's own
+(``ops/grouped_experts.py``) over the (token, expert) pairs sorted by
+expert: it streams each expert the tokens HIT through fast memory once,
+gate, up and down together with SwiGLU between them, so a decode step of 8
+slots reads about 57 of a layer's 256 experts and not all of them, and the
+hidden ``[pairs, f]`` never reaches HBM. Rows that carry no
 request (idle slots, a chunk's padding) are given to no expert. Each step
 returns, beside its tokens, the per-layer histogram of tokens per expert
 (and, last, the pairs whose expert is held elsewhere: none here);
@@ -75,6 +77,7 @@ from tritonclient_tpu.models.gpt_engine import (
     _sample_slots,
     wire_tensors,
 )
+from tritonclient_tpu.ops import grouped_experts
 
 _HI = lax.Precision.HIGHEST
 
@@ -323,8 +326,8 @@ def route(x, router, bias, cfg):
 
 def routed_experts(x, experts, weights, live, banks, cfg, layer=0):
     """``sum_e w_e SwiGLU_e(x)`` over those of each token's chosen experts
-    that this program HOLDS, as grouped products over the (token, expert)
-    pairs sorted by expert.
+    that this program HOLDS: one kernel (``ops/grouped_experts.py``) over
+    the (token, expert) pairs sorted by expert.
 
     The share: ``cfg.experts_held`` experts from ``cfg.first_expert`` on, of
     the ``cfg.n_experts`` the router chooses among (one chip's share of an
@@ -337,34 +340,50 @@ def routed_experts(x, experts, weights, live, banks, cfg, layer=0):
 
     ``banks``: ``w_gate``/``w_up`` [G, d, f] and ``w_down`` [G, f, d] with
     G = layers * held, EVERY expert layer's held experts in one group axis,
-    and ``layer`` (traced) says whose turn it is: the groups of the other
-    layers are empty. Slicing one layer's [held, d, f] out of the stack
-    instead would copy it on its way into the product (measured on the
-    v5e: 2.4 GB a layer, 29 ms of a 38 ms decode step).
+    and ``layer`` (traced) says whose turn it is: the kernel picks expert
+    ``layer * held + e`` by index and reads no other layer's. Slicing one
+    layer's [held, d, f] out of the stack instead would copy it on its way
+    into the product (measured on the v5e: 2.4 GB a layer, 29 ms of a 38 ms
+    decode step).
 
     ``live`` [T] bool: a row that carries no request is given to no expert.
     Its pairs, like those of an expert held elsewhere, sort past every
     group, so the product neither computes them nor reads an expert for
-    them. Returns (y [T, d], counts [held + 1]: tokens per held expert,
-    then the live pairs that fell elsewhere).
+    them (``grouped_swiglu`` leaves their rows unwritten: ``kept`` below).
+    The sort, the gather and the scatter-back are plain ``jax.numpy``
+    around the kernel; the routing weight, the sum over a token's k rows
+    and then the cast are float32, as the kernel's sums are. Returns
+    (y [T, d], counts [held + 1]: tokens per held expert, then the live
+    pairs that fell elsewhere).
+
+    All of it runs inside ONE jitted function, ``_routed_experts``: a step
+    program's expert scan (and a fused decode's micro-steps) call this, an
+    engine holds twenty-odd such programs of a handful of row counts, and
+    every one of them traces what it calls on every set-up. The inner jit's
+    trace is kept by shape across outer programs, so the sort, the kernel's
+    plan, its block specs and its body are traced once a distinct shape a
+    process and lowered once a module (PERF.md §6, PR 35). Nothing is static
+    but the share's two integers and where the kernel runs (a fact of the
+    process: a trace for the chip never serves a call off it).
     """
+    return _routed_experts(x, experts, weights, live, banks, layer,
+                           first=cfg.first_expert, held=cfg.experts_held,
+                           interpret=jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.jit, static_argnames=("first", "held", "interpret"))
+def _routed_experts(x, experts, weights, live, banks, layer, *, first: int,
+                    held: int, interpret: bool):
     t, k = experts.shape
-    held = cfg.experts_held
-    groups = banks["w_gate"].shape[0]
-    local = experts - cfg.first_expert
+    local = experts - first
     mine = (local >= 0) & (local < held)
     flat = jnp.where(live[:, None] & mine, local, held).reshape(t * k)
     order = jnp.argsort(flat)                      # stable: pairs by expert
     counts = jnp.zeros((held,), jnp.int32).at[flat].add(1, mode="drop")
     elsewhere = jnp.sum(live[:, None] & ~mine, dtype=jnp.int32)
-    sizes = lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32), counts,
-                                     (layer * held,))
-    rows = x[order // k]                           # [T * k, d]
-    grouped = functools.partial(lax.ragged_dot, group_sizes=sizes,
-                                preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(grouped(rows, banks["w_gate"]))
-              * grouped(rows, banks["w_up"])).astype(x.dtype)
-    y = grouped(hidden, banks["w_down"])           # [T * k, d] float32
+    y = grouped_experts.grouped_swiglu(
+        x[order // k], counts, banks["w_gate"], banks["w_up"],
+        banks["w_down"], layer, interpret=interpret)
     kept = (flat[order] < held)[:, None]           # rows past the groups
     y = jnp.where(kept, y * weights.reshape(t * k)[order][:, None], 0.0)
     # Back to token order: each token's k rows, summed.
@@ -372,11 +391,13 @@ def routed_experts(x, experts, weights, live, banks, cfg, layer=0):
     return y.astype(x.dtype), jnp.append(counts, elsewhere)
 
 
-def routing_counters(histograms, n_layers: int, held: int, k: int) -> dict:
+def routing_counters(histograms, cfg) -> dict:
     """A dispatch's routing counters (stepscope ``ROUTING_FIELDS``) from the
     histograms its expert layers returned (``routed_experts``' counts,
-    ``[n_layers, held + 1]`` or one such per micro-step)."""
-    counts = np.asarray(histograms).reshape(-1, n_layers, held + 1)
+    ``[n_moe_layers, held + 1]`` or one such per micro-step); ``cfg`` is
+    either routed family's config."""
+    held, k = cfg.experts_held, cfg.experts_per_token
+    counts = np.asarray(histograms).reshape(-1, cfg.n_moe_layers, held + 1)
     mine, elsewhere = counts[..., :held], counts[..., held]
     # Every expert layer routes the same rows: count them at the first.
     routed = int(mine[:, 0].sum() + elsewhere[:, 0].sum()) // k
@@ -387,6 +408,11 @@ def routing_counters(histograms, n_layers: int, held: int, k: int) -> dict:
         "expert_load_max": int(mine.max()),
         "expert_load_mean": float(mine.mean()),
         "pairs_elsewhere": int(elsewhere.sum()),
+        # How often the product streamed an expert's matrices: the experts
+        # hit, each once, unless an expert's rows lay in two row tiles of
+        # a product that takes ``f`` in tiles.
+        "expert_passes": grouped_experts.passes(
+            mine, cfg.d_model, cfg.d_expert, cfg.dtype),
     }
 
 
@@ -719,8 +745,7 @@ class MlaMoePaged(PagedModel):
         read on the engine's delivery thread, for stepscope's dispatch
         record."""
         cfg = self.cfg
-        counters = routing_counters(extras[0], cfg.n_moe_layers,
-                                    cfg.experts_held, cfg.experts_per_token)
+        counters = routing_counters(extras[0], cfg)
         if cfg.hc_mult > 1:
             # Every live row passes two sublayers' maps a layer.
             counters["hc_streams"] = cfg.hc_mult

@@ -18,9 +18,12 @@ The layer, per token ``x`` (every norm RMSNorm, no biases):
   * dense layers: ``W_down(silu(x W_gate) * x W_up)``.
   * expert layers: ``mla_moe.route`` over all ``n_experts`` and
     ``mla_moe.routed_experts`` over the ``experts_held`` this program holds
-    from ``first_expert`` on, plus the shared expert: one implementation
-    for both families. The other chips of the layer, and the exchange with
-    them, are not here and nothing stands in for them.
+    from ``first_expert`` on (one kernel, ``ops/grouped_experts.py``, that
+    streams each held expert the tokens hit once; the seven pairs in eight
+    whose expert another chip holds cost no product), plus the shared
+    expert: one implementation for both families. The other chips of the
+    layer, and the exchange with them, are not here and nothing stands in
+    for them.
 
 THE CACHE KNOWS THE KIND. Keys and values live in two flat pools ``[1,
 pages, block_size, H_kv * Dh]`` of two regions. A global layer has
@@ -507,9 +510,7 @@ class SwaMoePaged(PagedModel):
         return swa_moe_prefill_chunk
 
     def routing(self, extras) -> Optional[dict]:
-        cfg = self.cfg
-        return routing_counters(extras[0], cfg.n_moe_layers,
-                                cfg.experts_held, cfg.experts_per_token)
+        return routing_counters(extras[0], self.cfg)
 
 
 class SwaMoeEngineModel(GptEngineModel):
