@@ -16,10 +16,18 @@ how long it queued for the delivery thread, its readback and its hand-over;
 what the engine thread did between dispatches (``ticket_wait`` /
 ``idle_wait`` / ``admit`` / ``join``) and, inside ``admit`` and ``join``,
 its slot-state updates (how many, the slots each joined and freed, what
-each cost the thread: the ``slot updates`` row); and one timeline per request
-(receipt to core to submit, wait for a slot, the prefill span split at its
-first chunk, last chunk and first token's readback, tokens, worst gap
-between two tokens). A counters-mode dump has no device clock — the engine thread's
+each cost the thread: the ``slot updates`` row); for every stretch the two
+threads bracket (a loop state, a dispatch bracket: the ``dispatching
+<phase>`` rows, a slot update, a delivery's hand-over) the time the thread
+was OFF THE CPU (wall less the thread's own CPU time: in line for the
+interpreter lock, or descheduled) and the part of it spent runnable on a
+RUN QUEUE (its core taken), as totals and shares; the interpreter's
+collector (the ``collector`` row: collections by generation, total and worst
+pause, the seconds of the recorded span they fell in); and one timeline per
+request (receipt to core to submit, wait for a slot, the prefill span split
+at its first chunk, last chunk and first token's readback, the worst
+hand-over of a token to its stream handler (``wake``) and the handler's worst
+time on one message (``handler``), tokens, worst gap between two tokens). A counters-mode dump has no device clock — the engine thread's
 post-dispatch remainder is bookkeeping, and the delivery thread's
 ``ready_ns`` is when *it* saw the result — so it gets the tables and no
 dispatch-/device-bound verdict. It consumes
@@ -27,7 +35,7 @@ dispatch-/device-bound verdict. It consumes
 * a stepscope dump (``tritonclient_tpu._stepscope.dump()`` saved to a
   file) — the primary input: the recent-step ring with full breakdowns,
   the loop states, the delivery thread's ring, the slot-state updates'
-  ring and the finished requests' ring;
+  ring, the finished requests' ring and the collector's pauses;
 * a flight-recorder dump (``GET v2/debug/flight_recorder``) — retained
   records carry the slowest step's breakdown as ``step.slowest.*``
   attributes;
@@ -217,6 +225,11 @@ def load_slot_updates(doc) -> List[dict]:
     return _load_ring(doc, "slot_updates")
 
 
+def load_pauses(doc) -> List[dict]:
+    """The collector's pauses (process-wide: no model)."""
+    return _load_ring(doc, "gc")
+
+
 def load_file(path: str) -> List[dict]:
     with open(path) as f:
         return load_records(json.load(f))
@@ -250,9 +263,50 @@ def _stage_means(recs: List[dict]) -> Dict[str, float]:
     return means
 
 
+def _off_cpu(recs: List[dict], wall_us) -> Optional[dict]:
+    """Over the stretches that carry stepscope's thread clocks: the time the
+    thread was off the CPU (Σ ``wall_us(r)`` - ``cpu_us``: it slept in line
+    for the interpreter lock or a condition, or stood descheduled) and the
+    part of it spent runnable on a run queue (Σ ``runq_us``: its core was
+    taken), in ms, each with its share of the stretches' wall time. None
+    where no record carries ``cpu_us`` (an older dump); no run-queue fields
+    where none carries ``runq_us`` (the thread's schedstat was unreadable)."""
+    clocked = [r for r in recs if r.get("cpu_us") is not None]
+    if not clocked:
+        return None
+    wall = sum(wall_us(r) for r in clocked)
+    # Of the sums: a CPU clock that advances a tick at a time (10 ms on
+    # some hosts) reads 0 or a whole tick on a short stretch.
+    off = max(wall - sum(int(r["cpu_us"]) for r in clocked), 0)
+    cell = {"off_cpu_ms": round(off / 1000, 3),
+            "off_cpu_share": round(off / wall, 4) if wall else 0.0}
+    queued = [int(r["runq_us"]) for r in clocked
+              if r.get("runq_us") is not None]
+    if queued:
+        cell["runq_ms"] = round(sum(queued) / 1000, 3)
+        cell["runq_share"] = round(sum(queued) / wall, 4) if wall else 0.0
+    return cell
+
+
+def _off_cpu_text(cell: Optional[dict], lead: str = ", off-cpu") -> str:
+    if not cell or "off_cpu_ms" not in cell:
+        return ""
+    text = (f"{lead} {cell['off_cpu_ms']} ms "
+            f"({100 * cell['off_cpu_share']:.1f}%)")
+    if "runq_ms" in cell:
+        text += (f", run-queue {cell['runq_ms']} ms "
+                 f"({100 * cell['runq_share']:.1f}%)")
+    return text
+
+
+def _dispatch_us(r: dict) -> int:
+    return int(r.get("dispatch_us", 0))
+
+
 def _loop_states(recs: List[dict]) -> Dict[str, dict]:
-    """Per loop state: stretches, their total, and its share of the span
-    the model's records cover (first start to last end)."""
+    """Per loop state: stretches, their total, its share of the span the
+    model's records cover (first start to last end), and the stretches'
+    time off the CPU and on a run queue (``_off_cpu``)."""
     spans = [(int(r["start_ns"]), int(r["start_ns"])
               + 1000 * int(r.get("total_us") or r.get("dispatch_us", 0)))
              for r in recs if r.get("start_ns")]
@@ -260,14 +314,15 @@ def _loop_states(recs: List[dict]) -> Dict[str, dict]:
                   / 1000 if spans else 0)
     out = {}
     for state in _stepscope.LOOP_STATES:
-        durations = [int(r.get("dispatch_us", 0)) for r in recs
-                     if r.get("phase") == state]
+        stretches = [r for r in recs if r.get("phase") == state]
+        durations = [_dispatch_us(r) for r in stretches]
         if durations:
             out[state] = {
                 "n": len(durations),
                 "total_ms": round(sum(durations) / 1000, 3),
                 "share": round(sum(durations) / covered_us, 4)
                 if covered_us else 0.0,
+                **(_off_cpu(stretches, _dispatch_us) or {}),
             }
     return out
 
@@ -281,7 +336,8 @@ def _span_ms(start: Optional[int], end: Optional[int]) -> Optional[float]:
 def _deliveries(deliveries: List[dict]) -> Dict[str, dict]:
     """Per phase of the dispatch that made the item: how long items queued
     for the delivery thread, the readback as that thread saw it, and the
-    hand-over to the requests (p50 / p95, ms). Never a device time."""
+    hand-over to the requests (p50 / p95, ms), with the hand-overs' time off
+    the CPU and on a run queue. Never a device time."""
     out = {}
     for phase in sorted({d.get("phase", "") for d in deliveries}):
         cell = {"n": 0}
@@ -295,6 +351,12 @@ def _deliveries(deliveries: List[dict]) -> Dict[str, dict]:
             cell["n"] = max(cell["n"], len(spans))
             cell[f"{name}_ms"] = {"p50": _percentile(spans, 0.50),
                                   "p95": _percentile(spans, 0.95)}
+        # The hand-over's time off the CPU (ready_ns -> delivered_ns is the
+        # stretch the delivery thread clocks).
+        cell.update(_off_cpu(
+            [d for d in deliveries if d.get("phase") == phase
+             and d.get("ready_ns") and d.get("delivered_ns")],
+            lambda d: (d["delivered_ns"] - d["ready_ns"]) // 1000) or {})
         out[phase] = cell
     return out
 
@@ -303,7 +365,8 @@ def _slot_updates(updates: List[dict]) -> Optional[dict]:
     """The engine loop's slot-state updates: how many dispatches, the slots
     they joined and freed in all, and what one cost the loop's thread
     (host time from building its arrays to the call's return; p50 / p95,
-    ms). None where the dump has none."""
+    ms), with the updates' time off the CPU and on a run queue. None where
+    the dump has none."""
     if not updates:
         return None
     host_ms = sorted(round(int(u.get("host_ns", 0)) / 1e6, 3)
@@ -313,13 +376,21 @@ def _slot_updates(updates: List[dict]) -> Optional[dict]:
             "freed": sum(int(u.get("freed", 0)) for u in updates),
             "host_ms": {"p50": _percentile(host_ms, 0.50),
                         "p95": _percentile(host_ms, 0.95)},
-            "host_total_ms": round(sum(host_ms), 3)}
+            "host_total_ms": round(sum(host_ms), 3),
+            **(_off_cpu(updates,
+                        lambda u: int(u.get("host_ns", 0)) // 1000) or {})}
 
 
 #: The ms columns of the per-request table, in the order of the timeline.
 REQUEST_SPANS = ("recv_ms", "core_ms", "wait_ms", "to_chunk_ms",
-                 "chunking_ms", "readback_ms", "handover_ms",
-                 "prefill_span_ms", "worst_gap_ms")
+                 "chunking_ms", "readback_ms", "handover_ms", "wake_ms",
+                 "handler_ms", "prefill_span_ms", "worst_gap_ms")
+
+
+def _worst_ms(starts, ends) -> Optional[float]:
+    """The longest of the spans ``starts[i]`` -> ``ends[i]``, in ms."""
+    spans = [b - a for a, b in zip(starts or [], ends or [])]
+    return round(max(spans) / 1e6, 3) if spans else None
 
 
 def _request_rows(requests: List[dict]) -> List[dict]:
@@ -327,8 +398,12 @@ def _request_rows(requests: List[dict]) -> List[dict]:
     (``recv_ms``) and the core to the engine's submit (``core_ms``); the
     wait for a slot and pages; the prefill span (admission to the first
     token's hand-over) and its split at the first chunk's dispatch return,
-    the last chunk's, and the first token's readback; tokens; and the
-    worst gap between two consecutive tokens."""
+    the last chunk's, and the first token's readback; over its tokens the
+    worst hand-over from the delivery thread's put to the stream handler
+    holding the token (``wake_ms``: ``out_ns[i]`` -> ``taken_ns[i]``) and the
+    handler's worst time on one message (``handler_ms``: ``taken_ns[i]`` ->
+    ``resumed_ns[i]``); tokens; and the worst gap between two consecutive
+    tokens."""
     rows = []
     for r in requests:
         out_ns = r.get("out_ns") or []
@@ -349,12 +424,41 @@ def _request_rows(requests: List[dict]) -> List[dict]:
             "readback_ms": _span_ms(r.get("last_chunk_ns"),
                                     r.get("first_ready_ns")),
             "handover_ms": _span_ms(r.get("first_ready_ns"), first_out),
+            "wake_ms": _worst_ms(out_ns, r.get("taken_ns")),
+            "handler_ms": _worst_ms(r.get("taken_ns"), r.get("resumed_ns")),
             "prefill_span_ms": _span_ms(admitted, first_out),
             "chunks": r.get("chunks", 0),
             "tokens": len(out_ns),
             "worst_gap_ms": round(max(gaps) / 1e6, 3) if gaps else None,
         })
     return rows
+
+
+def _collector(pauses: List[dict], records: List[dict]) -> Optional[dict]:
+    """The interpreter's collector over the dump: collections by generation,
+    their total and the worst pause (and the thread it ran on), and the
+    seconds of the recorded span (from the first record's start) in which a
+    collection began. Every thread of the process stalls for a collection.
+    None where the dump has none."""
+    if not pauses:
+        return None
+    starts = [int(r["start_ns"]) for r in records if r.get("start_ns")]
+    origin = min(starts) if starts else min(p["start_ns"] for p in pauses)
+    by_generation: Dict[str, int] = {}
+    for p in pauses:
+        gen = str(p.get("generation"))
+        by_generation[gen] = by_generation.get(gen, 0) + 1
+    worst = max(pauses, key=lambda p: p.get("duration_ns", 0))
+    return {
+        "n": len(pauses),
+        "by_generation": dict(sorted(by_generation.items())),
+        "total_ms": round(sum(p.get("duration_ns", 0)
+                              for p in pauses) / 1e6, 3),
+        "worst_ms": round(worst.get("duration_ns", 0) / 1e6, 3),
+        "worst_thread": worst.get("thread_name", ""),
+        "seconds": sorted({int((p["start_ns"] - origin) // 1e9)
+                           for p in pauses}),
+    }
 
 
 def _pages_read_share(recs: List[dict]) -> Optional[float]:
@@ -432,12 +536,13 @@ def analyze(records: List[dict],
             compiles: Optional[Dict[str, Dict[str, dict]]] = None,
             requests: Optional[List[dict]] = None,
             deliveries: Optional[List[dict]] = None,
-            slot_updates: Optional[List[dict]] = None) -> dict:
+            slot_updates: Optional[List[dict]] = None,
+            pauses: Optional[List[dict]] = None) -> dict:
     """Per-model verdict + per-phase quantiles and stage means; when the
     dump carries the compile plane, each model also gets its per-callable
     cache-entry/retrace totals, and from a stepscope dump its loop states,
     its deliveries' table, its slot-state updates' row and its requests'
-    table."""
+    table; the collector's row is the process's (``collector``)."""
     by_model: Dict[str, List[dict]] = {}
     for r in records:
         by_model.setdefault(r.get("model", ""), []).append(r)
@@ -483,6 +588,13 @@ def analyze(records: List[dict],
                 # on dumps from before the record had ``ctx_pages``.
                 "pages_read_share": _pages_read_share(ph),
             }
+            # The dispatch brackets' time off the CPU (begin -> dispatched).
+            off_cpu = _off_cpu(ph, _dispatch_us)
+            if off_cpu is not None:
+                phases[phase]["off_cpu"] = {
+                    "total_ms": round(
+                        sum(_dispatch_us(r) for r in ph) / 1000, 3),
+                    **off_cpu}
             routing = _routing(ph)
             if routing is not None:
                 phases[phase]["routing"] = routing
@@ -512,7 +624,11 @@ def analyze(records: List[dict],
             "compiles": dict(sorted(((compiles or {}).get(model)
                                      or {}).items())),
         }
-    return {"models": models}
+    out = {"models": models}
+    collector = _collector(pauses or [], records)
+    if collector is not None:
+        out["collector"] = collector
+    return out
 
 
 def render(analysis: dict) -> str:
@@ -587,6 +703,16 @@ def render(analysis: dict) -> str:
                     f"{100 * cell['dispatches']:.1f}% on the kernel's "
                     f"straight-line body, {of_pages} of the pages read"
                 )
+        # What of a phase's dispatch brackets (begin -> dispatched) the engine
+        # thread spent off the CPU: in line for the interpreter lock (every
+        # transfer and the call give it up), or descheduled.
+        for phase, ph in m["phases"].items():
+            cell = ph.get("off_cpu")
+            if cell:
+                lines.append(
+                    f"  dispatching {phase:<10} {ph['n']:>6} brackets "
+                    f"{cell['total_ms']:>10.3f} ms{_off_cpu_text(cell)}"
+                )
         # The delivery thread's view of each dispatch's result (ms): a long
         # queue wait says items stand behind one another, a long readback
         # that the thread waited on the device. Not a device time.
@@ -598,6 +724,7 @@ def render(analysis: dict) -> str:
             lines.append(
                 f"  delivery {phase:<14} {cell['n']:>6} items, "
                 f"p50/p95 ms: {spans}"
+                + _off_cpu_text(cell, "; hand-overs off-cpu")
             )
         # What the engine thread did between dispatches: a large
         # ticket_wait share says the host runs ahead of the chip.
@@ -606,6 +733,7 @@ def render(analysis: dict) -> str:
                 f"  loop {state:<12} {cell['n']:>6} stretches "
                 f"{cell['total_ms']:>10.3f} ms "
                 f"({100 * cell['share']:.1f}% of the recorded span)"
+                + _off_cpu_text(cell)
             )
         # The writes joins, frees and cancels made to the slot state, inside
         # the ``admit`` and ``join`` stretches above: one dispatch a burst.
@@ -617,6 +745,7 @@ def render(analysis: dict) -> str:
                 f"freed, host p50/p95 ms: {updates['host_ms']['p50']}/"
                 f"{updates['host_ms']['p95']} "
                 f"({updates['host_total_ms']} ms in all)"
+                + _off_cpu_text(updates)
             )
         rows = m.get("requests") or []
         if rows:
@@ -626,6 +755,7 @@ def render(analysis: dict) -> str:
                 f"finished); slowest waits first"
             )
             # prefill_span = to_chunk + chunking + readback + handover;
+            # wake and handler are the worst over the request's tokens;
             # a wait marked * stood behind the page pool, not a slot.
             head = " ".join(
                 f"{name[:-3].replace('prefill_span', 'prefill'):>9}"
@@ -656,6 +786,18 @@ def render(analysis: dict) -> str:
                                 if r[name] is not None)
                 medians[name] = _percentile(values, 0.50) if values else None
             lines.append(f"  {'median':>7} {'':>6} {'':<10} {cells(medians)}")
+    # The interpreter's collector, process-wide: a collection holds the
+    # interpreter lock, so every thread above stalled for each.
+    collector = analysis.get("collector")
+    if collector:
+        by_generation = ", ".join(
+            f"gen {gen}: {n}" for gen, n in collector["by_generation"].items())
+        lines.append(
+            f"collector: {collector['n']} collections ({by_generation}), "
+            f"{collector['total_ms']} ms in all, worst "
+            f"{collector['worst_ms']} ms on {collector['worst_thread']}, "
+            f"begun in seconds {collector['seconds']} of the recorded span"
+        )
     return "\n".join(lines)
 
 
@@ -914,11 +1056,13 @@ def self_check() -> int:
         {"model": "gpt_engine", "phase": "ticket_wait", "step_index": 0,
          "batch_size": 0, "slots": 8, "start_ns": 30_000_000,
          "dispatch_us": 4000, "total_us": 4000, "micro_steps": 0,
-         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine"},
+         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine",
+         "cpu_us": 40, "runq_us": 10},
         {"model": "gpt_engine", "phase": "admit", "step_index": 0,
          "batch_size": 0, "slots": 8, "start_ns": 40_000_000,
          "dispatch_us": 500, "total_us": 500, "micro_steps": 0,
-         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine"},
+         "collectives": {}, "thread_ident": 42, "thread_name": "gpt-engine",
+         "cpu_us": 100, "runq_us": 50},
     ]
     dump["requests"] = [{
         "model": "gpt_engine", "key": [7, 40, 3], "recv_ns": 900_000,
@@ -927,13 +1071,16 @@ def self_check() -> int:
         "first_chunk_ns": 4_000_000, "last_chunk_ns": 5_000_000,
         "chunks": 2, "first_ready_ns": 8_000_000,
         "out_ns": [9_000_000, 10_000_000, 14_000_000],
+        "taken_ns": [9_100_000, 10_700_000, 14_200_000],
+        "resumed_ns": [9_150_000, 10_750_000, 14_500_000],
         "end_ns": 14_100_000, "outcome": "finished",
     }]
     for r in dump["records"]:
         r["tokens"], r["ctx_tokens"] = 4, 400
         if r["phase"] == "decode":      # 8 lanes x 64 entries, 128 live
             r.update(lanes=8, ctx_blocks=64, ctx_pages=128,
-                     attn_straight=True)
+                     attn_straight=True,
+                     cpu_us=r["dispatch_us"] - 20, runq_us=5)
         if r["phase"] == "prefill_chunk":   # the kernel's looped body
             r.update(ctx_pages=32, attn_straight=False)
         if r["phase"] == "decode":      # a routed family's counters
@@ -944,26 +1091,51 @@ def self_check() -> int:
         {"model": "gpt_engine", "phase": "decode", "step_index": i,
          "queued_ns": 1_000_000 * i, "taken_ns": 1_000_000 * i + 250_000,
          "ready_ns": 1_000_000 * i + 750_000,
-         "delivered_ns": 1_000_000 * i + 800_000} for i in (1, 2, 3)]
+         "delivered_ns": 1_000_000 * i + 800_000,
+         "cpu_us": 30, "runq_us": 10} for i in (1, 2, 3)]
     dump["slot_updates"] = [
         {"model": "gpt_engine", "joined": 3, "freed": 0,
-         "start_ns": 40_100_000, "host_ns": 300_000},
+         "start_ns": 40_100_000, "host_ns": 300_000, "cpu_us": 100,
+         "runq_us": 40},
         {"model": "gpt_engine", "joined": 0, "freed": 2,
-         "start_ns": 41_000_000, "host_ns": 100_000}]
+         "start_ns": 41_000_000, "host_ns": 100_000, "cpu_us": 100,
+         "runq_us": 0}]
+    dump["gc"] = [
+        {"start_ns": 2_000_000, "duration_ns": 3_000_000, "generation": 0,
+         "thread_ident": 42, "thread_name": "gpt-engine"},
+        {"start_ns": 1_002_000_000, "duration_ns": 7_500_000,
+         "generation": 2, "thread_ident": 7, "thread_name": "handler"}]
     analysis = analyze(load_records(dump), load_compiles(dump),
                        load_requests(dump), load_deliveries(dump),
-                       load_slot_updates(dump))
+                       load_slot_updates(dump), load_pauses(dump))
     m = analysis["models"]["gpt_engine"]
     rendered = render(analysis)
     if (m["verdict"] != VERDICT_NO_DEVICE_CLOCK
             or "device" in m["mean_us"] or m["n"] != 24
             or "ticket_wait" in m["phases"]
             or m["loop_states"]["ticket_wait"]["total_ms"] != 4.0
+            or {k: m["loop_states"]["admit"].get(k) for k in (
+                "off_cpu_ms", "off_cpu_share", "runq_ms", "runq_share")} != {
+                "off_cpu_ms": 0.4, "off_cpu_share": 0.8, "runq_ms": 0.05,
+                "runq_share": 0.1}
+            or "off-cpu 0.4 ms (80.0%), run-queue 0.05 ms (10.0%)"
+            not in rendered
+            or m["phases"]["decode"]["off_cpu"]["off_cpu_ms"]
+            != 0.02 * m["phases"]["decode"]["n"]
+            or "dispatching decode" not in rendered
+            or "off_cpu" in m["phases"]["prefill"]
+            or analysis.get("collector") != {
+                "n": 2, "by_generation": {"0": 1, "2": 1},
+                "total_ms": 10.5, "worst_ms": 7.5,
+                "worst_thread": "handler", "seconds": [0, 1]}
+            or "collector: 2 collections (gen 0: 1, gen 2: 1)"
+            not in rendered
             or m["requests"] != [{
                 "prompt_len": 40, "max_new": 3, "outcome": "finished",
                 "recv_ms": 0.05, "core_ms": 0.05, "wait_ms": 2.0,
                 "waited_for_pages": False, "to_chunk_ms": 1.0,
                 "chunking_ms": 1.0, "readback_ms": 3.0, "handover_ms": 1.0,
+                "wake_ms": 0.7, "handler_ms": 0.3,
                 "prefill_span_ms": 6.0, "chunks": 2, "tokens": 3,
                 "worst_gap_ms": 4.0}]
             or m["phases"]["decode"]["ctx_tokens_per_step"] != 400
@@ -986,10 +1158,15 @@ def self_check() -> int:
             or m["deliveries"] != {"decode": {
                 "n": 3, "queue_wait_ms": {"p50": 0.25, "p95": 0.25},
                 "readback_ms": {"p50": 0.5, "p95": 0.5},
-                "handover_ms": {"p50": 0.05, "p95": 0.05}}}
+                "handover_ms": {"p50": 0.05, "p95": 0.05},
+                "off_cpu_ms": 0.06, "off_cpu_share": 0.4,
+                "runq_ms": 0.03, "runq_share": 0.2}}
+            or "hand-overs off-cpu 0.06 ms (40.0%)" not in rendered
             or m["slot_updates"] != {
                 "n": 2, "joined": 3, "freed": 2,
-                "host_ms": {"p50": 0.3, "p95": 0.3}, "host_total_ms": 0.4}
+                "host_ms": {"p50": 0.3, "p95": 0.3}, "host_total_ms": 0.4,
+                "off_cpu_ms": 0.2, "off_cpu_share": 0.5,
+                "runq_ms": 0.04, "runq_share": 0.1}
             or "slot updates" not in rendered
             or "records no device time" not in rendered
             or "loop ticket_wait" not in rendered
@@ -997,7 +1174,8 @@ def self_check() -> int:
             or "worst_gap" not in rendered or "median" not in rendered):
         print("self-check [counters]: device clock invented, or loop "
               "states / deliveries / slot updates / requests / routing / "
-              "the attention body lost",
+              "the attention body / the time off the CPU / the collector "
+              "lost",
               file=sys.stderr)
         failures += 1
     else:
@@ -1081,7 +1259,8 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     analysis = analyze(records, load_compiles(doc), load_requests(doc),
-                       load_deliveries(doc), load_slot_updates(doc))
+                       load_deliveries(doc), load_slot_updates(doc),
+                       load_pauses(doc))
     if args.compare:
         try:
             with open(args.compare) as f:

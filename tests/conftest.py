@@ -127,6 +127,40 @@ _GPT_KEYED_SPEC_CASES = (
 )
 
 
+# A second hand-over to the next `benchmark` PR (PR 36). Eight tests of the
+# benchmark's own files hold `BENCHMARK.json`'s per-layer entries to a count
+# or to an exact set: four hold a traced tiny run's line to exactly the
+# names the families shared at the time (three of them by `len(shared) ==
+# 15`), three hold a cell to `15 + its family's own`, and one holds the
+# list's last three entries to the `mhc_*` names. PR 36 appended five shared
+# readers of the host's time (egress split, the loop off the CPU, the
+# collector), a PR that changes the program may not edit a file the
+# benchmark already has, and new entries go at the end of their list. The
+# same runs, cells and list are held to the enlarged sets, nothing left out,
+# by `tests/benchmark/test_benchmark_host_time.py`, one test for each of the
+# eight. Strict: the day one passes again (a `benchmark` PR made it read
+# `shared | the family's own` from BENCHMARK.json in place of a count) this
+# hook fails the run and goes, with PR 25's `tests/benchmark/conftest.py`.
+_STALE_COUNTS = (
+    "test_benchmark_spans.py::"
+    "test_the_traced_line_holds_exactly_the_old_and_the_new_metrics",
+    "test_benchmark_mla_moe.py::"
+    "test_traced_run_reports_the_shared_layers_and_the_routers_counters",
+    "test_benchmark_swa_moe.py::"
+    "test_traced_run_reports_the_shared_layers_and_the_new_counters",
+    "test_benchmark_mhc_mla_moe.py::"
+    "test_traced_run_reports_the_shared_layers_and_the_new_counter",
+    "test_benchmark_mla_moe.py::"
+    "test_the_cell_resolves_and_its_longest_request_fits",
+    "test_benchmark_swa_moe.py::"
+    "test_the_cell_resolves_and_its_longest_request_fits",
+    "test_benchmark_mhc_mla_moe.py::"
+    "test_the_cell_resolves_and_its_longest_request_fits",
+    "test_benchmark_mhc_mla_moe.py::"
+    "test_benchmark_json_gained_one_configuration_one_cell_three_metrics",
+)
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid.endswith(_GPT_KEYED_SPEC_CASES):
@@ -134,3 +168,11 @@ def pytest_collection_modifyitems(items):
                 strict=False, raises=(AssertionError, KeyError),
                 reason="holds every configuration to the GPT family's key "
                        "names and an empty `reduced`; see tests/conftest.py"))
+        elif item.nodeid.endswith(_STALE_COUNTS):
+            # (no `raises`: two of the traced tests share the checkout's
+            # `.bench_scratch/trace` with another file's, and a run that
+            # loses its trace to the other is no news about this hand-over)
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="holds the per-layer entries to a count from before "
+                       "PR 36's five shared readers; see tests/conftest.py"))

@@ -9,6 +9,7 @@ Deterministic: engines run greedy decoding on the virtual CPU mesh with
 seeded params, and the synthetic-record tests use fixed timings.
 """
 
+import gc
 import importlib.util
 import json
 import os
@@ -516,6 +517,10 @@ def test_cancel_event_carries_steps_completed():
 # --------------------------------------------------------------------------- #
 
 _CHUNK = 8
+# A thread's CPU clock against the wall: the kernel advances the first in
+# steps, and over a busy stretch of any length it ran up to 21 us ahead of
+# the second on this sandbox.
+_CLOCK_SLACK_US = 100
 
 
 def _timeline_run(prompt_lens, max_new, mode=_stepscope.MODE_COUNTERS):
@@ -846,9 +851,11 @@ def test_slot_updates_have_a_ring_of_their_own_and_add_up_to_the_requests():
         engine.shutdown()
     doc = _stepscope.dump()
     updates = doc["slot_updates"]
-    assert updates and all(set(u) == {"model", "joined", "freed",
-                                      "start_ns", "host_ns"}
-                           for u in updates)
+    assert updates and all(set(u) - {"runq_us"} == {
+        "model", "joined", "freed", "start_ns", "host_ns", "cpu_us"}
+        for u in updates)
+    assert all(0 <= u["cpu_us"] <= u["host_ns"] // 1000 + _CLOCK_SLACK_US
+               for u in updates)
     assert all(u["model"] == "gpt_engine" and u["host_ns"] > 0
                and u["joined"] + u["freed"] > 0
                and not (u["joined"] and u["freed"]) for u in updates)
@@ -873,7 +880,9 @@ def test_slot_updates_have_a_ring_of_their_own_and_add_up_to_the_requests():
     row = analysis["models"]["gpt_engine"]["slot_updates"]
     assert row["n"] == len(updates) and row["joined"] == row["freed"] == 6
     assert 0 < row["host_ms"]["p50"] <= row["host_ms"]["p95"]
+    assert 0 <= row["off_cpu_ms"] <= row["host_total_ms"]
     assert "  slot updates" in step_report.render(analysis)
+    assert "ms in all), off-cpu" in step_report.render(analysis)
     # A dump from before the ring existed has no row, and none is rendered.
     del doc["slot_updates"]
     older = step_report.analyze(
@@ -928,10 +937,18 @@ def test_stepscope_off_stamps_nothing():
     assert _stepscope.delivery_begin(None) is None
     _stepscope.delivery_end(None)
     _stepscope.step_abandon()
-    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, 1, 2)
-    _stepscope.slot_update("m", 1, 0, 1, 2)
+    assert _stepscope.clock() is None
+    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, (1, 0, 0), (2, 0, 0))
+    _stepscope.slot_update("m", 1, 0, (1, 0, 0), (2, 0, 0))
+    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, None, None)
+    _stepscope.slot_update("m", 1, 0, None, None)
     assert _stepscope.dump()["records"] == []
     assert _stepscope.dump()["slot_updates"] == []
+    # ... and keeps nothing outside its own state: no hook on the
+    # collector, no descriptor open, and a collection leaves no record
+    gc.collect()
+    assert _stepscope._gc_hook not in gc.callbacks
+    assert _stepscope._sched_fds == {} and _stepscope.dump()["gc"] == []
 
 
 def test_a_dispatch_that_raises_leaves_no_step_open(monkeypatch):
@@ -990,3 +1007,254 @@ def test_request_ring_takes_its_length_from_the_step_ring(monkeypatch):
     requests = _stepscope.dump()["requests"]
     assert len(requests) == 3
     assert {q["outcome"] for q in requests} == {_stepscope.OUTCOME_FINISHED}
+
+
+# --------------------------------------------------------------------------- #
+# the host's share of a token: the handler's stamps, the threads' clocks,     #
+# the collector                                                               #
+# --------------------------------------------------------------------------- #
+
+
+def _engine_model():
+    from tritonclient_tpu.models.gpt_engine import GptEngineModel
+
+    return GptEngineModel(gpt.gpt_tiny(max_len=64), max_slots=2)
+
+
+def test_the_stream_handler_stamps_every_token_twice():
+    """Through the generator every engine model shares
+    (``GptEngineModel.infer``): one ``taken_ns`` and one ``resumed_ns`` a
+    token, after the delivery thread's ``out_ns`` and in order. The
+    delivery thread ends the request (and hands its record to the ring)
+    before the handler has taken the last tokens: the ring holds the record
+    and ``dump()`` the copy, so the last stamps are there. A stream that is
+    closed early has stamped what it got."""
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    model = _engine_model()
+    try:
+        tokens = []
+        for response in model.infer({
+                "INPUT_IDS": _PROMPTS_C4[0],
+                "MAX_TOKENS": np.array([7], np.int32)}):
+            tokens.append(response["OUTPUT_IDS"])
+            time.sleep(0.01)  # tpulint: disable=TPU001 - the handler lags the delivery thread on purpose
+        assert len(tokens) == 7
+        (q,) = _stepscope.dump()["requests"]
+        assert q["outcome"] == _stepscope.OUTCOME_FINISHED
+        assert len(q["taken_ns"]) == len(q["resumed_ns"]) == len(
+            q["out_ns"]) == 7
+        for out, taken, resumed in zip(q["out_ns"], q["taken_ns"],
+                                       q["resumed_ns"]):
+            assert out <= taken <= resumed
+        assert all(resumed <= taken for resumed, taken
+                   in zip(q["resumed_ns"], q["taken_ns"][1:]))
+        # a record that is in the ring already (its request has ended)
+        # still takes the handler's stamps: the ring holds the record
+        late = _stepscope.request_begin("m", _PROMPTS_C4[3], 1)
+        _stepscope.request_end(late, _stepscope.OUTCOME_FINISHED)
+        late.taken_ns.append(5)
+        late.resumed_ns.append(6)
+        assert _stepscope.dump()["requests"][-1]["taken_ns"] == [5]
+        assert _stepscope.dump()["requests"][-1]["resumed_ns"] == [6]
+        # the copy is a copy
+        q["taken_ns"].append(0)
+        assert len(_stepscope.dump()["requests"][0]["taken_ns"]) == 7
+
+        _stepscope.reset()
+        stream = model.infer({"INPUT_IDS": _PROMPTS_C4[1],
+                              "MAX_TOKENS": np.array([30], np.int32)})
+        for _ in range(3):
+            next(stream)
+        stream.close()
+        deadline = time.time() + 30
+        while (not _stepscope.dump()["requests"]
+               and time.time() < deadline):
+            time.sleep(0.02)  # tpulint: disable=TPU001 - sync test, no loop
+        (q,) = _stepscope.dump()["requests"]
+        assert q["outcome"] == _stepscope.OUTCOME_CANCELLED
+        assert len(q["taken_ns"]) == 3 and len(q["resumed_ns"]) == 2
+        assert len(q["out_ns"]) >= 3
+    finally:
+        model.engine.shutdown()
+    # its columns in scripts/step_report.py's request table
+    step_report = _load_script("step_report.py", "step_report_egress")
+    (row,) = step_report._request_rows([q])
+    assert row["wake_ms"] >= 0 and row["handler_ms"] >= 0
+    older = dict(q)
+    del older["taken_ns"], older["resumed_ns"]
+    (row,) = step_report._request_rows([older])
+    assert row["wake_ms"] is None and row["handler_ms"] is None
+
+
+def test_stepscope_off_stamps_no_token():
+    model = _engine_model()
+    try:
+        assert len(list(model.infer({
+            "INPUT_IDS": _PROMPTS_C4[0],
+            "MAX_TOKENS": np.array([4], np.int32)}))) == 4
+    finally:
+        model.engine.shutdown()
+    assert _stepscope.dump()["requests"] == []
+
+
+def test_a_stretch_that_waits_for_a_lock_is_off_the_cpu_and_a_busy_one_is_on():
+    """``wall - cpu`` of a stretch spent waiting for a lock another thread
+    holds is about the wait, and the thread's CPU time next to nothing; a
+    stretch spent computing is on the CPU for its length, but for what the
+    thread stood on a run queue (this suite's workers share their cores:
+    no wall-clock number is judged without it)."""
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    lock = threading.Lock()
+    held = threading.Event()
+
+    def hold():
+        with lock:
+            held.set()
+            time.sleep(0.25)  # tpulint: disable=TPU001 - the other thread's hold
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    held.wait(10)
+    began = _stepscope.clock()
+    with lock:
+        ended = _stepscope.clock()
+    holder.join()
+    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, began, ended, 2)
+    began = _stepscope.clock()
+    spun = time.thread_time()
+    while time.thread_time() - spun < 0.1:
+        pass
+    _stepscope.loop_state("m", _stepscope.LOOP_JOIN, began,
+                          _stepscope.clock(), 2)
+    waited, busy = _stepscope.dump()["records"]
+    assert waited["phase"] == "admit" and busy["phase"] == "join"
+    assert waited["dispatch_us"] - waited["cpu_us"] >= 150_000
+    assert waited["cpu_us"] <= 50_000
+    assert busy["cpu_us"] >= 99_000
+    asleep = (busy["dispatch_us"] - busy["cpu_us"]
+              - busy.get("runq_us", busy["dispatch_us"]))
+    assert asleep <= 20_000
+    for r in (waited, busy):
+        assert r["cpu_us"] <= r["dispatch_us"] + _CLOCK_SLACK_US
+        assert r.get("runq_us", 0) >= 0
+    # ... and scripts/step_report.py's totals and shares on the loop's rows
+    step_report = _load_script("step_report.py", "step_report_offcpu")
+    waited["phase"], busy["phase"] = "admit", "join"
+    states = step_report._loop_states([waited, busy])
+    # (the busy stretch's share is what the other workers took of its core:
+    # held to the waiting stretch's, not to a number)
+    assert states["admit"]["off_cpu_share"] > 0.5
+    assert states["join"]["off_cpu_share"] < states["admit"]["off_cpu_share"]
+    rendered = step_report.render({"models": {"m": {
+        "n": 0, "mean_us": {}, "collectives_per_step": 0, "verdict": "-",
+        "phases": {}, "loop_states": states}}})
+    assert "loop admit" in rendered and "off-cpu" in rendered
+
+
+def test_every_record_of_a_run_carries_its_threads_cpu_time():
+    """Dispatch records, loop states, slot updates and deliveries of an
+    engine run: ``cpu_us`` on each, never longer than the stretch it is of
+    (to the clocks' resolution), and the run-queue delay beside it where
+    the thread's ``schedstat`` can be read."""
+    _, _, doc = _timeline_run([19, 23, 31, 37, 12, 40], 9)
+    readable = os.path.exists(f"/proc/self/task/{threading.get_native_id()}"
+                              "/schedstat")
+    walls = (
+        [(r, r["dispatch_us"]) for r in doc["records"]]
+        + [(u, u["host_ns"] // 1000) for u in doc["slot_updates"]]
+        + [(d, (d["delivered_ns"] - d["ready_ns"]) // 1000)
+           for d in doc["deliveries"]])
+    assert len(walls) > 30
+    for record, wall_us in walls:
+        assert 0 <= record["cpu_us"] <= wall_us + _CLOCK_SLACK_US, record
+        # (the run-queue delay is the scheduler's own clock, a CPU's: on
+        # this sandbox one reading ran 140 us past its stretch's wall, so
+        # no single record is held to it)
+        assert ("runq_us" in record) == readable
+        assert record.get("runq_us", 0) >= 0
+        assert "_ready" not in record
+    # the two threads that clock themselves each opened one descriptor
+    assert 1 <= len(_stepscope._sched_fds) <= 2 or not readable
+    step_report = _load_script("step_report.py", "step_report_cpu")
+    analysis = step_report.analyze(
+        step_report.load_records(doc),
+        deliveries=step_report.load_deliveries(doc),
+        slot_updates=step_report.load_slot_updates(doc))
+    m = analysis["models"]["gpt_engine"]
+    assert m["phases"]["decode"]["off_cpu"]["off_cpu_ms"] >= 0
+    assert "off_cpu_ms" in m["deliveries"]["decode"]
+    rendered = step_report.render(analysis)
+    assert "dispatching decode" in rendered
+    assert "hand-overs off-cpu" in rendered
+    _stepscope.configure(_stepscope.MODE_OFF)
+    assert _stepscope._sched_fds == {}
+
+
+def test_runq_is_absent_where_schedstat_cannot_be_read(monkeypatch):
+    monkeypatch.setattr(_stepscope, "_SCHEDSTAT",
+                        "/nonexistent/{tid}/schedstat")
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    began = _stepscope.clock()
+    assert began[2] is None and began[1] > 0
+    time.sleep(0.002)  # tpulint: disable=TPU001 - sync test, no loop
+    ended = _stepscope.clock()
+    _stepscope.loop_state("m", _stepscope.LOOP_ADMIT, began, ended, 2)
+    _stepscope.slot_update("m", 1, 0, began, ended)
+    rec = _stepscope.step_begin("m", _stepscope.PHASE_DECODE, 0)
+    _stepscope.step_dispatched(rec)
+    _stepscope.step_end(rec)
+    doc = _stepscope.dump()
+    for record in doc["records"] + doc["slot_updates"]:
+        assert "cpu_us" in record and "runq_us" not in record
+    assert _stepscope._sched_fds == {}
+    step_report = _load_script("step_report.py", "step_report_norunq")
+    cell = step_report._off_cpu(doc["records"], step_report._dispatch_us)
+    assert "off_cpu_ms" in cell and "runq_ms" not in cell
+    assert "run-queue" not in step_report._off_cpu_text(cell)
+    # a dump from before the clocks: no cell, no text
+    assert step_report._off_cpu([{"dispatch_us": 5}],
+                                step_report._dispatch_us) is None
+    assert step_report._off_cpu_text(None) == ""
+
+
+def test_a_collection_is_recorded_while_stepscope_is_on_and_only_then():
+    """The collector's hook: registered by ``configure``, one record a
+    collection (when, how long, which generation, which thread), gone with
+    stepscope; the ring stays for the readers until ``reset``."""
+    gc.collect()
+    assert _stepscope.dump()["gc"] == []
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    assert gc.callbacks.count(_stepscope._gc_hook) == 1
+    _stepscope.configure(_stepscope.MODE_COUNTERS)      # once, not twice
+    assert gc.callbacks.count(_stepscope._gc_hook) == 1
+    before = time.monotonic_ns()
+    gc.collect()
+    after = time.monotonic_ns()
+    full = [p for p in _stepscope.dump()["gc"] if p["generation"] == 2
+            and before <= p["start_ns"] <= after]
+    assert len(full) == 1
+    (pause,) = full
+    assert set(pause) == {"start_ns", "duration_ns", "generation",
+                          "thread_ident", "thread_name"}
+    assert 0 < pause["duration_ns"] <= after - before
+    assert pause["thread_name"] == threading.current_thread().name
+    assert pause["thread_ident"] == threading.get_ident()
+    _stepscope.configure(_stepscope.MODE_OFF)
+    assert _stepscope._gc_hook not in gc.callbacks
+    n = len(_stepscope.dump()["gc"])
+    gc.collect()
+    assert len(_stepscope.dump()["gc"]) == n >= 1       # kept, not added to
+    # the operator's row
+    step_report = _load_script("step_report.py", "step_report_gc")
+    doc = _stepscope.dump()
+    assert step_report.load_pauses(doc) == doc["gc"]
+    row = step_report._collector(doc["gc"], [])
+    assert row["n"] == n and row["by_generation"]["2"] >= 1
+    assert row["worst_ms"] <= row["total_ms"] and row["seconds"][0] == 0
+    assert step_report._collector([], []) is None
+    _stepscope.reset()
+    assert _stepscope.dump()["gc"] == []

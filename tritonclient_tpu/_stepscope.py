@@ -5,8 +5,8 @@ the model ran, not where an engine *step* spent its time. This module is
 the missing layer — a low-overhead clock the decode/prefill loops in
 ``models/gpt_engine.py`` and the dynamic batcher's compute phase stamp
 where the work happens. All times are ``time.monotonic_ns()``. It keeps
-four rings (``dump()["records"]``, ``dump()["deliveries"]``,
-``dump()["slot_updates"]`` and ``dump()["requests"]``):
+five rings (``dump()["records"]``, ``dump()["deliveries"]``,
+``dump()["slot_updates"]``, ``dump()["requests"]`` and ``dump()["gc"]``):
 
 **Dispatch records** — one per device dispatch, opened on the dispatching
 thread:
@@ -33,7 +33,8 @@ thread:
   XLA dispatch of the jitted call); the same bracket is a
   ``jax.profiler.TraceAnnotation`` named ``{model}/{phase}``, so a profile
   opened in Perfetto or TensorBoard shows the host span beside the
-  device's module;
+  device's module; and what of that bracket the thread spent ON the CPU and
+  in line for one (``cpu_us``, ``runq_us``: "Off the CPU", below);
 - for a family with a routed expert layer, what the router did
   (``ROUTING_FIELDS``): ``routed_tokens``, ``experts_hit`` (distinct
   experts that got a token, summed over the expert layers and the
@@ -69,7 +70,8 @@ thread:
 delivery thread when it is done with the item, and joined to the dispatch
 that made the item by ``(model, phase, step_index)``: ``queued_ns``,
 ``taken_ns``, ``ready_ns`` (readback returned), ``delivered_ns`` (last
-token handed to its request). ``ready_ns`` is when *that thread saw* the
+token handed to its request), and ``cpu_us`` / ``runq_us`` of the hand-over
+``ready_ns`` -> ``delivered_ns``. ``ready_ns`` is when *that thread saw* the
 result, in the order it serves its two queues — NOT the device's
 completion time; no device time may be derived from it. A dispatch with no
 delivery item (a prefill chunk that finishes no prompt) has no delivery
@@ -77,15 +79,29 @@ record, and nothing is added to observe it.
 
 **Engine-loop states** (``LOOP_STATES``: ``ticket_wait``, ``idle_wait``,
 ``admit``, ``join``) — what the engine thread did between dispatches, as
-records in the same ring with ``dispatch_us`` = their duration. They overlap
-neither a dispatch record nor each other, and reach the ring only: no
-sketch, no ``/metrics`` row.
+records in the same ring with ``dispatch_us`` = their duration and the
+stretch's ``cpu_us`` / ``runq_us``. They overlap neither a dispatch record
+nor each other, and reach the ring only: no sketch, no ``/metrics`` row.
+
+**Off the CPU** — every stretch the engine loop and the delivery thread
+bracket (a loop state, a dispatch bracket, a slot update, a delivery's
+hand-over) is clocked three ways at both ends (``clock()``): the wall
+(``monotonic_ns``), the thread's own CPU time (``time.thread_time_ns``)
+and the thread's run-queue delay (second field of
+``/proc/self/task/<tid>/schedstat``, one ``os.pread`` on a descriptor the
+thread opens once). The record carries ``cpu_us`` and, where that file can
+be read, ``runq_us``. ``wall - cpu`` in ``admit``, ``join``, a dispatch
+bracket or a slot update is time the thread neither ran nor meant to wait:
+it SLEPT in line for the interpreter lock, or it was RUNNABLE and its core
+was taken; ``runq_us`` is the second part. In ``ticket_wait`` and
+``idle_wait`` the thread means to wait and the difference says nothing.
 
 **Slot-update records** — one per dispatch of the engine's slot-state
 update (``gpt_engine._update_slots``: the one program through which joins,
 frees and cancels write the per-slot device state): ``joined`` and
-``freed`` (the slots the call carried) and ``start_ns`` / ``host_ns``, the
-host time from building the call's arrays to its return. They lie INSIDE a
+``freed`` (the slots the call carried), ``start_ns`` / ``host_ns``, the
+host time from building the call's arrays to its return, and that
+stretch's ``cpu_us`` / ``runq_us``. They lie INSIDE a
 ``join`` or ``admit`` stretch, so they have a ring of their own and are no
 loop state.
 
@@ -93,6 +109,22 @@ loop state.
 ends it: receipt and core stamps copied from the request's
 ``TraceContext``, then submit, admission, first/last prefill chunk, first
 token ready, every token's hand-over, end and outcome (``RequestRecord``).
+Beside each token's hand-over (``out_ns[i]``, the delivery thread's put) the
+stream handler's thread stamps ``taken_ns[i]`` (its ``req.out.get`` has
+returned the token: it is awake and holds the interpreter lock) and
+``resumed_ns[i]`` (the response generator is resumed after the token's
+``yield``: the core's response, the protobuf message and grpcio's send of it
+are done on the server's side). The handler may still be taking tokens
+when the delivery thread ends the request, so the ring holds the record
+itself and ``dump()`` takes the copy.
+
+**Collector pauses** (``dump()["gc"]``) — while stepscope is on a
+``gc.callbacks`` hook records every collection of the interpreter's
+collector: ``start_ns``, ``duration_ns``, ``generation`` and the thread it
+ran on. A collection holds the interpreter lock, so it stalls every thread
+of the process, the engine loop included. The hook is registered by
+``configure`` and removed when stepscope goes off, as the threads'
+``schedstat`` descriptors are closed then.
 
 The module also carries a tiny in-flight plane: ``inflight_update`` tracks
 how many decode dispatches each engine currently has in flight (the
@@ -107,8 +139,9 @@ retained records), and the Perfetto exporters (``perfetto_events`` emits
 one thread-scoped track per engine thread — orphan tracks with no request
 parent, which the loaders accept). ``scripts/step_report.py`` turns a
 ``dump()`` into per-phase tables, the deliveries' queue wait and readback,
-the loop-state shares, a per-request table and — from a ``sync`` dump only
-— a dispatch-bound / device-bound / collective-bound verdict.
+the loop-state shares with their time off the CPU, the collector's row, a
+per-request table and — from a ``sync`` dump only — a dispatch-bound /
+device-bound / collective-bound verdict.
 
 Activation: ``TPU_STEPSCOPE=1`` (cheap counters), ``TPU_STEPSCOPE=sync``
 (adds ``block_until_ready`` bracketing). Off by default; the off path is
@@ -118,6 +151,7 @@ through ``sanitize.named_lock`` so the runtime sanitizer sees them.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -188,6 +222,83 @@ def _env_mode() -> str:
 _mode = _env_mode()
 
 
+# -- the calling thread's clocks -------------------------------------------- #
+
+# A thread's scheduler statistics: "<ns on a CPU> <ns runnable, waiting on a
+# run queue> <timeslices>". Read with one pread on a descriptor the thread
+# opens once; the descriptors are closed when stepscope goes off.
+_SCHEDSTAT = "/proc/self/task/{tid}/schedstat"
+_sched_lock = sanitize.named_lock("stepscope._sched_lock")
+_sched_fds: Dict[int, int] = {}     # native thread id -> descriptor
+_sched_epoch = 0                    # bumped when the descriptors are closed
+
+
+def _open_schedstat() -> Optional[int]:
+    """The calling thread's ``schedstat`` descriptor, or None where the file
+    cannot be opened (no procfs, no scheduler statistics) or stepscope is
+    off. Descriptors of threads that have ended are closed on the way."""
+    tid = threading.get_native_id()
+    with _sched_lock:
+        if _mode == MODE_OFF:
+            return None
+        alive = {t.native_id for t in threading.enumerate()}
+        for gone in [t for t in _sched_fds if t not in alive]:
+            os.close(_sched_fds.pop(gone))
+        try:
+            fd = os.open(_SCHEDSTAT.format(tid=tid), os.O_RDONLY)
+        except OSError:
+            return None
+        _sched_fds[tid] = fd
+        return fd
+
+
+def _close_schedstat():
+    """Close every thread's descriptor; a thread that reads its clocks
+    again (stepscope is on again) sees the new epoch and opens anew."""
+    global _sched_epoch
+    with _sched_lock:
+        _sched_epoch += 1
+        while _sched_fds:
+            os.close(_sched_fds.popitem()[1])
+
+
+def _runq_ns() -> Optional[int]:  # tpulint: disable=TPU009 - lock-free read of an int that only grows: a stale epoch is met by a failed pread (None) and a reopen at the next reading
+    """How long the calling thread has stood runnable on a run queue, in
+    all; None where its ``schedstat`` cannot be read."""
+    cell = getattr(_tls, "sched", None)
+    if cell is None or cell[0] != _sched_epoch:
+        cell = _tls.sched = (_sched_epoch, _open_schedstat())
+    if cell[1] is None:
+        return None
+    try:
+        return int(os.pread(cell[1], 96, 0).split()[1])
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _now() -> Tuple[int, int, Optional[int]]:
+    return time.monotonic_ns(), time.thread_time_ns(), _runq_ns()
+
+
+def clock() -> Optional[Tuple[int, int, Optional[int]]]:
+    """The calling thread's clocks at this point, for one end of a stretch
+    that ``loop_state`` or ``slot_update`` records: ``(monotonic_ns, the
+    thread's CPU time, its run-queue delay or None)``. None while stepscope
+    is off: callers pass it straight through."""
+    if _mode == MODE_OFF:
+        return None
+    return _now()
+
+
+def _off_cpu(began, ended) -> Dict[str, int]:
+    """A record's ``cpu_us`` and, where both ends read it, ``runq_us``: of
+    the stretch between two ``clock()`` readings of ONE thread."""
+    fields = {"cpu_us": (ended[1] - began[1]) // 1000}
+    if began[2] is not None and ended[2] is not None:
+        fields["runq_us"] = (ended[2] - began[2]) // 1000
+    return fields
+
+
 class StepRecord:
     """One engine dispatch. Mutated by the stepping thread until
     ``step_end`` hands it to the aggregator."""
@@ -197,6 +308,7 @@ class StepRecord:
         "lanes", "ctx_blocks", "ctx_pages", "tokens", "ctx_tokens",
         "t_begin", "t_dispatch", "t_end",
         "dispatch_us", "device_us", "other_us", "total_us",
+        "_began", "_off_cpu",
         "micro_steps",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
         "ctx_pages_global", "ctx_pages_window", "kv_held_global",
@@ -221,10 +333,14 @@ class StepRecord:
         self.ctx_pages = 0
         self.tokens = 0
         self.ctx_tokens = 0
-        self.t_begin = time.monotonic_ns()
+        # The thread's three clocks where the dispatch bracket opens; the
+        # bracket's CPU time and run-queue delay are taken where it closes.
+        self._began = _now()
+        self.t_begin = self._began[0]
         self.t_dispatch = 0
         self.t_end = 0
         self.dispatch_us = 0
+        self._off_cpu: Dict[str, int] = {}
         # sync mode only (a bracketed block_until_ready and the clamped
         # remainder); counters mode has no device clock and leaves None.
         self.device_us: Optional[int] = None
@@ -281,6 +397,7 @@ class StepRecord:
             "thread_ident": self.thread_ident,
             "thread_name": self.thread_name,
         }
+        out.update(self._off_cpu)
         if self.device_us is not None:
             out["device_us"] = self.device_us
             out["other_us"] = self.other_us
@@ -298,12 +415,15 @@ class RequestRecord:
     """One generation's timeline across the three threads that serve a
     token (server worker, engine loop, delivery). Each stamp is written by
     the one thread that owns that moment; ``request_end`` hands the record
-    to the ring once."""
+    to the ring once. The server worker (the stream's handler) goes on
+    stamping ``taken_ns`` / ``resumed_ns`` after that, until it has taken
+    the last token: the ring holds the record, ``dump()`` the copy."""
 
     __slots__ = (
         "model", "key", "recv_ns", "core_ns", "submit_ns", "admitted_ns",
         "waited_for_pages", "first_chunk_ns", "last_chunk_ns", "chunks",
-        "first_ready_ns", "out_ns", "end_ns", "outcome",
+        "first_ready_ns", "out_ns", "taken_ns", "resumed_ns", "end_ns",
+        "outcome",
     )
 
     def __init__(self, model: str, prompt, max_new: int, timestamps=None):
@@ -323,13 +443,18 @@ class RequestRecord:
         self.chunks = 0
         self.first_ready_ns: Optional[int] = None
         self.out_ns: List[int] = []
+        # The stream handler's two stamps a token: it holds the token; the
+        # generator is resumed behind the token's ``yield``.
+        self.taken_ns: List[int] = []
+        self.resumed_ns: List[int] = []
         self.end_ns: Optional[int] = None
         self.outcome: Optional[str] = None
 
     def as_dict(self) -> dict:
         out = {name: getattr(self, name) for name in self.__slots__}
         out["key"] = list(self.key)
-        out["out_ns"] = list(self.out_ns)
+        for name in ("out_ns", "taken_ns", "resumed_ns"):
+            out[name] = list(out[name])
         return out
 
 
@@ -378,8 +503,12 @@ class _Aggregator:
             self.deliveries: deque = deque(maxlen=max(ring, 1))
             # The engine loop's slot-state updates (``slot_update``).
             self.slot_updates: deque = deque(maxlen=max(ring, 1))
-            # Finished request records (RequestRecord.as_dict()).
+            # Ended requests' records (the RequestRecord itself: its
+            # stream handler may still be stamping; ``dump`` copies).
             self.requests: deque = deque(maxlen=max(ring, 1))
+            # The collector's pauses (``_gc_hook``; appended WITHOUT the
+            # lock: a collection may begin on a thread that holds it).
+            self.gc: deque = deque(maxlen=max(ring, 1))
 
     def absorb(self, rec: StepRecord):
         stages = [(STAGE_DISPATCH, rec.dispatch_us)]
@@ -438,7 +567,47 @@ def configure(new_mode: Optional[str] = None) -> str:
         _mode = new_mode
     else:
         raise ValueError(f"unknown stepscope mode: {new_mode!r}")
+    _sync_hooks()
     return _mode
+
+
+_gc_started = 0
+
+
+def _gc_hook(phase: str, info: dict):
+    """``gc.callbacks`` entry: one record a collection. The collector does
+    not nest, so one module-level start stamp serves; the ring is appended
+    to without the aggregator's lock (``_Aggregator.reset``)."""
+    global _gc_started
+    if phase == "start":
+        _gc_started = time.monotonic_ns()
+    elif _gc_started:
+        started, _gc_started = _gc_started, 0
+        thread = threading.current_thread()
+        _aggregator.gc.append({
+            "start_ns": started,
+            "duration_ns": time.monotonic_ns() - started,
+            "generation": info.get("generation"),
+            "thread_ident": thread.ident or 0, "thread_name": thread.name})
+
+
+def _sync_hooks():
+    """What stepscope keeps outside its own state while it is on, and
+    nothing of when it is off: the collector's hook and the threads'
+    ``schedstat`` descriptors."""
+    hooked = _gc_hook in gc.callbacks
+    if hooked == (_mode != MODE_OFF):
+        return
+    # Going off closes the descriptors; coming on none is open, and the new
+    # epoch makes a thread forget a try that failed while stepscope was off.
+    _close_schedstat()
+    if hooked:
+        gc.callbacks.remove(_gc_hook)
+    else:
+        gc.callbacks.append(_gc_hook)
+
+
+_sync_hooks()
 
 
 def reset():
@@ -491,8 +660,14 @@ def step_dispatched(rec: Optional[StepRecord]):
     """Mark dispatch return: host trace+dispatch of the jitted call is
     everything between ``step_begin`` and here."""
     if rec is not None:
-        rec.t_dispatch = time.monotonic_ns()
+        _stamp_dispatched(rec)
         _close_annotation(rec)
+
+
+def _stamp_dispatched(rec: StepRecord):
+    now = _now()
+    rec.t_dispatch = now[0]
+    rec._off_cpu = _off_cpu(rec._began, now)
 
 
 def step_end(rec: Optional[StepRecord], outputs=None):
@@ -509,7 +684,7 @@ def step_end(rec: Optional[StepRecord], outputs=None):
     _tls.active = None
     _close_annotation(rec)
     if rec.t_dispatch == 0:
-        rec.t_dispatch = time.monotonic_ns()
+        _stamp_dispatched(rec)
     device_ns = -1
     if _mode == MODE_SYNC and outputs is not None:
         t0 = time.monotonic_ns()
@@ -558,8 +733,9 @@ def step_routing(rec: Optional[StepRecord], counters: Optional[dict]):
 def delivery_begin(rec: Optional[StepRecord]) -> Optional[dict]:
     """Open the delivery record of the item that carries ``rec``'s result
     (stamps ``queued_ns``); None when stepscope is off. The item hands it
-    to the delivery thread, which stamps ``taken_ns``, ``ready_ns`` and
-    ``delivered_ns`` and closes it with ``delivery_end``."""
+    to the delivery thread, which stamps ``taken_ns``, ``ready_ns``
+    (``delivery_ready``) and ``delivered_ns`` (``delivery_delivered``) and
+    closes it with ``delivery_end``."""
     if rec is None:
         return None
     return {"model": rec.model, "phase": rec.phase,
@@ -568,44 +744,65 @@ def delivery_begin(rec: Optional[StepRecord]) -> Optional[dict]:
             "ready_ns": None, "delivered_ns": None}
 
 
+def delivery_ready(delivery: dict) -> int:
+    """The item's readback has returned on the delivery thread: stamps
+    ``ready_ns`` (and returns it), and keeps the thread's clocks for the
+    hand-over that starts here."""
+    now = delivery["_ready"] = _now()
+    delivery["ready_ns"] = now[0]
+    return now[0]
+
+
+def delivery_delivered(delivery: dict):
+    """The item's last token is handed to its request: stamps
+    ``delivered_ns`` and the hand-over's ``cpu_us`` / ``runq_us``."""
+    now = _now()
+    delivery["delivered_ns"] = now[0]
+    delivery.update(_off_cpu(delivery.pop("_ready", now), now))
+
+
 def delivery_end(delivery: Optional[dict]):
     """The delivery thread is done with the item: its record enters the
     ``deliveries`` ring."""
     if delivery is not None:
+        delivery.pop("_ready", None)    # a hand-over that raised
         with _aggregator._lock:
             _aggregator.deliveries.append(delivery)
 
 
-def loop_state(model: str, state: str, start_ns: int, end_ns: int,
-               slots: int = 0):
+def loop_state(model: str, state: str, began, ended, slots: int = 0):
     """One stretch of an engine-loop state (``LOOP_STATES``), into the
-    ring only. The caller keeps stretches from overlapping a dispatch
-    record or each other; ``start_ns`` 0 (stepscope came on mid-stretch)
-    and empty stretches are dropped."""
-    if _mode == MODE_OFF or not start_ns or end_ns <= start_ns:
+    ring only, between two ``clock()`` readings of the loop's thread: its
+    duration, and what of it the thread spent on the CPU and in line for
+    one. The caller keeps stretches from overlapping a dispatch record or
+    each other; a stretch whose either end was read with stepscope off (it
+    came on or went off mid-stretch) and an empty one are dropped."""
+    if _mode == MODE_OFF or not began or not ended or ended[0] <= began[0]:
         return
     thread = threading.current_thread()
-    duration_us = (end_ns - start_ns) // 1000
+    duration_us = (ended[0] - began[0]) // 1000
     record = {
         "model": model, "phase": state, "step_index": 0, "batch_size": 0,
-        "slots": slots, "start_ns": start_ns, "dispatch_us": duration_us,
+        "slots": slots, "start_ns": began[0], "dispatch_us": duration_us,
         "total_us": duration_us, "micro_steps": 0, "collectives": {},
         "thread_ident": thread.ident or 0, "thread_name": thread.name,
     }
+    record.update(_off_cpu(began, ended))
     with _aggregator._lock:
         _aggregator.ring.append(record)
 
 
-def slot_update(model: str, joined: int, freed: int, start_ns: int,
-                end_ns: int):
+def slot_update(model: str, joined: int, freed: int, began, ended):
     """One dispatch of the engine's slot-state update: how many slots it
-    joined and freed, and the host time it took the engine loop from
-    building the arrays to the call's return. ``start_ns`` 0 (stepscope
-    was off when the call began) records nothing."""
-    if _mode == MODE_OFF or not start_ns:
+    joined and freed, the host time it took the engine loop from building
+    the arrays to the call's return (between two ``clock()`` readings),
+    and what of that the thread spent on the CPU and in line for one. An
+    end read with stepscope off records nothing."""
+    if _mode == MODE_OFF or not began or not ended:
         return
     record = {"model": model, "joined": joined, "freed": freed,
-              "start_ns": start_ns, "host_ns": end_ns - start_ns}
+              "start_ns": began[0], "host_ns": ended[0] - began[0]}
+    record.update(_off_cpu(began, ended))
     with _aggregator._lock:
         _aggregator.slot_updates.append(record)
 
@@ -626,13 +823,14 @@ def request_begin(model: str, prompt, max_new: int,
 
 def request_end(rec: Optional[RequestRecord], outcome: str):
     """The request ended (its terminator or error is about to be put):
-    stamp and hand the record to the ring, once."""
+    stamp and hand the record to the ring, once. The record itself: its
+    stream handler has not yet taken the last tokens."""
     if rec is None or rec.end_ns is not None:
         return
     rec.end_ns = time.monotonic_ns()
     rec.outcome = outcome
     with _aggregator._lock:
-        _aggregator.requests.append(rec.as_dict())
+        _aggregator.requests.append(rec)
 
 
 def note_collective(op: str, count: int = 1, nbytes: int = 0):
@@ -842,14 +1040,15 @@ def perfetto_events(epoch_ns: int) -> List[dict]:
 def dump() -> dict:
     """Self-describing document ``scripts/step_report.py`` loads: the
     recent-step ring (dispatch records and loop states), the delivery
-    thread's ring, the slot-state updates' ring, the finished requests'
-    ring, plus aggregate totals."""
+    thread's ring, the slot-state updates' ring, the ended requests' ring
+    (copied here), the collector's pauses, plus aggregate totals."""
     agg = _aggregator
     with agg._lock:
         records = list(agg.ring)
         deliveries = list(agg.deliveries)
         slot_updates = list(agg.slot_updates)
-        requests = list(agg.requests)
+        requests = [r.as_dict() for r in agg.requests]
+        pauses = list(agg.gc)
         step_counts = {
             f"{model}|{phase}": count
             for (model, phase), count in sorted(agg.step_counts.items())
@@ -878,6 +1077,7 @@ def dump() -> dict:
         "deliveries": deliveries,
         "slot_updates": slot_updates,
         "requests": requests,
+        "gc": pauses,
         "step_counts": step_counts,
         "collectives": collectives,
         "kv_bytes": kv_bytes,

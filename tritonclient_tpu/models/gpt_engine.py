@@ -759,7 +759,7 @@ class _Distributor:
         nxt_np = np.asarray(nxt_dev)
         ready_ns = 0
         if delivery is not None:
-            ready_ns = delivery["ready_ns"] = time.monotonic_ns()
+            ready_ns = _stepscope.delivery_ready(delivery)
         rows = nxt_np if nxt_np.ndim == 2 else nxt_np[None]
         finished = []
         for t in range(rows.shape[0]):
@@ -796,7 +796,7 @@ class _Distributor:
             with self._engine._cv:
                 self._engine._cv.notify_all()
         if delivery is not None:
-            delivery["delivered_ns"] = time.monotonic_ns()
+            _stepscope.delivery_delivered(delivery)
 
 
 # The columns of ``_update_slots``' one host-built argument, ``[S, 7 +
@@ -1247,7 +1247,7 @@ class GenerationEngine:
         the executable is the same whatever the burst carries; stepscope
         gets one record a call (how many slots, and what the call cost
         this thread)."""
-        began = time.monotonic_ns() if _stepscope.enabled() else 0
+        began = _stepscope.clock()
         writes = np.zeros((self.max_slots, _W_ROW + self._table_width),
                           np.int32)
         writes[list(frees), _W_FREED] = 1
@@ -1263,7 +1263,7 @@ class GenerationEngine:
             self._btabs, self._tokens, self._pos, self._seeds, self._steps,
             self._temps, self._topks, firsts, writes)
         _stepscope.slot_update(self._scope_name, len(joins), len(frees),
-                               began, time.monotonic_ns())
+                               began, _stepscope.clock())
 
     def _table_row(self, slot: int, blocks, n_ctx: int) -> np.ndarray:
         """A slot's table row as the family's steps take it: its ring's
@@ -1470,7 +1470,7 @@ class GenerationEngine:
         writes there, and no later dispatch reads the old rows). When the
         pass did something the stretch is stepscope's ``admit`` loop state;
         returns whether it did."""
-        began = time.monotonic_ns() if _stepscope.enabled() else 0
+        began = _stepscope.clock()
         freed = self._process_frees() + self._release_cancelled()
         if freed:
             self._write_slot_state(self._tokens, frees=freed)
@@ -1478,7 +1478,7 @@ class GenerationEngine:
         worked = bool(freed) or admitted
         if worked:
             _stepscope.loop_state(self._scope_name, _stepscope.LOOP_ADMIT,
-                                  began, time.monotonic_ns(),
+                                  began, _stepscope.clock(),
                                   self.max_slots)
         return worked
 
@@ -1642,7 +1642,7 @@ class GenerationEngine:
             return True
         # stepscope's ``join`` loop state: from here to the hand-over of
         # the first tokens.
-        joining_from = time.monotonic_ns() if scope is not None else 0
+        joining_from = _stepscope.clock() if scope is not None else None
         # A synchronized churn burst (batched steps finish batchmates
         # together, their clients resubmit together) completes many
         # prefills at one loop top. Whatever their number, the burst is
@@ -1670,7 +1670,7 @@ class GenerationEngine:
             first_token=True, scope=scope,
         )
         _stepscope.loop_state(self._scope_name, _stepscope.LOOP_JOIN,
-                              joining_from, time.monotonic_ns(),
+                              joining_from, _stepscope.clock(),
                               self.max_slots)
         return True
 
@@ -1815,12 +1815,12 @@ class GenerationEngine:
                 with self._cv:
                     if (self._admit.empty() and self._dist.free_q.empty()
                             and self._pending is None):
-                        began = (time.monotonic_ns()
-                                 if _stepscope.enabled() else 0)
+                        # stepscope's ``idle_wait`` loop state.
+                        began = _stepscope.clock()
                         got = self._cv.wait(timeout=5.0)
                         _stepscope.loop_state(
                             self._scope_name, _stepscope.LOOP_IDLE_WAIT,
-                            began, time.monotonic_ns(), self.max_slots)
+                            began, _stepscope.clock(), self.max_slots)
                         if (not got and self._admit.empty()
                                 and self._dist.free_q.empty()
                                 and self._pending is None):
@@ -1838,7 +1838,7 @@ class GenerationEngine:
             # that misses until the ticket comes. Work done between tries
             # (an admission, a chunk dispatch) closes the stretch before
             # it and opens another after, so no two records overlap.
-            waiting_from = time.monotonic_ns() if _stepscope.enabled() else 0
+            waiting_from = _stepscope.clock()
             missed = False
             got_ticket = self._dist.try_ticket(timeout=0.005)
             while not got_ticket:
@@ -1846,19 +1846,19 @@ class GenerationEngine:
                 if self._stopping or self._broken is not None:  # tpulint: disable=TPU002,TPU009 - single-transition stop/broken flags polled lock-free by the loop
                     break
                 missed = True
-                missed_at = time.monotonic_ns() if waiting_from else 0
+                missed_at = _stepscope.clock() if waiting_from else None
                 worked = self._housekeep()
                 dispatched = self._advance_prefills()
                 if (worked or dispatched) and waiting_from:
                     _stepscope.loop_state(
                         self._scope_name, _stepscope.LOOP_TICKET_WAIT,
                         waiting_from, missed_at, self.max_slots)
-                    waiting_from = time.monotonic_ns()
+                    waiting_from = _stepscope.clock()
                 got_ticket = self._dist.try_ticket(timeout=0.005)
             if missed:
                 _stepscope.loop_state(
                     self._scope_name, _stepscope.LOOP_TICKET_WAIT,
-                    waiting_from, time.monotonic_ns(), self.max_slots)
+                    waiting_from, _stepscope.clock(), self.max_slots)
             if not got_ticket:
                 continue  # stopping/broken handled at loop top
             # Recompute: slots whose prefill completed during the ticket
@@ -2047,6 +2047,10 @@ class GptEngineModel(Model):
                                      top_k=top_k, seed=gen_seed,
                                      cancel_event=cancel_event,
                                      timestamps=timestamps)
+            # stepscope's two stamps a token on this thread (None while it
+            # is off): the handler holds the token; the transport has sent
+            # it and asks for the next.
+            span = req.span
             try:
                 while True:
                     token = req.out.get(timeout=300)
@@ -2054,7 +2058,11 @@ class GptEngineModel(Model):
                         return
                     if isinstance(token, BaseException):
                         raise token
+                    if span is not None:
+                        span.taken_ns.append(time.monotonic_ns())
                     yield {"OUTPUT_IDS": token}
+                    if span is not None:
+                        span.resumed_ns.append(time.monotonic_ns())
             finally:
                 req.cancelled = True
 
