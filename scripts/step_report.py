@@ -462,11 +462,15 @@ def _collector(pauses: List[dict], records: List[dict]) -> Optional[dict]:
 
 
 def _pages_read_share(recs: List[dict]) -> Optional[float]:
-    """Σ ``ctx_pages`` / Σ table pages (``lanes`` x ``ctx_blocks`` a
+    """Σ pages read / Σ table pages (``lanes`` x ``ctx_blocks`` a
     micro-step) of a phase's dispatches: the share of the tables' width
-    that lay under the lanes' lengths. None where no record carries
-    ``ctx_pages`` (a phase with no block table, an older dump)."""
-    pages = sum(int(r.get("ctx_pages", 0)) for r in recs)
+    that the attention read. A paged kernel reads what lies under the
+    lanes' lengths (``ctx_pages``); a family that gathers its table, the
+    width it took of every lane's (``pages_gathered``: 1.0 where it takes
+    the whole table). None where no record carries either (a phase with no
+    block table, an older dump)."""
+    pages = sum(int(r.get("pages_gathered", r.get("ctx_pages", 0)))
+                for r in recs)
     table = sum(int(r.get("lanes", 0)) * int(r.get("ctx_blocks", 0))
                 * max(int(r.get("micro_steps", 1) or 1), 1) for r in recs)
     if not pages or not table:

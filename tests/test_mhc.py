@@ -344,10 +344,11 @@ def test_a_wide_table_is_attended_a_table_at_a_time_and_gives_the_same(
 # names or line numbers in it). PR 35 did: the routed experts' product
 # became the kernel of ``ops/grouped_experts.py`` (interpreted here) in
 # place of three ``lax.ragged_dot``; recorded with ``_program_digests`` on
-# its final tree.
+# its final tree. PR 37 did for the two decode programs (their attention
+# became a ``lax.switch`` over the table's widths); the chunk's is PR 35's.
 _ONE_STREAM_PROGRAMS = {
-    "decode": "6f8df725e5eddbbc",
-    "fused_2": "8869fc03991d740d",
+    "decode": "aa358c5658c05f37",
+    "fused_2": "e93f44f6c5885f08",
     "prefill_chunk": "a0061543d29cf202",
 }
 

@@ -5,6 +5,7 @@ plain reference's full forward pass ON LOGITS; absorbed against expanded
 attention; the router's choice by ``s + b`` and weight by ``s``; the seam
 the engine holds the family by. Nothing here is a device number."""
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -253,12 +254,150 @@ def test_requests_batched_together_get_the_tokens_they_get_alone(tiny):
     assert engine._pool.used_count == 1          # the scratch page
 
 
-@pytest.mark.parametrize("program", ["decode", "fused_2", "prefill_chunk"])
-def test_the_latent_pool_is_the_carry_of_both_layer_scans(tiny, program):
-    """One pool of [layers, pages, 16, latent padded to whole lane tiles],
-    carried through the dense layers' scan and the expert layers' scan and
-    neither scanned in nor stacked out (ROADMAP A10, as the GPT pools)."""
+_PAGE = 16      # the engine's default page, positions
+
+
+def _bank_by_hand(cfg, held, seed=0):
+    """A slot bank as the engine would hold it, by hand: slot s has ``held[s]``
+    positions in the cache and is about to write the next (its ``pos``); its
+    table row names pages of its own for those and four more positions, the
+    rest the scratch page. The pool holds seeded latents (the padding lanes
+    zero, as a step writes them). ``None``: a freed slot, its row the
+    scratch page throughout and its ``pos`` STALE, near the table's end.
+    Returns (pool, btabs, tokens, pos)."""
+    rng = np.random.default_rng(seed)
+    slots, wide = len(held), cfg.max_len // _PAGE
+    pool = rng.standard_normal(
+        (cfg.n_layers, 1 + slots * wide, _PAGE, cfg.pool_width))
+    pool[..., cfg.latent_dim:] = 0.0
+    btabs = np.zeros((slots, wide), np.int32)
+    for s, n in enumerate(held):
+        if n is not None:
+            pages = -(-(n + 4) // _PAGE)
+            btabs[s, :pages] = 1 + s * wide + np.arange(pages)
+    pos = [cfg.max_len - 8 if n is None else n for n in held]
+    return (jnp.asarray(pool, jnp.float32), jnp.asarray(btabs),
+            jnp.asarray(rng.integers(0, cfg.vocab_size, slots), jnp.int32),
+            jnp.asarray(pos, jnp.int32))
+
+
+def _decode_by_hand(cfg, params, bank, n_steps, monkeypatch):
+    """``n_steps`` decode micro-steps of ``bank`` in ONE program (1: the
+    plain step; more: the fused one). Returns (tokens [n_steps, S], the
+    logits each micro-step picked from, the positions each layer's
+    attention gathered a table, sorted)."""
+    logits, gathered = {}, []
+    pick, attend = mla_moe._pick, mla_moe._attend
+
+    def spy_pick(lg, seeds, steps, temps, topks):
+        jax.debug.callback(
+            lambda lg, steps: logits.__setitem__(int(steps[0]),
+                                                 np.asarray(lg)), lg, steps)
+        return pick(lg, seeds, steps, temps, topks)
+
+    def spy_attend(q_nope, q_rope, table, mask, lp, cfg, absorbed):
+        # Inside a branch: only the branch TAKEN calls back.
+        jax.debug.callback(lambda n: gathered.append(int(n)),
+                           jnp.int32(table.shape[1]))
+        return attend(q_nope, q_rope, table, mask, lp, cfg, absorbed)
+
+    monkeypatch.setattr(mla_moe, "_pick", spy_pick)
+    monkeypatch.setattr(mla_moe, "_attend", spy_attend)
+    pool, btabs, tokens, pos = bank
+    z = jnp.zeros_like(pos)
+    sampling = (z, z, jnp.zeros(pos.shape, jnp.float32), z)
+    if n_steps == 1:
+        nxt, _, _ = jax.jit(lambda *a: mla_moe._decode_step_latent(
+            *a, cfg=cfg, block_size=_PAGE))(
+                params, pool, btabs, tokens, pos, *sampling)
+        toks = nxt[None]
+    else:
+        toks = jax.jit(lambda *a: mla_moe._decode_multi_step_latent(
+            *a, cfg=cfg, block_size=_PAGE, n_steps=n_steps))(
+                params, pool, btabs, tokens, pos, *sampling)[0]
+    toks = np.asarray(toks)
+    jax.effects_barrier()
+    return toks, logits, sorted(gathered)
+
+
+def test_the_decode_widths_are_the_tables_halvings_and_the_least_that_holds():
+    assert mla_moe.decode_widths(256) == (16, 32, 64, 128, 256)
+    assert mla_moe.decode_widths(512) == (32, 64, 128, 256, 512)
+    assert mla_moe.decode_widths(8) == (1, 2, 4, 8)
+    assert mla_moe.decode_widths(6) == (3, 6)
+    taken = [mla_moe.decode_width(n, 8, _PAGE)
+             for n in (1, 16, 17, 32, 33, 64, 65, 128, 500)]
+    assert taken == [0, 0, 1, 1, 2, 2, 3, 3, 3]
+    model = mla_moe.MlaMoePaged(mla_moe.mla_moe_tiny())
+    assert [model.pages_gathered(n, 8, _PAGE) for n in (1, 17, 64, 65)] == [
+        1, 2, 4, 8]
+    # the step's own reading of the same rule, on a traced scalar
+    assert [int(jax.jit(lambda n: mla_moe.decode_width(n, 8, _PAGE))(n))
+            for n in (16, 17, 65)] == [0, 1, 3]
+
+
+@pytest.mark.parametrize("n_steps", [1, 2], ids=["decode", "fused_2"])
+@pytest.mark.parametrize("longest,width", [(9, 16), (20, 32), (50, 64),
+                                           (100, 128)])
+def test_decode_attends_the_least_width_over_the_longest_live_context(
+        tiny, monkeypatch, longest, width, n_steps):
+    """A bank whose longest context falls in each of the table's widths in
+    turn (8 pages: 16, 32, 64, 128 positions): every layer of every
+    micro-step gathers that width and no other, and the logits are the
+    whole-width form's (the keys dropped were masked: exact zeros in the
+    sums). The third slot is FREED, its position stale at 120: it widens
+    nothing."""
     cfg, params = tiny
+    bank = _bank_by_hand(cfg, [longest, 7, None])
+    toks, narrow, gathered = _decode_by_hand(cfg, params, bank, n_steps,
+                                             monkeypatch)
+    assert gathered == [width] * (cfg.n_layers * n_steps)
+    monkeypatch.setattr(mla_moe, "decode_widths", lambda pages: (pages,))
+    toks_whole, whole, gathered = _decode_by_hand(cfg, params, bank, n_steps,
+                                                  monkeypatch)
+    assert gathered == [cfg.max_len] * (cfg.n_layers * n_steps)
+    assert sorted(narrow) == sorted(whole) == list(range(n_steps))
+    for step in range(n_steps):
+        np.testing.assert_allclose(narrow[step][:2], whole[step][:2],
+                                   atol=1e-5, rtol=1e-5)
+    assert (toks[:, :2] == toks_whole[:, :2]).all()
+
+
+def test_a_bank_with_no_live_slot_runs_at_the_least_width(tiny, monkeypatch):
+    cfg, params = tiny
+    bank = _bank_by_hand(cfg, [None, None])
+    toks, logits, gathered = _decode_by_hand(cfg, params, bank, 2,
+                                             monkeypatch)
+    assert gathered == [_PAGE] * (cfg.n_layers * 2)
+    assert toks.shape == (2, 2) and all(
+        np.isfinite(lg).all() for lg in logits.values())
+
+
+def test_a_context_that_crosses_a_widths_edge_inside_a_fused_dispatch(
+        tiny, monkeypatch):
+    """31 positions held: four fused micro-steps attend 32, 33, 34 and 35,
+    so the first takes the 32-wide branch and the rest the 64-wide one,
+    chosen from the carried ``pos`` on the device; the tokens are those of
+    four plain steps."""
+    cfg, params = tiny
+    pool, btabs, tokens, pos = _bank_by_hand(cfg, [31, 12])
+    fused, _, gathered = _decode_by_hand(
+        cfg, params, (pool, btabs, tokens, pos), 4, monkeypatch)
+    assert gathered == [32] * cfg.n_layers + [64] * (3 * cfg.n_layers)
+    z = jnp.zeros_like(pos)
+    step = jax.jit(lambda *a: mla_moe._decode_step_latent(
+        *a, cfg=cfg, block_size=_PAGE))
+    plain = []
+    for i in range(4):
+        tokens, pool, _ = step(params, pool, btabs, tokens, pos + i, z, z + i,
+                               jnp.zeros(pos.shape, jnp.float32), z)
+        plain.append(np.asarray(tokens))
+    assert (fused == np.stack(plain)).all()
+
+
+def _traced_program(cfg, params, program):
+    """(jaxpr, the lowered module's first 200 characters, the pool) of one
+    of a two-slot engine's three step programs."""
     engine = GenerationEngine(mla_moe.MlaMoePaged(cfg), params, max_slots=2,
                               prefill_chunk=8)
     try:
@@ -279,6 +418,16 @@ def test_the_latent_pool_is_the_carry_of_both_layer_scans(tiny, program):
         name = fn.lower(*args).as_text()[:200]
     finally:
         engine.shutdown()
+    return jaxpr, name, pool
+
+
+@pytest.mark.parametrize("program", ["decode", "fused_2", "prefill_chunk"])
+def test_the_latent_pool_is_the_carry_of_both_layer_scans(tiny, program):
+    """One pool of [layers, pages, 16, latent padded to whole lane tiles],
+    carried through the dense layers' scan and the expert layers' scan and
+    neither scanned in nor stacked out (ROADMAP A10, as the GPT pools)."""
+    cfg, params = tiny
+    jaxpr, name, pool = _traced_program(cfg, params, program)
     assert tuple(pool.shape) == (3, 1 + 2 * (cfg.max_len // 16), 16, 128)
     assert cfg.latent_dim == 40 and cfg.pool_width == 128
     assert {"decode": "module @jit_mla_moe_decode_step ",
@@ -305,6 +454,46 @@ def test_the_latent_pool_is_the_carry_of_both_layer_scans(tiny, program):
     assert layer_scans == 2
 
 
+def _eqns(jaxpr, inside_branch=None):
+    """(equation, the index of the ``cond`` branch it lies in or None) of a
+    jaxpr and every jaxpr nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_branch
+        for key, value in eqn.params.items():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            for i, sub in enumerate(subs):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    branch = (i if eqn.primitive.name == "cond"
+                              and key == "branches"
+                              and len(subs) > 2 else inside_branch)
+                    yield from _eqns(sub, branch)
+
+
+@pytest.mark.parametrize("program", ["decode", "fused_2"])
+def test_a_decode_program_gathers_the_whole_table_in_its_widest_branch_alone(
+        tiny, program):
+    """Each layer scan's attention is one ``cond`` over the table's four
+    widths (8 pages: 1, 2, 4, 8), branch i gathering ``[slots, width_i]``
+    pages of the pool and nothing wider; outside the branches the program
+    gathers nothing of a table's shape, and no equation anywhere makes a
+    pool-shaped copy."""
+    cfg, params = tiny
+    jaxpr, _, pool = _traced_program(cfg, params, program)
+    widths = mla_moe.decode_widths(cfg.max_len // 16)
+    assert widths == (1, 2, 4, 8)
+    page = tuple(pool.shape[2:])
+    gathered = {}       # branch -> the page counts its gathers take a slot
+    for eqn, branch in _eqns(jaxpr):
+        assert eqn.primitive.name != "copy"
+        for out in eqn.outvars:
+            shape = tuple(out.aval.shape)
+            if eqn.primitive.name == "gather" and shape[2:] == page:
+                assert shape[0] == 2
+                gathered.setdefault(branch, set()).add(shape[1])
+    assert gathered == {i: {w} for i, w in enumerate(widths)}
+
+
 def test_a_mesh_is_refused_with_the_reason(tiny):
     cfg, params = tiny
     from tritonclient_tpu.parallel import build_mesh
@@ -314,16 +503,26 @@ def test_a_mesh_is_refused_with_the_reason(tiny):
         GenerationEngine(mla_moe.MlaMoePaged(cfg), params, mesh=mesh)
 
 
+@contextlib.contextmanager
+def _counting():
+    """stepscope in counters mode with empty rings; then as it was."""
+    was = _stepscope.mode()
+    _stepscope.configure(_stepscope.MODE_COUNTERS)
+    _stepscope.reset()
+    try:
+        yield
+    finally:
+        _stepscope.configure(was)
+        _stepscope.reset()
+
+
 def test_dispatch_records_gain_what_the_router_did(tiny):
     """stepscope on: the delivery thread reads each step's histogram behind
     its tokens and the dispatch record in the ring gains the counters, chunk
     dispatches that finish no prompt included; the GPT family's records
     gain nothing."""
     cfg, params = tiny
-    was = _stepscope.mode()
-    _stepscope.configure(_stepscope.MODE_COUNTERS)
-    _stepscope.reset()
-    try:
+    with _counting():
         engine = GenerationEngine(mla_moe.MlaMoePaged(cfg), params,
                                   max_slots=2, prefill_chunk=8,
                                   scope_name="mla_moe_test")
@@ -340,9 +539,6 @@ def test_dispatch_records_gain_what_the_router_did(tiny):
                 time.sleep(0.02)  # tpulint: disable=TPU001
         finally:
             engine.shutdown()
-    finally:
-        _stepscope.configure(was)
-        _stepscope.reset()
     chunks = [r for r in records if r["phase"] == "prefill_chunk"]
     decodes = [r for r in records if r["phase"] == "decode"
                and r["routed_tokens"]]
@@ -361,6 +557,54 @@ def test_dispatch_records_gain_what_the_router_did(tiny):
     # one request alone: a decode micro-step routes one token
     assert {r["routed_tokens"] // r["micro_steps"] for r in decodes} == {1}
     assert any(r["micro_steps"] > 1 for r in decodes)
+
+
+def test_decode_records_stamp_the_width_taken_and_the_report_its_share(tiny):
+    """A gathering family's dispatch record carries the table entries its
+    attention gathered: for a decode dispatch every slot of the bank times
+    the width the step's own rule takes over the longest live context,
+    micro-step by micro-step, and ``kv_bytes`` are those pages' bytes.
+    ``step_report.py``'s pages-read share of the table is then a quarter
+    for a bank of 20 to 28 positions of 128 (2 pages of 8), 1 for one of
+    over 64, and 1 for the chunks, which gather the table they are given."""
+    from test_stepscope import _load_script
+
+    cfg, params = tiny
+    model = mla_moe.MlaMoePaged(cfg)
+    with _counting():
+        engine = GenerationEngine(model, params, max_slots=2,
+                                  prefill_chunk=32, scope_name="mla_moe_w")
+        try:
+            for held, new in ((19, 9), (70, 6)):
+                prompt = np.arange(1, held + 1, dtype=np.int32)[None]
+                assert len(_collect(engine.submit(prompt, new))) == new
+        finally:
+            engine.shutdown()
+        doc = _stepscope.dump()
+    records = [r for r in doc["records"] if r["model"] == "mla_moe_w"
+               and r["phase"] in ("decode", "prefill_chunk")]
+    table = cfg.max_len // _PAGE
+    for r in records:
+        assert r["kv_bytes"] == r["pages_gathered"] * model.block_bytes(_PAGE)
+        if r["phase"] == "decode":      # one request: its length the longest
+            assert r["pages_gathered"] == r["slots"] * sum(
+                model.pages_gathered(r["ctx_tokens"] + i, table, _PAGE)
+                for i in range(r["micro_steps"]))
+        else:
+            assert r["pages_gathered"] == r["lanes"] * r["ctx_blocks"]
+    step_report = _load_script("step_report.py", "step_report_widths")
+
+    def share(phase, keep):
+        kept = dict(doc, records=[r for r in records
+                                  if r["phase"] == phase and keep(r)])
+        assert kept["records"]
+        analysis = step_report.analyze(step_report.load_records(kept))
+        return analysis["models"]["mla_moe_w"]["phases"][phase][
+            "pages_read_share"]
+
+    assert share("decode", lambda r: r["ctx_tokens"] < 32) == 0.25
+    assert share("decode", lambda r: r["ctx_tokens"] > 64) == 1.0
+    assert share("prefill_chunk", lambda r: True) == 1.0
 
 
 def test_routing_counters_from_a_hand_histogram():

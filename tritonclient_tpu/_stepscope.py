@@ -312,7 +312,7 @@ class StepRecord:
         "micro_steps",
         "collectives", "kv_bytes", "thread_ident", "thread_name",
         "ctx_pages_global", "ctx_pages_window", "kv_held_global",
-        "kv_held_window", "attn_straight",
+        "kv_held_window", "attn_straight", "pages_gathered",
         "_annotation", "_entry",
     )
 
@@ -352,8 +352,8 @@ class StepRecord:
         # op -> [count, bytes]
         self.collectives: Dict[str, List[int]] = {}
         # Paged-KV bytes this step's attention read: ``ctx_pages`` x block
-        # bytes where the family's kernel reads the pages held, the
-        # block-table extent x block bytes where it gathers the table; the
+        # bytes where the family's kernel reads the pages held,
+        # ``pages_gathered`` x block bytes where it gathers the table; the
         # engine sets it on the thread-owned record before step_end.
         self.kv_bytes = 0
         # A family with window layers only (set by the engine): the pages
@@ -364,6 +364,9 @@ class StepRecord:
         # A family whose attention is the paged kernel only (set by the
         # engine): which of the kernel's two bodies this executable holds.
         self.attn_straight: Optional[bool] = None
+        # A family that gathers its table only (set by the engine): the
+        # entries gathered, every lane's and micro-step's width taken.
+        self.pages_gathered: Optional[int] = None
         thread = threading.current_thread()
         self.thread_ident = thread.ident or 0
         self.thread_name = thread.name
@@ -403,6 +406,8 @@ class StepRecord:
             out["other_us"] = self.other_us
         if self.attn_straight is not None:
             out["attn_straight"] = self.attn_straight
+        if self.pages_gathered is not None:
+            out["pages_gathered"] = self.pages_gathered
         if self.ctx_pages_window is not None:
             out.update(ctx_pages_global=self.ctx_pages_global,
                        ctx_pages_window=self.ctx_pages_window,

@@ -335,8 +335,9 @@ class PagedModel:
 
     cfg = None      # has ``max_len``: the positions served (the table's width)
     # Whether the family's attention reads the pages under a lane's length
-    # (a paged kernel) or gathers its table's whole width: what a
-    # dispatch's ``kv_bytes`` are reckoned from.
+    # (a paged kernel) or gathers its table: what a dispatch's ``kv_bytes``
+    # are reckoned from. A gathering family's prefill chunk takes the whole
+    # width of the table it is given, its decode step ``pages_gathered``.
     reads_pages_held = False
     # Whether a prompt's full pages may be handed to a later request with
     # the same prefix. A family whose layers keep state that those pages do
@@ -378,6 +379,14 @@ class PagedModel:
         """Pages a layer of each kind reads for ``rows`` query rows of one
         table that end at context ``length``: ``(global, window)``."""
         return -(-length // block_size), 0
+
+    def pages_gathered(self, longest: int, table_pages: int,
+                       block_size: int) -> int:
+        """Table entries a decode micro-step gathers for EACH slot of a bank
+        whose longest live context is ``longest`` positions, in a family
+        that gathers its table: the whole width, unless the family's step
+        takes a narrower one (the latent family's does)."""
+        return table_pages
 
     def attends_straight(self, rows: int) -> Optional[bool]:
         """Whether the family's paged-attention kernel takes its
@@ -1042,7 +1051,7 @@ class GenerationEngine:
 
         atexit.register(lambda: (lambda e: e and e.shutdown())(ref()))
 
-    def _note_attention(self, scope, lanes, table_pages: int, slots,
+    def _note_attention(self, scope, lanes, gathered: int, slots,
                         rows_per_table: int = 1):
         """What a dispatch's attention reads, on its record. ``lanes``:
         ``(context length, query rows)`` of each real lane (and micro-step);
@@ -1052,8 +1061,9 @@ class GenerationEngine:
         ``ctx_pages``: the table entries under the lanes' lengths, what a
         global layer's kernel visits. ``kv_bytes``: by kind, the pages the
         kernel visits where the family's kernel reads the pages held (hit
-        pages too); every lane's and micro-step's whole table extent
-        (``table_pages``) where it gathers the table. And on a family with
+        pages too); where it gathers the table, the entries gathered
+        (``gathered``, ``pages_gathered`` on the record: every lane's and
+        micro-step's width taken of its table). And on a family with
         window layers the split by kind: pages read, and bytes held by the
         requests of ``slots``."""
         bs, most = self.block_size, self._max_blocks
@@ -1062,10 +1072,12 @@ class GenerationEngine:
         read = (sum(min(g, most) for g, _ in each),
                 sum(min(w, most) for _, w in each))
         scope.ctx_pages = read[0]
-        scope.kv_bytes = (
-            sum(n * b for n, b in zip(read, self._kind_bytes))
-            if self._model.reads_pages_held
-            else self._block_kv_bytes * table_pages)
+        if self._model.reads_pages_held:
+            scope.kv_bytes = sum(
+                n * b for n, b in zip(read, self._kind_bytes))
+        else:
+            scope.pages_gathered = gathered
+            scope.kv_bytes = self._block_kv_bytes * gathered
         scope.attn_straight = self._model.attends_straight(rows_per_table)
         if not self._ring:
             return
@@ -1886,10 +1898,15 @@ class GenerationEngine:
                         + self._dispatched[s] for s in active]
                 scope.ctx_tokens = sum(held)
                 # Table entries under the active slots' lengths, every
-                # micro-step's: what a paged kernel visits.
+                # micro-step's: what a paged kernel visits. A gathering
+                # family takes, for every slot of the bank, the width over
+                # the longest of them, a micro-step later a position more.
                 self._note_attention(
                     scope, [(n + i, 1) for n in held for i in range(fuse)],
-                    fuse * self.max_slots * self._max_blocks, active)
+                    self.max_slots * sum(
+                        self._model.pages_gathered(
+                            max(held) + i, self._max_blocks, self.block_size)
+                        for i in range(fuse)), active)
             step_seq += fuse
             # Whole-bank decode traces one shape per fuse width: the
             # unfused branch is a single cache entry, the fused branch

@@ -43,7 +43,15 @@ two pools are. Decode attends it ABSORBED (``q_nope W_UK`` against the
 latent itself, ``P . c_kv`` then ``W_UV``); a prefill chunk EXPANDS the
 gathered latent to per-head keys and values (all its tables at once, or,
 past ``_EXPAND_AT_ONCE`` positions, a table at a time). All the same
-mathematics.
+mathematics. A prefill chunk gathers the whole table it is given (the
+engine hands it a context bucket); a decode step is given the whole table
+and gathers the width its bank HOLDS: the tables' first page groups under
+the longest live context, one of ``decode_widths`` (the table's halvings),
+chosen on the device from the step's positions by ``decode_width`` (in a
+fused dispatch every micro-step anew) as one branch of a ``lax.switch``
+in ``_scan_layers_over_latent_pool``. A key past a row's position is masked
+and adds an exact zero to the softmax's sums, so the narrower branch is the
+whole table's result up to the order of a float32 sum.
 
 The routed product is ONE Pallas kernel of the repo's own
 (``ops/grouped_experts.py``) over the (token, expert) pairs sorted by
@@ -431,6 +439,27 @@ def expert_banks(moe: Dict) -> Dict:
 _EXPAND_AT_ONCE = 2048
 
 
+def decode_widths(table_pages: int) -> Tuple[int, ...]:
+    """The widths (table entries, ascending) a decode step's attention may
+    take: the table's whole width and its halvings, at most four. A fact of
+    the table alone: 256 to 4,096 positions of a 4,096-wide table, 512 to
+    8,192 of an 8,192-wide one."""
+    widths = [table_pages]
+    while len(widths) < 5 and widths[-1] % 2 == 0:
+        widths.append(widths[-1] // 2)
+    return tuple(reversed(widths))
+
+
+def decode_width(longest, table_pages: int, block_size: int):
+    """Which of ``decode_widths`` (its index) a decode step takes for a bank
+    whose longest live context is ``longest`` positions: the least that
+    holds it. THE rule: the step applies it to the positions it is given (a
+    traced scalar), the engine's dispatch record to the lengths it knows
+    (an int)."""
+    return sum(longest > pages * block_size
+               for pages in decode_widths(table_pages)[:-1])
+
+
 def _attend(q_nope, q_rope, table, mask, lp, cfg: MlaMoeConfig,
             absorbed: bool):
     """q_nope [T, R, H, nope], q_rope [T, R, H, rope] (rotated) against
@@ -443,6 +472,14 @@ def _attend(q_nope, q_rope, table, mask, lp, cfg: MlaMoeConfig,
     nothing of ``[L, H, ...]`` is made (decode: R = 1). Otherwise the
     latent is expanded to per-head keys and values first (a prefill
     chunk). The same mathematics either way.
+
+    ``table`` need only reach past every row's last key: a masked score is
+    ``finfo.min``, its probability an exact 0.0 once the row's maximum is
+    subtracted (every row sees its own position), and it adds nothing to
+    the softmax's sum or to ``P . c_kv``. So decode hands in the tables'
+    first ``decode_widths`` entries over its bank's longest context and
+    not their whole width (the caller's ``lax.switch``), and gets the whole
+    width's result but for the order of the float32 sums.
 
     Expanded over more than ``_EXPAND_AT_ONCE`` positions, the tables go
     one after another (a fact of the shapes, no option): every table's
@@ -502,8 +539,10 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
     x [N, d] with N = T * R rows: ``btabs`` [T, n_ctx] are the tables, each
     attended by R consecutive rows; ``dest``/``off``/``positions``/``live``
     are per row. A layer writes its N latent rows at ``(layer, page,
-    offset)`` and gathers ``pool[layer, btabs]``. Returns (h, pool, tokens
-    per expert [n_moe_layers, E + 1]).
+    offset)`` and gathers ``pool[layer, btabs]`` (``absorbed``, a decode
+    step: the tables' first entries over the longest live context, one of
+    ``decode_widths``). Returns (h, pool, tokens per expert [n_moe_layers,
+    E + 1]).
 
     With ``cfg.hc_mult`` n > 1 the carried state is ``[N, n, d]``: every
     stream starts as the row's embedding, attention and the feed-forward
@@ -516,6 +555,14 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
     rows = n // n_tables
     h_, dn = cfg.n_heads, cfg.qk_nope_head_dim
     dc, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
+    if absorbed:
+        # Decode attends the width its bank HOLDS: the least of the table's
+        # few widths over the longest live context, chosen here, on the
+        # device, once a step for all its layers. A slot with no request
+        # sits on the scratch page wherever its position stands and widens
+        # nothing; a bank with none takes the least width.
+        longest = jnp.max(jnp.where(live, positions, 0)) + 1
+        width = decode_width(longest, n_ctx, pool.shape[2])
 
     def attention(a, pool, lp, li):
         q = _dot(_rms_norm(_dot(a, lp["wq_a"]), lp["q_norm"], eps),
@@ -529,12 +576,21 @@ def _scan_layers_over_latent_pool(params: Dict, x, pool, btabs, dest, off,
         # One scatter at (layer, page, offset), then only the tables' pages
         # are read: [T, n_ctx, bs, width] -> [T, L, width].
         pool = pool.at[li, dest, off].set(latent.astype(pool.dtype))
-        table = pool[li, btabs].reshape(n_tables, -1, cfg.pool_width)
-        out = _attend(
-            q[..., :dn].reshape(n_tables, rows, h_, dn),
-            _rope(q[..., dn:], positions[:, None], cfg).reshape(
-                n_tables, rows, h_, -1),
-            table, mask, lp, cfg, absorbed)
+
+        def attend(pool, pages):    # over the tables' first ``pages`` entries
+            table = pool[li, btabs[:, :pages]].reshape(
+                n_tables, -1, cfg.pool_width)
+            return _attend(
+                q[..., :dn].reshape(n_tables, rows, h_, dn),
+                _rope(q[..., dn:], positions[:, None], cfg).reshape(
+                    n_tables, rows, h_, -1),
+                table, mask[..., :table.shape[1]], lp, cfg, absorbed)
+
+        if absorbed:
+            out = lax.switch(width, [functools.partial(attend, pages=w)
+                                     for w in decode_widths(n_ctx)], pool)
+        else:
+            out = attend(pool, n_ctx)
         return _dot(out.reshape(n, -1), lp["wo"]), pool
 
     def plain_layer(ffn, carry, xs):
@@ -693,6 +749,11 @@ class MlaMoePaged(PagedModel):
         cfg = self.cfg
         return (cfg.n_layers * block_size * cfg.pool_width
                 * np.dtype(cfg.dtype).itemsize)
+
+    def pages_gathered(self, longest: int, table_pages: int,
+                       block_size: int) -> int:
+        return decode_widths(table_pages)[
+            decode_width(longest, table_pages, block_size)]
 
     def shard(self, mesh, params):
         raise NotImplementedError(
